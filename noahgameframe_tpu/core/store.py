@@ -159,12 +159,24 @@ def _reset_and_write_rows(cs: ClassState, rows, i32, f32, vec) -> ClassState:
         remain=t.remain.at[rows].set(0),
         active=t.active.at[rows].set(False),
     )
+    # record pages are cleared by a row mask, not a scatter: a scatter
+    # into a [cap, R, k] page makes the TPU compiler relayout the whole
+    # page row-major, which pads (R, k) to (8, 128) tiles — at a 2^20
+    # capacity the [cap, 9, 29] stat page became two 8 GB temporaries and
+    # the program did not fit the chip.  The select keeps the page's own
+    # layout; the result is the same.
+    hit = jnp.zeros(cs.alive.shape, bool).at[rows].set(True)
+
+    def clear(page, fill):
+        mask = hit.reshape(hit.shape + (1,) * (page.ndim - 1))
+        return jnp.where(mask, jnp.asarray(fill, page.dtype), page)
+
     records = {
         rname: RecordState(
-            i32=rec.i32.at[rows].set(0),
-            f32=rec.f32.at[rows].set(0.0),
-            vec=rec.vec.at[rows].set(0.0),
-            used=rec.used.at[rows].set(False),
+            i32=clear(rec.i32, 0),
+            f32=clear(rec.f32, 0.0),
+            vec=clear(rec.vec, 0.0),
+            used=clear(rec.used, False),
         )
         for rname, rec in cs.records.items()
     }

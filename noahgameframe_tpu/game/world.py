@@ -166,6 +166,17 @@ class GameWorld:
 
         self.telemetry = TelemetryModule()
         modules.append(self.telemetry)
+        if self.combat is not None:
+            combat = self.combat
+            self.telemetry.registry.register_callback(
+                "nf_combat_fold_engine",
+                lambda: -1 if combat.engine_baked is None
+                else combat.engine_baked,
+                kind="gauge",
+                help="combat fold engine baked into the newest trace, "
+                     "after any VMEM downgrade (0 XLA, 1 Pallas fold, "
+                     "2 fused; -1 before the first trace)",
+            )
 
         # elastic mesh surface (parallel/elastic.py): populated by
         # .shard(); None keeps the world single-device
@@ -313,11 +324,15 @@ def build_benchmark_world(
     seed: int = 0,
     attack_period_s: float = 1.0,
     player_capacity: int = 64,
+    movement: bool = True,
+    placement=None,
 ) -> GameWorld:
     """The staged BASELINE configs: density held at ~0.4 NPCs per world
     unit² so AOI cost scales with N, not with density.  `player_capacity`
     sizes the Player bank for served-path runs (bench.py --served seats
-    one live avatar per simulated session)."""
+    one live avatar per simulated session).  `placement` (a
+    parallel.SpatialPlacement over class "NPC") attaches the full-row
+    migration phase for a world that is then `.shard()`ed over a mesh."""
     if extent is None:
         extent = max(64.0, float(np.sqrt(n_npcs / 0.4)))
     cap = 1 << int(np.ceil(np.log2(max(n_npcs, 64))))
@@ -326,10 +341,12 @@ def build_benchmark_world(
             npc_capacity=cap,
             extent=extent,
             combat=combat,
+            movement=movement,
             seed=seed,
             attack_period_s=attack_period_s,
             middleware=False,
             player_capacity=player_capacity,
+            placement=placement,
         )
     )
     w.start()

@@ -18,7 +18,8 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time as _time
-from typing import Callable, Dict, List, Optional
+from collections import deque
+from typing import Callable, Deque, Dict, List, Optional
 
 from ..core.chash import ConsistentHash
 from .defines import KEEPALIVE_SECONDS, RECONNECT_SECONDS, ServerType
@@ -141,6 +142,10 @@ class NetServerModule:
         # (`NFINet.h:246-405`): conn_id -> dict of app tags
         self.conn_tags: Dict[int, Dict[str, object]] = {}
         self.dispatch.on_socket_event(self._track)
+        # polled but not yet dispatched (execute() with a budget), in
+        # arrival order; backlog_max is the most that ever waited
+        self._backlog: Deque[NetEvent] = deque()
+        self.backlog_max = 0
 
     def _track(self, conn_id: int, kind: int) -> None:
         if kind == EV_CONNECTED:
@@ -185,8 +190,23 @@ class NetServerModule:
         self.conn_tags.pop(conn_id, None)
 
     # ------------------------------------------------------------ pump
-    def execute(self) -> None:
-        self.dispatch.feed(self.transport.poll())
+    def execute(self, budget_seconds: Optional[float] = None) -> None:
+        """Poll the transport and dispatch what arrived.  With a budget,
+        dispatching stops once this round has spent it (one event is
+        always served) and the rest waits for the next round, in arrival
+        order and ahead of anything newer: a burst of slow handlers can
+        then not hold the pump for longer than the budget plus one
+        handler.  An event reaches the dispatch tap (the journal) only
+        when it is dispatched, so replay sees it in the window whose
+        state it changed."""
+        self._backlog.extend(self.transport.poll())
+        end = (None if budget_seconds is None
+               else _time.perf_counter() + budget_seconds)
+        while self._backlog:
+            self.dispatch.feed([self._backlog.popleft()])
+            if end is not None and _time.perf_counter() >= end:
+                break
+        self.backlog_max = max(self.backlog_max, len(self._backlog))
 
     def shut(self) -> None:
         self.transport.close()

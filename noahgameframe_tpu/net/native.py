@@ -1,15 +1,21 @@
 """ctypes binding for the native C++ epoll transport (native/nfnet.cc).
 
-Builds ``libnfnet.so`` on demand with g++ (the image has no pybind11;
-the flat C API + ctypes keeps the binding dependency-free).  The
-classes expose the exact poll/send contract of the pure-Python backend
-in :mod:`noahgameframe_tpu.net.transport`, so the two are drop-in
-interchangeable via ``create_server/create_client``.
+Builds ``libnfnet-<hash>.so`` on demand with g++ (the image has no
+pybind11; the flat C API + ctypes keeps the binding dependency-free).
+The output name carries a hash of ``nfnet.cc`` and the ``Makefile``, so
+a binary left over from other sources is never loaded: a changed source
+is a different file name, and that file is built from what the checkout
+holds.  The classes expose the exact poll/send contract of the
+pure-Python backend in :mod:`noahgameframe_tpu.net.transport`, so the
+two are drop-in interchangeable via ``create_server/create_client``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
+import shutil
 import subprocess
 import threading
 from pathlib import Path
@@ -21,15 +27,41 @@ from .transport import EV_CONNECTED, EV_DISCONNECTED, NetEvent
 # NF_NATIVE_DIR at a checkout of native/ (or anywhere holding
 # nfnet.cc/Makefile) — create_server/create_client fall back to the
 # pure-Python transport when neither exists
-import os as _os
-
 _NATIVE_DIR = Path(
-    _os.environ.get("NF_NATIVE_DIR")
+    os.environ.get("NF_NATIVE_DIR")
     or Path(__file__).resolve().parents[2] / "native"
 )
-_LIB_PATH = _NATIVE_DIR / "build" / "libnfnet.so"
+_SOURCES = ("nfnet.cc", "Makefile")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
+
+
+def lib_path() -> Path:
+    """``native/build/libnfnet-<hash of the sources>.so``."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        h.update((_NATIVE_DIR / name).read_bytes())
+    return _NATIVE_DIR / "build" / f"libnfnet-{h.hexdigest()[:16]}.so"
+
+
+def _build(out: Path) -> None:
+    """make into a private directory, then rename into place: several
+    processes (test workers, the five roles) may build at once, and
+    none may load a half-written library.  Libraries built from other
+    sources are then removed (a process that has one loaded keeps it)."""
+    tmp = out.parent / f"tmp.{os.getpid()}"
+    try:
+        subprocess.run(
+            ["make", "-s", "-C", str(_NATIVE_DIR), f"BUILD={tmp}"],
+            check=True,
+            capture_output=True,
+        )
+        os.replace(tmp / "libnfnet.so", out)
+        for stale in out.parent.glob("libnfnet-*.so"):
+            if stale != out:
+                stale.unlink(missing_ok=True)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
 
 
 def _load() -> ctypes.CDLL:
@@ -37,13 +69,10 @@ def _load() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if not _LIB_PATH.exists():
-            subprocess.run(
-                ["make", "-s", "-C", str(_NATIVE_DIR)],
-                check=True,
-                capture_output=True,
-            )
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        path = lib_path()
+        if not path.exists():
+            _build(path)
+        lib = ctypes.CDLL(str(path))
         lib.nfnet_server_create.restype = ctypes.c_void_p
         lib.nfnet_server_create.argtypes = [ctypes.c_char_p, ctypes.c_int]
         lib.nfnet_client_create.restype = ctypes.c_void_p
@@ -81,6 +110,8 @@ def _load() -> ctypes.CDLL:
 
 
 class _NativeEndpoint:
+    backend_name = "native"
+
     def __init__(self, handle: int) -> None:
         self._lib = _load()
         self._h = handle

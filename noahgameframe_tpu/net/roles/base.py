@@ -21,6 +21,7 @@ Roles are pump-driven and single-threaded like the reference main loop.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time as _time
 import xml.etree.ElementTree as ET
 from pathlib import Path
@@ -92,6 +93,13 @@ class ServerRole:
         self.server = NetServerModule(config.ip, config.port, backend=backend)
         config.port = self.server.port  # resolve ephemeral port
         self.backend = backend
+        # the transport `backend` resolved to ("native" epoll or "py"):
+        # 'auto' falls back when the C++ library cannot be built, and
+        # that must be readable from a run (here, /json, pipeline_stats)
+        self.transport_backend = self.server.transport.backend_name
+        logging.getLogger("nf.net.role").info(
+            "%s id=%d transport=%s", config.name, config.server_id,
+            self.transport_backend)
         self.clients: Dict[str, NetClientModule] = {}
         self.state = int(ServerState.NORMAL)
         # telemetry: one registry per role.  A role that owns a world
@@ -182,6 +190,8 @@ class ServerRole:
         # min of recv - sent over the heartbeat stream)
         ext.key.append(b"mono_ns")
         ext.value.append(str(_time.perf_counter_ns()).encode())
+        ext.key.append(b"transport")
+        ext.value.append(self.transport_backend.encode())
         if self.metrics.frames:
             p = self.metrics.percentiles()
             for k in ("p50_ms", "p95_ms", "p99_ms"):
@@ -197,9 +207,15 @@ class ServerRole:
         return Ident(svrid=self.config.server_id, index=0)
 
     # ---------------------------------------------------------- pump
+    def inbound_budget_seconds(self) -> Optional[float]:
+        """The most one execute() round spends dispatching client
+        requests before the rest waits for the next round (None: no
+        bound).  Roles whose handlers are slow override it."""
+        return None
+
     def execute(self, now: Optional[float] = None) -> None:
         now = _time.monotonic() if now is None else now
-        self.server.execute()
+        self.server.execute(self.inbound_budget_seconds())
         for pool in self.clients.values():
             pool.execute(now)
         if self._metrics_http is not None:

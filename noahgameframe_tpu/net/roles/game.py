@@ -716,6 +716,9 @@ class GameRole(ServerRole):
             "last_wall_ms": round(sc.last_wall_ns / 1e6, 4),
             "last_ms": {k: round(v / 1e6, 4) for k, v in sc.last.items()},
             "stages": sc.stats(),
+            "transport": self.transport_backend,
+            # requests that waited for a later round (inbound budget)
+            "inbound_backlog_max": self.server.backlog_max,
             "trace": {
                 "sample": self._trace_sample,
                 "sent": self.trace_sent,
@@ -772,6 +775,15 @@ class GameRole(ServerRole):
 
     def cur_count(self) -> int:
         return len(self.sessions)
+
+    def inbound_budget_seconds(self) -> float:
+        """One frame period.  A handler here reads and writes device
+        state eagerly (enter-game: ~170 such operations, 0.57 s a session
+        on a TPU v5e), so a login burst served in one round would hold
+        the tick, the flush and this role's heartbeats for the whole
+        burst; bounded, requests wait in arrival order and a round is at
+        most a frame period and one handler late."""
+        return self.game_world.config.dt
 
     # ------------------------------------------------------------ sending
     def _send_to(self, idents: Sequence[Ident], conn_id: int, msg_id: int,

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import errno
+import logging
 import selectors
 import socket
 from typing import Dict, List, Optional, Tuple
@@ -52,6 +53,10 @@ class _Conn:
 
 class _Endpoint:
     """Shared server/client machinery: registered socket set + pump."""
+
+    #: which transport this is; roles report it (log line, /json, and
+    #: pipeline_stats) so a fallback from the native one is visible
+    backend_name = "py"
 
     def __init__(self) -> None:
         self._sel = selectors.DefaultSelector()
@@ -256,6 +261,19 @@ class PyNetClient(_Endpoint):
             self._cid = None
 
 
+_log = logging.getLogger("nf.net.transport")
+
+
+def _native_unavailable(backend: str, e: Exception) -> None:
+    """'native' was asked for by name: fail.  'auto' falls back to the
+    pure-Python transport, and says so (logged with the cause, and
+    visible afterwards as the endpoint's ``backend_name``)."""
+    if backend == "native":
+        raise e
+    _log.warning("native transport unavailable, using the pure-Python "
+                 "one: %s: %s", type(e).__name__, e)
+
+
 def create_server(host: str = "127.0.0.1", port: int = 0, backend: str = "auto"):
     """backend: 'py', 'native', or 'auto' (native if the C++ lib builds)."""
     if backend in ("native", "auto"):
@@ -263,9 +281,8 @@ def create_server(host: str = "127.0.0.1", port: int = 0, backend: str = "auto")
             from .native import NativeNetServer
 
             return NativeNetServer(host, port)
-        except Exception:
-            if backend == "native":
-                raise
+        except Exception as e:  # noqa: BLE001 — cannot be built or loaded
+            _native_unavailable(backend, e)
     return PyNetServer(host, port)
 
 
@@ -275,7 +292,6 @@ def create_client(host: str, port: int, backend: str = "auto"):
             from .native import NativeNetClient
 
             return NativeNetClient(host, port)
-        except Exception:
-            if backend == "native":
-                raise
+        except Exception as e:  # noqa: BLE001 — cannot be built or loaded
+            _native_unavailable(backend, e)
     return PyNetClient(host, port)

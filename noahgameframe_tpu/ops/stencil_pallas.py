@@ -67,6 +67,14 @@ PALLAS_PARITY_TESTS = {
 }
 
 
+def pallas_interpret() -> bool:
+    """Whether the kernels below run in Pallas interpret mode: exactly
+    when the default backend is the CPU (tests, rehearsals).  Every
+    other backend, known or not, reaches its compiler and fails there
+    rather than quietly interpreting."""
+    return jax.default_backend() == "cpu"
+
+
 def _kernel(vic_ref, top_ref, mid_ref, bot_ref, out_ref, *, w: int, r2: float):
     kv = vic_ref.shape[2]
     ka = top_ref.shape[2]

@@ -683,11 +683,16 @@ class RoomBatch:
         scalar, so admitting to any slot reuses one compiled scatter."""
         self._sync_generation()
         if self._jit_admit is None:
+            # the batch comes back under the batch's own shardings:
+            # left to the compiler, a zero-width bank returns
+            # replicated, and rooms.step (pinned in_shardings) refuses it
+            jkw = ({} if self.mesh is None
+                   else {"out_shardings": self.shardings()})
             self._jit_admit = self.costbook.wrap(
                 "rooms.admit",
                 lambda b, r, s: jax.tree.map(
                     lambda bb, ll: bb.at[s].set(ll), b, r),
-                donate_argnums=0, stage="tick")
+                donate_argnums=0, stage="tick", jit_kwargs=jkw)
         payload = self._room_payload(room)
         self.state = self._jit_admit(self.state, payload, jnp.int32(int(slot)))
         return int(slot)

@@ -38,17 +38,6 @@ from ..kernel.module import Module
 from ..persist.rowblob import class_row_leaf_items, rebuild_class_state, row_nbytes
 from .mesh import SHARD_AXIS, make_mesh
 
-# jax.shard_map landed as a top-level API (with check_vma) after 0.4.x;
-# older releases spell it jax.experimental.shard_map with check_rep.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax<0.6 only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SM_KW = {"check_rep": False}
-
-
 def _pack_rows(sel, rank, budget, *arrays):
     """Gather up to `budget` selected rows into fixed [budget] buffers.
     sel: [n] bool, rank: [n] exclusive rank among selected.  Returns
@@ -204,12 +193,12 @@ def mesh_migrate_class(
         stats = jnp.stack([mig, ovf, drp])[None, :]  # [1, 3] per shard
         return tuple(merged) + tuple(new_others[n_row - 1:]) + (stats,)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(row,) * (n_row + len(extras)),
         out_specs=(row,) * (n_row + len(extras) + 1),
-        **_SM_KW,
+        check_vma=False,
     )
     out = smapped(*(arrs + extras))
     new_leaves = list(out[:n_row])

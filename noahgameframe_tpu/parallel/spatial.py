@@ -58,9 +58,7 @@ from ..ops.stencil import binning_mode, build_cell_table_pair, pull
 from ..ops.verlet import VerletCache, full_table, refresh, sub_table
 from .mesh import SHARD_AXIS, make_mesh
 from .rowmigrate import (
-    _SM_KW,
     _pack_rows,  # noqa: F401  (re-export: the slab protocol's packer moved)
-    _shard_map,
     RowMigrationModule,
     SpatialPlacement,
 )
@@ -313,12 +311,12 @@ class _SpatialModule(Module):
         cs = state.classes["spatial"]
         vc = state.aux[VC_AUX]
         row, rep = P(w.axis), P()
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             partial(_combat_body, g, w.axis),
             mesh=w.mesh,
             in_specs=(row,) * 13 + (rep,),
             out_specs=(row,) * 9,
-            **_SM_KW,
+            check_vma=False,
         )
         (hp, died, vc_pos, vc_active, vc_order, vc_skey, vc_slot, cstat,
          stats) = smapped(

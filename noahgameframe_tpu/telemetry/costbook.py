@@ -67,18 +67,17 @@ _EVENT_RING = 128
 #: run compiles a few dozen programs, so the cap is a runaway backstop)
 _COMPILE_LOG_CAP = 4096
 
-#: peak FLOPs/s and HBM bytes/s per platform for the roofline fold.
-#: CPU has no honest single number (it depends on the host SKU), so the
-#: entry is a deliberately round placeholder marked *provisional* — the
-#: schema and the achieved numerators are platform-agnostic; only the
-#: denominators (and so the fractions) firm up on real hardware.
+#: peak FLOPs/s and HBM bytes/s for the roofline fold, keyed by
+#: ``jax.Device.device_kind``.  A device that is not in the table is an
+#: error in :func:`roofline_fold`, not a default: a fraction of some
+#: other device's peak is not a measurement.
 PEAKS: Dict[str, Dict[str, Any]] = {
-    "cpu": {"flops_per_s": 5.0e10, "bytes_per_s": 2.0e10,
-            "source": "provisional-nominal-cpu"},
-    "tpu": {"flops_per_s": 1.97e14, "bytes_per_s": 1.23e12,
-            "source": "tpu-v5e-spec-bf16"},
-    "gpu": {"flops_per_s": 9.89e13, "bytes_per_s": 2.04e12,
-            "source": "a100-spec-bf16"},
+    "TPU v5 lite": {
+        "flops_per_s": 1.97e14, "bytes_per_s": 8.19e11,
+        "hbm_bytes": 16 * 1024 ** 3,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 819 GB/s HBM, 16 GB per chip",
+    },
 }
 
 
@@ -485,7 +484,7 @@ class CostBook:
 
 
 def roofline_fold(book: CostBook, pipeline_stats: Dict[str, Any],
-                  platform: Optional[str] = None) -> Dict[str, Any]:
+                  device_kind: Optional[str] = None) -> Dict[str, Any]:
     """Join CostBook FLOPs/bytes with StageClock device seconds into
     achieved-vs-peak fractions per stage.
 
@@ -494,13 +493,17 @@ def roofline_fold(book: CostBook, pipeline_stats: Dict[str, Any],
     that stage's entries of (per-dispatch cost x dispatches) / frames;
     honest device seconds require the run to have had
     ``NF_STAGE_TIMING=1`` (otherwise the tick stage times only the
-    async dispatch and the fractions are upper bounds)."""
-    if platform is None:
-        try:
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
-    peaks = PEAKS.get(platform, PEAKS["cpu"])
+    async dispatch and the fractions are upper bounds).
+
+    ``device_kind`` defaults to the first device's; a kind that has no
+    row in :data:`PEAKS` raises ``KeyError``."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no peaks for device_kind {device_kind!r}: the roofline "
+            f"table knows {sorted(PEAKS)}; add a row with its source")
+    peaks = PEAKS[device_kind]
     frames = max(1, int(pipeline_stats.get("frames", 0)))
     stage_ms = pipeline_stats.get("stages", {})
     per_stage: Dict[str, Dict[str, Any]] = {}
@@ -532,9 +535,7 @@ def roofline_fold(book: CostBook, pipeline_stats: Dict[str, Any],
             s["frac_of_peak_flops"] = 0.0
             s["frac_of_peak_bytes"] = 0.0
     return {
-        "platform": platform,
-        "provisional": str(peaks.get("source", "")).startswith(
-            "provisional"),
+        "device_kind": device_kind,
         "peaks": dict(peaks),
         "frames": frames,
         "stages": per_stage,

@@ -28,7 +28,6 @@ def main() -> None:
 
     from noahgameframe_tpu.utils.platform import init_compile_cache
 
-    os.environ.setdefault("NF_COMPILE_CACHE", "/tmp/nf_xla_cache")
     init_compile_cache()
 
     import jax

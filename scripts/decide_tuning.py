@@ -1,11 +1,11 @@
 """Promote measured A/B winners into bench_runs/tuning.json.
 
-The harvest queue captures the 1M tick under the default engines and
-under the opt-in variants (NF_RADIX=1/2 sort, NF_PALLAS=1 fold /
+Chip captures under bench_runs/ time the 1M tick under the default
+engines and under the opt-in variants (NF_RADIX=1/2 sort, NF_PALLAS=1 fold /
 NF_PALLAS=2 fused table-free).  This
 script compares whatever captures exist and records the winning flag
-set, so later bench runs (including the driver's end-of-round one) use
-the fastest measured configuration instead of the defaults.  Env vars
+set, so later bench runs use the fastest measured configuration
+instead of the defaults.  Env vars
 still override (bench.py applies tuning via setdefault).
 
 A variant must beat the baseline fused tick by >3% to be promoted —
@@ -88,8 +88,8 @@ def main() -> None:
         ):
             tuning["NF_PALLAS_ALIGN"] = "128"
 
-    # Verlet skin (ops/verlet.py): the harvest queue captures the 1M tick
-    # at skins 1/2/4; the fastest capture that beats the margin elects
+    # Verlet skin (ops/verlet.py): captures of the 1M tick at skins
+    # 1/2/4; the fastest capture that beats the margin elects
     # NF_VERLET_SKIN.  A too-large skin loses through bucket inflation
     # (cell_size >= radius + skin), a too-small one through rebuild rate,
     # so this is a measured election, not a formula.
@@ -103,8 +103,8 @@ def main() -> None:
         tuning["NF_VERLET_SKIN"] = best_skin
 
     # Counting-sort binning (NF_BINNING, ops/stencil.py): the r07 A/B
-    # pins its OWN baseline (env NF_BINNING=sort in the harvest queue,
-    # immune to this file's previous output) — compare count against
+    # pins its OWN baseline (a capture with NF_BINNING=sort in its
+    # environment, immune to this file's previous output) — compare count against
     # that same-round capture when it exists, else the round baseline.
     count_base = tick_ms("r07_tpu_1m.json")
     if count_base is None:

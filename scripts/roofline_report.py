@@ -8,10 +8,9 @@ device work) and folds the CostBook's per-entry `cost_analysis()`
 FLOPs/bytes against the StageClock's per-stage seconds into
 achieved-vs-peak fractions per stage (telemetry/costbook.roofline_fold).
 
-The schema is platform-agnostic; on the CPU backend the peak
-denominators are the PEAKS table's provisional placeholders and the
-output is marked `"provisional": true` — the achieved numerators are
-real either way.
+The peaks come from costbook.PEAKS, keyed by `device_kind` with their
+source; a device that has no row there (the CPU backend included) is an
+error, so this script only produces a report on the chip.
 
 Usage:
     NF_STAGE_TIMING=1 python scripts/roofline_report.py \

@@ -617,3 +617,35 @@ def test_sdk_set_fight_hero_bytes_drive_the_server(rig):
                  MsgBase(player_id=ident, msg_data=base.msg_data).encode())
     ])
     assert world.heroes.fight_hero(g, 1) == row
+
+
+def test_login_burst_is_served_a_round_at_a_time(rig):
+    """A burst of enter-game requests does not hold one execute() round
+    for the whole burst: the game role dispatches client requests for at
+    most a frame period per round (ServerRole.inbound_budget_seconds) and
+    the rest waits, in arrival order.  With the budget at zero that is
+    exactly one request per round."""
+    from noahgameframe_tpu.net.wire import ReqEnterGameServer
+
+    world, role, seat, send, acks = rig
+    assert role.inbound_budget_seconds() == world.config.dt
+    role.inbound_budget_seconds = lambda: 0.0
+    burst = []
+    for i in range(4):
+        ident = Ident(svrid=9, index=i + 1)
+        req = ReqEnterGameServer(id=ident, account=f"acc{i}".encode(),
+                                 game_id=6, name=f"Hero{i}".encode())
+        burst.append(NetEvent(EV_MSG, 100, int(MsgID.REQ_ENTER_GAME),
+                              wrap(req, player_id=ident)))
+    arrivals = [burst]
+    role.server.transport.poll = lambda: arrivals.pop() if arrivals else []
+
+    def entered():
+        return [s.account for s in role.sessions.values()
+                if s.guid is not None]
+
+    for n in range(1, 5):
+        role.execute()
+        assert entered() == [f"acc{i}" for i in range(n)]
+    assert role.pipeline_stats()["inbound_backlog_max"] == 3
+    assert len(acks(100, MsgID.ACK_ENTER_GAME)) == 4

@@ -22,6 +22,7 @@ REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
 import jax.numpy as jnp
+import pytest
 
 from noahgameframe_tpu.telemetry.costbook import CostBook, roofline_fold
 
@@ -102,8 +103,10 @@ def test_roofline_fold_fractions():
     for _ in range(4):
         f(x)
     stats = {"frames": 4, "stages": {"tick": {"mean_ms": 2.0}}}
-    fold = roofline_fold(book, stats, platform="cpu")
-    assert fold["platform"] == "cpu" and fold["provisional"]
+    fold = roofline_fold(book, stats, device_kind="TPU v5 lite")
+    assert fold["device_kind"] == "TPU v5 lite"
+    assert fold["peaks"]["bytes_per_s"] == 8.19e11  # v5e: 819 GB/s
+    assert "TPU v5e" in fold["peaks"]["source"]
     s = fold["stages"]["tick"]
     assert s["entries"] == ["t.mm"]
     assert s["device_s_per_frame"] == 0.002
@@ -111,6 +114,17 @@ def test_roofline_fold_fractions():
     assert s["flops_per_frame"] == book.entries["t.mm"].last["flops"]
     if s["flops_per_frame"] > 0:
         assert 0 < s["frac_of_peak_flops"] < 1
+
+
+def test_roofline_fold_unknown_device_is_an_error():
+    """A device without a row in PEAKS has no roofline: no CPU
+    placeholder, no default (the suite's own CPU device included)."""
+    book = CostBook()
+    stats = {"frames": 1, "stages": {}}
+    with pytest.raises(KeyError, match="no peaks for device_kind"):
+        roofline_fold(book, stats)
+    with pytest.raises(KeyError, match="TPU v9"):
+        roofline_fold(book, stats, device_kind="TPU v9")
 
 
 # ------------------------------------------------- 120-tick churn soak

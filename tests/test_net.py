@@ -216,6 +216,40 @@ def test_server_client_modules_envelope():
     server.shut()
 
 
+def test_server_inbound_budget_defers_in_order():
+    """With a budget, one execute() round stops dispatching once it has
+    spent it: a burst of slow handlers is served over several rounds, in
+    arrival order, and the dispatch tap (the journal's seam) sees each
+    event only in the round that dispatches it."""
+    from noahgameframe_tpu.net.transport import EV_MSG, NetEvent
+
+    server = NetServerModule(backend="py")
+    try:
+        handled, tapped = [], []
+        server.dispatch.tap = lambda ev: tapped.append(ev.body)
+
+        def slow(_conn, _mid, body):
+            time.sleep(0.02)
+            handled.append(body)
+
+        server.on(7, slow)
+        burst = [NetEvent(EV_MSG, 1, 7, bytes([i])) for i in range(6)]
+        arrivals = [list(burst)]
+        server.transport.poll = lambda: arrivals.pop() if arrivals else []
+
+        server.execute(budget_seconds=0.03)  # 2 x 20 ms reaches the budget
+        assert handled == tapped == [b"\x00", b"\x01"]
+        assert server.backlog_max == 4
+        arrivals.append([NetEvent(EV_MSG, 1, 7, b"new")])
+        server.execute(budget_seconds=0.0)  # one event is always served
+        assert handled == tapped == [b"\x00", b"\x01", b"\x02"]
+        server.execute()  # no budget: everything, backlog before the new
+        assert handled == tapped == [bytes([i]) for i in range(6)] + [b"new"]
+        assert server.backlog_max == 4
+    finally:
+        server.shut()
+
+
 def test_client_pool_reconnect_fsm():
     server = NetServerModule(backend="py")
     port = server.port
@@ -268,3 +302,44 @@ def test_consistent_hash_routing_stability():
     assert all(after[k] != 3 for k in keys)
     # only keys that lived on the removed node may move
     assert moved == 0
+
+
+_NATIVE_REBUILD_PROBE = """
+import sys
+from pathlib import Path
+from noahgameframe_tpu.net import native
+
+first = native.lib_path()
+native._load()
+assert first.exists()
+src = Path(sys.argv[1]) / "nfnet.cc"
+src.write_text(src.read_text() + "\\n// edited\\n")
+native._lib = None  # what a fresh process would see
+second = native.lib_path()
+assert second != first
+native._load()
+print("LIBS", sorted(p.name for p in second.parent.glob("*.so")) == [second.name])
+"""
+
+
+def test_native_library_is_rebuilt_when_its_sources_change(tmp_path):
+    """The library's name carries a hash of nfnet.cc and the Makefile: an
+    edited source is built under a new name, and the binary of the old
+    source is removed, never loaded."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    if shutil.which("g++") is None or shutil.which("make") is None:
+        pytest.skip("no C++ toolchain here")
+    for name in ("nfnet.cc", "Makefile"):
+        shutil.copy(repo / "native" / name, tmp_path / name)
+    r = subprocess.run(
+        [sys.executable, "-c", _NATIVE_REBUILD_PROBE, str(tmp_path)],
+        capture_output=True, text=True, timeout=300, cwd=repo,
+        env=dict(os.environ, NF_NATIVE_DIR=str(tmp_path)))
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "LIBS True" in r.stdout

@@ -205,6 +205,50 @@ def test_blob_roundtrip_and_fail_closed(scenario):
 # -- host-only: the bin packer ----------------------------------------------
 
 
+def test_batch_state_keeps_the_rooms_sharding_on_every_leaf():
+    """Every program that hands back ``batch.state`` pins the batch's
+    shardings: after admit, grow and a train each leaf — the zero-width
+    banks included, which the compiler would otherwise return
+    replicated — is laid out room-major over ROOMS_AXIS, so the next
+    pinned-input program (rooms.step) accepts it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from noahgameframe_tpu.parallel.mesh import ROOMS_AXIS, make_mesh
+    from noahgameframe_tpu.parallel.rooms import RoomBatch
+
+    mesh = make_mesh(4, axis=ROOMS_AXIS)
+    w = _recipe(0)
+    w.kernel._ensure_aux()
+    batch = RoomBatch(w.kernel, 4, mesh=mesh)
+
+    def assert_room_major(when):
+        leaves = jax.tree_util.tree_leaves_with_path(batch.state)
+        for path, leaf in leaves:
+            want = NamedSharding(mesh, PartitionSpec(ROOMS_AXIS))
+            assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (
+                when, jax.tree_util.keystr(path), leaf.shape, leaf.sharding)
+        return [leaf.shape for _, leaf in leaves]
+
+    for slot in range(4):
+        batch.admit(slot, w.kernel.state.replace(
+            rng=jax.random.PRNGKey(7 + slot)))
+    shapes = assert_room_major("admit")
+    assert any(0 in shp for shp in shapes), "recipe has no zero-width bank"
+    batch.tick()  # the pinned-input program takes what admit returned
+
+    assert batch.grow(8) == 8
+    assert_room_major("grow")
+    batch.admit(5, w.kernel.state.replace(rng=jax.random.PRNGKey(99)))
+    assert_room_major("admit after grow")
+
+    batch.configure_train(2)
+    lanes = batch.train(3)  # one 2-tick train + one ragged single
+    assert lanes.shape[:2] == (3, 8)
+    assert_room_major("train")
+    batch.run(2)
+    assert_room_major("run")
+
+
 def test_packer_least_loaded_spreads_across_blocks():
     p = RoomBinPacker(8, n_blocks=4)
     slots = [p.alloc(load=1.0) for _ in range(4)]

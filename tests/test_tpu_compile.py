@@ -1,0 +1,194 @@
+"""What the chip's compiler accepts, asked without a chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described (`v5e:2x2`) and not attached, so these cases guard every later
+PR at no chip time: the Pallas fold at the real 100k and 1M geometries,
+the fused neighbourhood kernel's refusal (strict xfail: the day Mosaic
+lowers its gather, this file says so), the program that seeds a 1M
+world, the default tick, and one sharded tick over the described 2x2
+mesh.  A compile that passes is not a chip
+run: nothing executes, so no result or time is checked here.
+
+This is the only file that describes a topology.  The description
+happens inside the module-scoped `topo` fixture, never at import (only
+one process may hold the TPU library; see section 2 of the
+on-chip-measurement guide), and the compile cache is off around it: such
+a compile can be written to the cache but not read back without a chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from noahgameframe_tpu.game import build_benchmark_world
+from noahgameframe_tpu.game.combat import CombatModule
+from noahgameframe_tpu.ops import stencil_pallas as sp
+from noahgameframe_tpu.ops.stencil import CellSlots, CellTable
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _geometry(n):
+    """(capacity, width, cell, victim bucket, attacker bucket) of
+    build_benchmark_world(n)'s combat grid, from the module that sizes
+    it (staggered arming: duty = dt / attack_period = 1/30)."""
+    extent = max(64.0, float(np.sqrt(n / 0.4)))
+    cap = 1 << int(np.ceil(np.log2(n)))
+    m = CombatModule(extent=extent, radius=4.0)
+    m._attacker_duty = 1.0 / 30.0
+    return (cap, m.width, m.cell_size, m.resolved_bucket(cap),
+            m.resolved_att_bucket(cap))
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding)
+
+
+@pytest.mark.parametrize("n,want", [
+    (100_000, (125, 20, 6)),
+    (1_000_000, (395, 16, 6)),
+])
+def test_combat_fold_pallas_compiles_natively(one_chip, n, want):
+    cap, width, cell, kv, ka = _geometry(n)
+    assert (width, kv, ka) == want, "benchmark geometry moved"
+    cells = width * width
+
+    def fold(vp, vs, ap, as_):
+        return sp.combat_fold_pallas(
+            CellTable(vp, vs, jnp.int32(0), width, cell, kv),
+            CellTable(ap, as_, jnp.int32(0), width, cell, ka),
+            4.0, interpret=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(fold).lower(
+        arg((cells * kv + 1, sp.N_VFEATS + 1), jnp.float32),
+        arg((cap,), jnp.int32),
+        arg((cells * ka + 1, sp.N_AFEATS + 1), jnp.float32),
+        arg((cap,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="Mosaic: 'Only 2D gather is supported' — the fused kernel "
+           "gathers [K, W] rows out of a 1-D bank; restated as a 2-D "
+           "take_along_axis it is refused too ('Multiple source vregs "
+           "along gather dimension'), so engine 2 has never run natively")
+def test_fused_neighborhood_compiles_natively(one_chip):
+    cap, width, cell, kv, ka = _geometry(100_000)
+    fits, _need, _budget = sp.fused_fits_vmem(cap, width, kv, ka)
+    assert fits, "the refusal is the compiler's, not the VMEM gate's"
+
+    def fused(bank, vso, aso):
+        return sp.fused_neighborhood(
+            bank, CellSlots(vso, jnp.int32(0), width, cell, kv),
+            CellSlots(aso, jnp.int32(0), width, cell, ka),
+            4.0, interpret=False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    jax.jit(fused).lower(
+        arg((cap, sp.N_BFEATS), jnp.float32), arg((cap,), jnp.int32),
+        arg((cap,), jnp.int32),
+    ).compile()
+
+
+def test_world_seeding_fits_one_chip_at_1m(one_chip):
+    """The program that seeds rows (store.create_many) at BASELINE
+    config 4's capacity.  Found on the chip in PR 21: cleared by a
+    scatter, the [2^20, 9, 29] stat page was relaid row-major, padded
+    7.8x to two 8 GB temporaries, and the 1M world could not be built."""
+    from noahgameframe_tpu.core import store
+
+    cap = 1 << 20
+    cs = build_benchmark_world(64, seed=0).kernel.state.classes["NPC"]
+    assert cs.records["CommPropertyValue"].i32.shape[1:] == (9, 29)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    big = jax.tree.map(lambda x: arg((cap,) + x.shape[1:], x.dtype), cs)
+    compiled = store._reset_and_write_rows.lower(
+        big, arg((cap,), jnp.int32),
+        arg((cap, cs.i32.shape[1]), jnp.int32),
+        arg((cap, cs.f32.shape[1]), jnp.float32),
+        arg((cap,) + cs.vec.shape[1:], jnp.float32),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 3
+
+
+def test_default_tick_compiles_for_one_chip(one_chip):
+    """kernel._trace_step of the benchmark world (engine 0, the shipped
+    default) at a 32,768-row capacity."""
+    k = build_benchmark_world(20_000, seed=0).kernel
+    k._ensure_aux()
+    assert k.store.capacity("NPC") == 32_768
+    state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
+    compiled = jax.jit(k._trace_step, donate_argnums=0).lower(state).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0
+    assert mem.temp_size_in_bytes < 16 * 1024 ** 3
+
+
+def test_sharded_tick_compiles_for_the_2x2_mesh(topo):
+    """One sharded tick with live row migration (ShardedKernel +
+    RowMigrationModule, the SpatialWorld preset) over the four described
+    devices: migration and halos lower to collective-permutes."""
+    from noahgameframe_tpu.parallel.mesh import SHARD_AXIS
+    from noahgameframe_tpu.parallel.shard import world_shardings
+    from noahgameframe_tpu.parallel.spatial import SpatialGeom, SpatialWorld
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (SHARD_AXIS,))
+    geom = SpatialGeom(
+        extent=256.0, cell_size=4.0, width=64, n_shards=4, bucket=16,
+        att_bucket=8, radius=4.0, mig_budget=64, speed=1.0,
+        attack_period=30)
+    sw = SpatialWorld(geom, mesh=mesh)
+    sw.bank_size = 2048
+    sw._build_kernel(4 * sw.bank_size)  # state stays on the CPU
+    k = sw.kernel
+    k._ensure_aux()
+    shardings = world_shardings(k.state, mesh)
+
+    def step(st):
+        st2, _out = k._trace_step(st)
+        return st2
+
+    compiled = jax.jit(
+        step, in_shardings=(shardings,), out_shardings=shardings,
+        donate_argnums=0,
+    ).lower(_shapes(k.state, shardings)).compile()
+    assert "collective-permute" in compiled.as_text()
+    # each device holds a quarter of the banks, not all of them
+    bank = 4 * sw.bank_size * (5 + 3) * 4  # i32[cap,5] + f32[cap,1,3]
+    assert compiled.memory_analysis().argument_size_in_bytes < bank

@@ -1,7 +1,7 @@
-"""Measured-tuning promotion (scripts/decide_tuning.py): the harvest
-queue's A/B captures elect the engine flags the driver bench runs with.
-Wrong promotion logic would silently pessimize (or break) the round's
-official benchmark, so the election rules are pinned here."""
+"""Measured-tuning promotion (scripts/decide_tuning.py): A/B captures
+taken on the chip elect the engine flags bench.py runs with.  Wrong
+promotion logic would silently pessimize (or break) the benchmark, so
+the election rules are pinned here."""
 
 import importlib.util
 import json
@@ -230,7 +230,7 @@ def test_train8_error_capture_not_elected(tmp_path, capsys):
     mod = _load(tmp_path)
     _w(tmp_path, "r05_tpu_1m.json", 100.0)
     _w(tmp_path, "r07_tpu_100k.json", 20.0)
-    _w(tmp_path, "r13_tpu_100k_train8.json", 1.0, error="tunnel died")
+    _w(tmp_path, "r13_tpu_100k_train8.json", 1.0, error="run cut short")
     got = _run(mod, capsys)
     assert "NF_TICK_TRAIN" not in got["env"]
 
