@@ -18,6 +18,7 @@ import time as _time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..telemetry.pipeline import TraceError, decode_trace, encode_trace
+from ..telemetry.tracing import span
 from ..net.defines import EventCode, MsgID
 from ..net.transport import EV_CONNECTED, EV_DISCONNECTED, EV_MSG, PyNetClient
 from ..net.wire import (
@@ -218,16 +219,17 @@ class GameClient:
     def execute(self) -> None:
         if self._conn is None:
             return
-        for ev in self._conn.poll():
-            if ev.kind == EV_CONNECTED:
-                self.connected = True
-            elif ev.kind == EV_DISCONNECTED:
-                self.connected = False
-            elif ev.kind == EV_MSG:
-                base = MsgBase.decode(ev.body)
-                fn = self._handlers.get(ev.msg_id)
-                if fn is not None:
-                    fn(base)
+        with span("client.pump"):
+            for ev in self._conn.poll():
+                if ev.kind == EV_CONNECTED:
+                    self.connected = True
+                elif ev.kind == EV_DISCONNECTED:
+                    self.connected = False
+                elif ev.kind == EV_MSG:
+                    base = MsgBase.decode(ev.body)
+                    fn = self._handlers.get(ev.msg_id)
+                    if fn is not None:
+                        fn(base)
 
     def _send(self, msg_id: int, msg: Message) -> bool:
         return self._conn is not None and self._conn.send_msg(
@@ -242,22 +244,29 @@ class GameClient:
             ctx = decode_trace(base.msg_data)
         except TraceError:
             return
-        ctx.client_recv_ns = _time.perf_counter_ns()
-        self.traces.append({
-            "tick": ctx.tick,
-            "game_id": ctx.game_id,
-            "seq": ctx.seq,
-            "proxy_relay_ms": (
-                (ctx.proxy_out_ns - ctx.proxy_in_ns) / 1e6
-                if ctx.proxy_out_ns and ctx.proxy_in_ns else None
-            ),
-        })
-        del self.traces[:-256]
-        if self._conn is not None:
-            self._conn.send_msg(
-                int(MsgID.FRAME_TRACE_ACK),
-                MsgBase(msg_data=encode_trace(ctx)).encode(),
-            )
+        with span("trace.recv", tick=ctx.tick, seq=ctx.seq):
+            ctx.client_recv_ns = _time.perf_counter_ns()
+            # all four stamps: game and proxy stamps are on their own
+            # processes' clocks (one clock in a LocalCluster)
+            self.traces.append({
+                "tick": ctx.tick,
+                "game_id": ctx.game_id,
+                "seq": ctx.seq,
+                "t_encode_ns": ctx.t_encode_ns,
+                "proxy_in_ns": ctx.proxy_in_ns,
+                "proxy_out_ns": ctx.proxy_out_ns,
+                "client_recv_ns": ctx.client_recv_ns,
+                "proxy_relay_ms": (
+                    (ctx.proxy_out_ns - ctx.proxy_in_ns) / 1e6
+                    if ctx.proxy_out_ns and ctx.proxy_in_ns else None
+                ),
+            })
+            del self.traces[:-256]
+            if self._conn is not None:
+                self._conn.send_msg(
+                    int(MsgID.FRAME_TRACE_ACK),
+                    MsgBase(msg_data=encode_trace(ctx)).encode(),
+                )
 
     # ------------------------------------------------------------- login flow
     def login(self) -> None:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ..core.datatypes import Guid
@@ -470,80 +471,88 @@ class CombatModule(Module):
             # masks by TRUE radius on current positions, so results stay
             # bit-identical to rebuilding every tick.
             aux_key = f"verlet/{cname}"
-            cache, rebuilt = refresh(
-                state.aux[aux_key], pos, cs.alive,
-                self.cell_size, self.width, bucket, self.verlet_skin,
-            )
+            with jax.named_scope("nf.aoe.rank"):
+                cache, rebuilt = refresh(
+                    state.aux[aux_key], pos, cs.alive,
+                    self.cell_size, self.width, bucket, self.verlet_skin,
+                )
             n_cells = self.width * self.width
             if engine == 2:
                 # slots only — the payload tables are never materialized
-                vic_bin = slots_from_assignment(
-                    cs.alive, cache.slot_of, n_cells,
-                    self.cell_size, self.width, bucket,
-                )
-                att_bin = slots_from_assignment(
-                    attacking, sub_slots(cache, attacking, n_cells, att_bucket),
-                    n_cells, self.cell_size, self.width, att_bucket,
-                )
+                with jax.named_scope("nf.aoe.rank"):
+                    vic_bin = slots_from_assignment(
+                        cs.alive, cache.slot_of, n_cells,
+                        self.cell_size, self.width, bucket,
+                    )
+                    att_bin = slots_from_assignment(
+                        attacking,
+                        sub_slots(cache, attacking, n_cells, att_bucket),
+                        n_cells, self.cell_size, self.width, att_bucket,
+                    )
             else:
-                vic_bin = full_table(
-                    cache, vic_feats, cs.alive, n_cells,
-                    self.cell_size, self.width, bucket,
-                )
-                att_bin = sub_table(
-                    cache, attacking, att_feats, n_cells,
-                    self.cell_size, self.width, att_bucket,
-                )
+                with jax.named_scope("nf.aoe.table"):
+                    vic_bin = full_table(
+                        cache, vic_feats, cs.alive, n_cells,
+                        self.cell_size, self.width, bucket,
+                    )
+                    att_bin = sub_table(
+                        cache, attacking, att_feats, n_cells,
+                        self.cell_size, self.width, att_bucket,
+                    )
             ctx.count("grid_rebuilds", rebuilt)
             ctx.count("grid_reuses", 1 - rebuilt)
             ctx.count("grid_cache_age", cache.age)
             state = state.replace(aux={**state.aux, aux_key: cache})
         elif engine == 2:
             # one key pass feeds both slot assignments, no payloads
-            vic_bin, att_bin = build_cell_slots_pair(
-                pos, cs.alive, attacking,
-                self.cell_size, self.width, bucket, att_bucket,
-            )
+            with jax.named_scope("nf.aoe.rank"):
+                vic_bin, att_bin = build_cell_slots_pair(
+                    pos, cs.alive, attacking,
+                    self.cell_size, self.width, bucket, att_bucket,
+                )
         else:
-            # one argsort feeds both tables (attackers subset of alive)
+            # one argsort feeds both tables (attackers subset of alive);
+            # this one call ranks and builds: it opens nf.aoe.rank and
+            # nf.aoe.table itself
             vic_bin, att_bin = build_cell_table_pair(
                 pos, cs.alive, vic_feats, attacking, att_feats,
                 self.cell_size, self.width, bucket, att_bucket,
             )
         nbr = None
-        if engine == 2:
-            from ..ops.stencil_pallas import (
-                fused_neighborhood,
-                pallas_interpret,
-            )
+        with jax.named_scope("nf.aoe.fold"):
+            if engine == 2:
+                from ..ops.stencil_pallas import (
+                    fused_neighborhood,
+                    pallas_interpret,
+                )
 
-            # one shared SoA bank serves both sides of the fold; the
-            # attacker row id is the gather index itself
-            bank = jnp.stack(
-                [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f, eff_atk],
-                axis=-1,
-            )
-            inc, bestr, nbr = fused_neighborhood(
-                bank,
-                vic_bin,
-                att_bin,
-                self.radius,
-                interpret=pallas_interpret(),
-            )
-        elif engine == 1:
-            from ..ops.stencil_pallas import (
-                combat_fold_pallas,
-                pallas_interpret,
-            )
+                # one shared SoA bank serves both sides of the fold; the
+                # attacker row id is the gather index itself
+                bank = jnp.stack(
+                    [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f, eff_atk],
+                    axis=-1,
+                )
+                inc, bestr, nbr = fused_neighborhood(
+                    bank,
+                    vic_bin,
+                    att_bin,
+                    self.radius,
+                    interpret=pallas_interpret(),
+                )
+            elif engine == 1:
+                from ..ops.stencil_pallas import (
+                    combat_fold_pallas,
+                    pallas_interpret,
+                )
 
-            inc, bestr = combat_fold_pallas(
-                vic_bin,
-                att_bin,
-                self.radius,
-                interpret=pallas_interpret(),
-            )
-        else:
-            inc, bestr = combat_fold_xla(vic_bin, att_bin, self.radius)
+                inc, bestr = combat_fold_pallas(
+                    vic_bin,
+                    att_bin,
+                    self.radius,
+                    interpret=pallas_interpret(),
+                )
+            else:
+                inc, bestr = combat_fold_xla(vic_bin, att_bin, self.radius)
         if self.emit_events:
             # runtime overflow signal: the duty-sized attacker bucket is
             # baked into the traced tick, so arming patterns that
@@ -565,17 +574,20 @@ class CombatModule(Module):
         # emit_events-gated overflow event above)
         ctx.count("aoi_victim_overflow_drops", vic_bin.dropped)
         ctx.count("aoi_attacker_overflow_drops", att_bin.dropped)
-        pulled = pull_slots(
-            vic_bin.slot_of, jnp.stack([inc, bestr], axis=-1), fill=(0, -1)
-        )
-        if nbr is not None:
-            # fused-path bonus output: the AOI/interest occupancy count
-            # per entity (scope per ops.interest.scope_mask, self
-            # excluded) — a counter, not state, so digests stay
-            # bit-identical across engines
-            ctx.count(
-                "aoi_interest_pairs", pull_slots(vic_bin.slot_of, nbr, fill=0)
+        with jax.named_scope("nf.aoe.pull"):
+            pulled = pull_slots(
+                vic_bin.slot_of, jnp.stack([inc, bestr], axis=-1),
+                fill=(0, -1),
             )
+            if nbr is not None:
+                # fused-path bonus output: the AOI/interest occupancy
+                # count per entity (scope per ops.interest.scope_mask,
+                # self excluded) — a counter, not state, so digests stay
+                # bit-identical across engines
+                ctx.count(
+                    "aoi_interest_pairs",
+                    pull_slots(vic_bin.slot_of, nbr, fill=0),
+                )
         incoming = pulled[..., 0]
         # dead-but-not-yet-respawned victims take no damage
         incoming = jnp.where(cs.alive & (hp > 0), incoming, 0)

@@ -19,7 +19,6 @@ each tick, fetching device data only when someone is listening.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import enum
 from fnmatch import fnmatch
@@ -36,6 +35,8 @@ from ..core.store import EntityStore, StoreConfig, WorldState
 from .events import DeviceEvent, EventModule
 from .module import Module, Phase
 from .schedule import ScheduleModule
+# after .module: telemetry's package imports kernel.module back
+from ..telemetry.tracing import SpanTracer
 
 
 class ObjectEvent(enum.IntEnum):
@@ -329,9 +330,10 @@ class Kernel(Module):
         # per-tick fingerprint, riding the summary fetch at zero extra
         # syncs.  Flip via enable_digest() so the tick is retraced.
         self.digest_enabled = False
-        # optional telemetry.SpanTracer for host-side tick stage spans
-        # (dispatch / summary fetch / post-tick fan-out); None = no cost
-        self.tracer = None
+        # host-side tick spans (nf.kernel.dispatch / .fetch / .fanout):
+        # in the profiler's trace whenever a session is open; a
+        # TelemetryModule swaps in its own tracer (the operator's ring)
+        self.tracer = SpanTracer(enabled=False)
         # back-pointer set by parallel/rooms.RoomBatch.attach() when this
         # kernel is the TEMPLATE for a room-batched world: its _trace_step
         # is vmapped over a leading [R] room axis and its own state/jit
@@ -339,7 +341,7 @@ class Kernel(Module):
         self.room_batch = None
         # honest per-stage timing (NF_STAGE_TIMING=1, set by GameRole /
         # telemetry/pipeline.stage_timing_enabled): block after dispatch
-        # so the kernel.dispatch span measures device time, not async
+        # so the nf.kernel.dispatch span measures device time, not async
         # enqueue latency.  Never on by default — it serializes the
         # device queue and kills dispatch/fetch overlap.
         self.stage_timing = False
@@ -491,47 +493,53 @@ class Kernel(Module):
         # kernel builtins.  Names are static per compilation (same contract
         # as _event_meta); values ride the summary fetch below, so the
         # telemetry surface costs ZERO extra device syncs per tick.
-        ev_counts = [jnp.sum(e.mask, dtype=jnp.int32) for e in ctx.emitted]
-        counters = dict(ctx._counters)
-        zero = jnp.zeros((), jnp.int32)
-        counters["deaths"] = sum(died_count.values(), zero)
-        counters["diff_cells"] = sum(diff_count.values(), zero)
-        counters["rec_diff_cells"] = sum(rec_diff_count.values(), zero)
-        counters["events_fired"] = sum(ev_counts, zero)
-        # the tick's own logical number (post-increment, i.e. the value
-        # tick_count reaches once this frame lands) rides in-lane so a
-        # K-tick train can stamp journal marks and death attribution
-        # with the REAL tick of each stacked frame, not the train's end
-        counters["tick"] = state.tick
+        digest = None
         if self.digest_enabled:
             # post-increment state, i.e. exactly what a checkpoint taken
             # after this tick would capture — replay compares like for like
-            counters["state_digest"] = jax.lax.bitcast_convert_type(
-                state_digest(state, self.store.class_order), jnp.int32
+            with jax.named_scope("nf.digest"):
+                digest = jax.lax.bitcast_convert_type(
+                    state_digest(state, self.store.class_order), jnp.int32
+                )
+        with jax.named_scope("nf.summary"):
+            ev_counts = [jnp.sum(e.mask, dtype=jnp.int32) for e in ctx.emitted]
+            counters = dict(ctx._counters)
+            zero = jnp.zeros((), jnp.int32)
+            counters["deaths"] = sum(died_count.values(), zero)
+            counters["diff_cells"] = sum(diff_count.values(), zero)
+            counters["rec_diff_cells"] = sum(rec_diff_count.values(), zero)
+            counters["events_fired"] = sum(ev_counts, zero)
+            # the tick's own logical number (post-increment, i.e. the value
+            # tick_count reaches once this frame lands) rides in-lane so a
+            # K-tick train can stamp journal marks and death attribution
+            # with the REAL tick of each stacked frame, not the train's end
+            counters["tick"] = state.tick
+            if digest is not None:
+                counters["state_digest"] = digest
+            self._counter_names = tuple(sorted(counters))
+            # ONE packed scalar vector per tick — the only thing the host
+            # ever synchronously fetches.  Anything else (masks, params,
+            # fired) is fetched lazily and only when this summary says
+            # there's something to see; every fetch is a device->host
+            # round trip, so this is the difference between 1 and
+            # O(classes+events) syncs per tick.
+            summary = jnp.concatenate(
+                [
+                    jnp.stack([died_count[c] for c in self.store.class_order])
+                    if self.store.class_order
+                    else jnp.zeros((0,), jnp.int32),
+                    jnp.stack([diff_count[c] for c in sorted(diff_count)])
+                    if diff_count
+                    else jnp.zeros((0,), jnp.int32),
+                    jnp.stack([rec_diff_count[c] for c in sorted(rec_diff_count)])
+                    if rec_diff_count
+                    else jnp.zeros((0,), jnp.int32),
+                    jnp.stack(ev_counts)
+                    if ctx.emitted
+                    else jnp.zeros((0,), jnp.int32),
+                    jnp.stack([counters[k] for k in self._counter_names]),
+                ]
             )
-        self._counter_names = tuple(sorted(counters))
-        # ONE packed scalar vector per tick — the only thing the host ever
-        # synchronously fetches.  Anything else (masks, params, fired) is
-        # fetched lazily and only when this summary says there's something
-        # to see; every fetch is a device->host round trip, so this is
-        # the difference between 1 and O(classes+events) syncs per tick.
-        summary = jnp.concatenate(
-            [
-                jnp.stack([died_count[c] for c in self.store.class_order])
-                if self.store.class_order
-                else jnp.zeros((0,), jnp.int32),
-                jnp.stack([diff_count[c] for c in sorted(diff_count)])
-                if diff_count
-                else jnp.zeros((0,), jnp.int32),
-                jnp.stack([rec_diff_count[c] for c in sorted(rec_diff_count)])
-                if rec_diff_count
-                else jnp.zeros((0,), jnp.int32),
-                jnp.stack(ev_counts)
-                if ctx.emitted
-                else jnp.zeros((0,), jnp.int32),
-                jnp.stack([counters[k] for k in self._counter_names]),
-            ]
-        )
         out = {
             "fired": fired,
             "diff": diff,
@@ -607,12 +615,6 @@ class Kernel(Module):
                 aux[k] = self._aux_init[k]()
             self.state = self.state.replace(aux=aux)
 
-    def _span(self, name: str):
-        """Host-side tracer span if a tracer is attached, else free."""
-        if self.tracer is not None:
-            return self.tracer.span(name)
-        return contextlib.nullcontext()
-
     def tick(self) -> TickOutputs:
         """Advance the world one frame and fan out host-visible effects."""
         return self.tick_finish(self.tick_begin())
@@ -630,7 +632,7 @@ class Kernel(Module):
         run before tick_begin."""
         self.compile()
         self._ensure_aux()
-        with self._span("kernel.dispatch"):
+        with self.tracer.span("kernel.dispatch"):
             self.state, raw = self._jit_step(self.state)
             if self.stage_timing:
                 jax.block_until_ready((self.state, raw))
@@ -655,7 +657,7 @@ class Kernel(Module):
                 )
             ],
         )
-        with self._span("kernel.summary_fetch"):
+        with self.tracer.span("kernel.fetch"):
             summary = np.asarray(raw["summary"])
         # decode the counter bank from the summary tail (names captured at
         # trace time, same static-metadata contract as _event_meta)
@@ -668,7 +670,7 @@ class Kernel(Module):
                 if k in ("state_digest", "tick"):
                     continue  # a hash / a stamp; summing either is noise
                 self.counter_totals[k] = self.counter_totals.get(k, 0) + v
-        with self._span("kernel.post_tick"):
+        with self.tracer.span("kernel.fanout"):
             self._post_tick(out, summary)
         return out
 
@@ -775,7 +777,7 @@ class Kernel(Module):
         contract as tick_begin, K frames deep."""
         self.compile_train()
         self._ensure_aux()
-        with self._span("kernel.dispatch"):
+        with self.tracer.span("kernel.dispatch"):
             self.state, raw = self._jit_train(self.state)
             if self.stage_timing:
                 jax.block_until_ready((self.state, raw))
@@ -792,7 +794,7 @@ class Kernel(Module):
         Deaths are attributed from each lane's own died mask (the final
         carried state cannot say WHICH tick killed a row)."""
         k = self._train_k
-        with self._span("kernel.summary_fetch"):
+        with self.tracer.span("kernel.fetch"):
             summary = np.asarray(raw["summary"])  # [K, L]
         self.train_fetch_bytes += summary.nbytes
         stacked = {kk: vv for kk, vv in raw.items() if kk != "summary"}
@@ -827,7 +829,7 @@ class Kernel(Module):
                     self.counter_totals[kk] = (
                         self.counter_totals.get(kk, 0) + v
                     )
-            with self._span("kernel.post_tick"):
+            with self.tracer.span("kernel.fanout"):
                 self._post_tick(out, row, exact_deaths=True)
             outs.append(out)
         return outs
@@ -865,28 +867,31 @@ class Kernel(Module):
         # device-emitted events FIRST — entities that died this tick must
         # still deliver their events (the reference fires events before
         # destroy), so guid identities are intact here
-        live_events = [
-            ev for ev, cnt in zip(out.events, event_counts) if cnt > 0
-        ]
-        if live_events:
-            self.events.dispatch_device_events(live_events, self.store)
+        span = self.tracer.span
+        with span("fanout.events"):
+            live_events = [
+                ev for ev, cnt in zip(out.events, event_counts) if cnt > 0
+            ]
+            if live_events:
+                self.events.dispatch_device_events(live_events, self.store)
         # deaths: reconcile host allocation + fire destroy events.
         # exact_deaths (the train path) frees the rows named by THIS
         # frame's died mask — the carried post-train state's alive mask
         # would pin every death to the train's last tick, so attribution
         # must come from the lane, not from reconcile's final-state scan
-        for cname, cnt in zip(self.store.class_order, died_counts):
-            if int(cnt) == 0:
-                continue
-            if exact_deaths:
-                rows = np.flatnonzero(np.asarray(out.died[cname]))
-                dead = self.store.release_rows(cname, rows)
-            else:
-                dead = self.store.reconcile_deaths(self.state, cname)
-            for g in dead:
-                self._fire_class_event(g, cname, ObjectEvent.DESTROY)
+        with span("fanout.deaths"):
+            for cname, cnt in zip(self.store.class_order, died_counts):
+                if int(cnt) == 0:
+                    continue
+                if exact_deaths:
+                    rows = np.flatnonzero(np.asarray(out.died[cname]))
+                    dead = self.store.release_rows(cname, rows)
+                else:
+                    dead = self.store.reconcile_deaths(self.state, cname)
+                for g in dead:
+                    self._fire_class_event(g, cname, ObjectEvent.DESTROY)
         # property-change host subscribers (batch granularity)
-        if self._prop_event_subs:
+        with span("fanout.props"):
             for (cname, pname), fns in self._prop_event_subs.items():
                 masks = out.diff.get(cname)
                 if not masks:
@@ -903,7 +908,7 @@ class Kernel(Module):
                     for fn in fns:
                         fn(cname, pname, rows)
         # record-diff subscribers (device-path record mutations)
-        if self._rec_event_subs:
+        with span("fanout.records"):
             for (cname, rname), fns in self._rec_event_subs.items():
                 if int(rec_counts.get(cname, 0)) == 0:
                     continue

@@ -21,6 +21,8 @@ from typing import Dict, List, Optional, Sequence, Type, TypeVar
 
 from .kernel import Kernel
 from .module import LIFECYCLE, SHUTDOWN, Module
+# after .module: telemetry's package imports kernel.module back
+from ..telemetry.tracing import span
 
 M = TypeVar("M", bound=Module)
 
@@ -122,13 +124,22 @@ class PluginManager:
             self.kernel.compile()
         self._started = True
 
+    def execute_modules(self) -> None:
+        """Host execute() on every module, the kernel's own last: the one
+        place a frame's host modules run (run_once, GameRole.execute)."""
+        with span("tick.modules"):
+            for m in self.modules.values():
+                if m is not self.kernel:
+                    with span("module." + m.name):
+                        m.execute()
+            if self.kernel is not None:
+                with span("module." + self.kernel.name):
+                    self.kernel.execute()
+
     def run_once(self) -> None:
         """One frame: host execute() on every module, then the device tick."""
-        for m in self.modules.values():
-            if m is not self.kernel:
-                m.execute()
+        self.execute_modules()
         if self.kernel is not None:
-            self.kernel.execute()
             self.kernel.tick()
         self.frame += 1
 

@@ -29,6 +29,7 @@ from typing import Dict, List, Optional
 
 from ..defines import MsgID, ServerState, ServerType
 from ..module import NetClientModule, NetServerModule
+from ...telemetry.tracing import span
 from ..wire import (
     Ident,
     MsgBase,
@@ -102,6 +103,7 @@ class ServerRole:
             self.transport_backend)
         self.clients: Dict[str, NetClientModule] = {}
         self.state = int(ServerState.NORMAL)
+        self._span_name = "role." + ServerType(self.server_type).name.lower()
         # telemetry: one registry per role.  A role that owns a world
         # (GameRole sets self.game_world before super().__init__) adopts
         # the world's TelemetryModule so /metrics includes the kernel's
@@ -214,7 +216,12 @@ class ServerRole:
         return None
 
     def execute(self, now: Optional[float] = None) -> None:
-        now = _time.monotonic() if now is None else now
+        """One pump pass of this role, as the host span
+        ``nf.role.<type>``; what a role does in it is its `_pump`."""
+        with span(self._span_name):
+            self._pump(_time.monotonic() if now is None else now)
+
+    def _pump(self, now: float) -> None:
         self.server.execute(self.inbound_budget_seconds())
         for pool in self.clients.values():
             pool.execute(now)

@@ -36,6 +36,7 @@ from ...telemetry.pipeline import (
     stage_timing_enabled,
     trace_sample_n,
 )
+from ...telemetry.tracing import span
 from ...core.store import RecordOp
 from ...game.world import GameWorld, WorldConfig
 from ...kernel.kernel import (
@@ -790,11 +791,12 @@ class GameRole(ServerRole):
                  msg: Message) -> None:
         # "send" stage = envelope encode + transport write; add_ns keeps
         # it exclusive of whichever stage (interest/encode) called us
-        t0 = _time.perf_counter_ns()
-        self.server.send_raw(
-            conn_id, int(msg_id), wrap(msg, clients=list(idents))
-        )
-        self.stage_clock.add_ns("send", _time.perf_counter_ns() - t0)
+        with span("stage.send"):
+            t0 = _time.perf_counter_ns()
+            self.server.send_raw(
+                conn_id, int(msg_id), wrap(msg, clients=list(idents))
+            )
+            self.stage_clock.add_ns("send", _time.perf_counter_ns() - t0)
 
     # ------------------------------------------------------ wire tracing
     def _emit_frame_traces(self) -> None:
@@ -808,19 +810,23 @@ class GameRole(ServerRole):
                 continue
             self._trace_seq = (self._trace_seq + 1) & 0xFFFFFFFF
             seq = self._trace_seq
-            t_enc = _time.perf_counter_ns()
-            ctx = TraceContext(tick=self.kernel.tick_count,
-                               game_id=self.config.server_id,
-                               seq=seq, t_encode_ns=t_enc)
-            self._trace_pending[seq] = (self.kernel.tick_count, t_enc)
-            while len(self._trace_pending) > 4096:  # lost acks: drop oldest
-                self._trace_pending.pop(next(iter(self._trace_pending)))
-            base = MsgBase(player_id=sess.ident,
-                           msg_data=encode_trace(ctx),
-                           player_client_list=[sess.ident])
-            self.server.send_raw(
-                sess.conn_id, int(MsgID.FRAME_TRACE), base.encode()
-            )
+            tick = self.kernel.tick_count
+            # one span per sampled sidecar, the finest grain a span has:
+            # (tick, seq) joins it with the proxy's and the client's
+            with span("trace.emit", tick=tick, seq=seq):
+                t_enc = _time.perf_counter_ns()
+                ctx = TraceContext(tick=tick,
+                                   game_id=self.config.server_id,
+                                   seq=seq, t_encode_ns=t_enc)
+                self._trace_pending[seq] = (tick, t_enc)
+                while len(self._trace_pending) > 4096:  # lost acks
+                    self._trace_pending.pop(next(iter(self._trace_pending)))
+                base = MsgBase(player_id=sess.ident,
+                               msg_data=encode_trace(ctx),
+                               player_client_list=[sess.ident])
+                self.server.send_raw(
+                    sess.conn_id, int(MsgID.FRAME_TRACE), base.encode()
+                )
             self.trace_sent += 1
 
     def _on_frame_trace_ack(self, _conn_id: int, _msg_id: int,
@@ -1880,9 +1886,8 @@ class GameRole(ServerRole):
             fn(sess.guid, int(req.row))
 
     # ------------------------------------------------------------ tick + sync
-    def execute(self, now: Optional[float] = None) -> None:
-        now = _time.monotonic() if now is None else now
-        super().execute(now)
+    def _pump(self, now: float) -> None:
+        super()._pump(now)
         pm = self.game_world.pm
         sc = self.stage_clock
         tick_due = now - self._last_tick >= self.game_world.config.dt
@@ -1892,7 +1897,11 @@ class GameRole(ServerRole):
                                   or self._interest_dirty
                                   or self._serve_pending)
         if framed:
-            sc.frame_begin(self.kernel.tick_count)
+            # the frame is named by the tick it serves (the count its
+            # FRAME_TRACE sidecars will carry), so every span of one
+            # served frame, on the wire too, shares one number
+            sc.frame_begin(self.kernel.tick_count
+                           + ((self.tick_train or 1) if tick_due else 0))
         flushed = False
         # overlap mode: interest lanes deferred by the last flush, to be
         # served against THIS frame's pre-tick snapshot (sync_classes
@@ -1907,12 +1916,9 @@ class GameRole(ServerRole):
         if tick_due:
             self._last_tick = now
             ticks_this_frame = self.tick_train or 1
-            with self.telemetry.tracer.span("game.tick"), sc.stage("tick"):
+            with sc.stage("tick"):
                 t0 = _time.perf_counter()
-                for m in pm.modules.values():
-                    if m is not self.kernel:
-                        m.execute()
-                self.kernel.execute()
+                pm.execute_modules()
                 if pend_classes:
                     # double-buffered serve: fetch the deferred lanes'
                     # deltas from the pre-tick state (the donated buffers

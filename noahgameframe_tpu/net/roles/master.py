@@ -469,9 +469,8 @@ class MasterRole(ServerRole):
         )
 
     # ------------------------------------------------------------ pump
-    def execute(self, now: Optional[float] = None) -> None:
-        now = _time.monotonic() if now is None else now
-        super().execute(now)
+    def _pump(self, now: float) -> None:
+        super()._pump(now)
         self._sweep_leases(now)
         if self.http is not None:
             self.http.execute()

@@ -16,9 +16,10 @@ from __future__ import annotations
 
 import hmac
 import time as _time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 from ...telemetry.pipeline import TraceError, decode_trace, encode_trace
+from ...telemetry.tracing import span
 from ..defines import EventCode, MsgID, ServerState, ServerType, SwitchNoticeCode
 from ..failover import ParkingBuffer
 from ..module import NORMAL, NetClientModule
@@ -366,14 +367,15 @@ class ProxyRole(ServerRole):
         targets = base.player_client_list or (
             [base.player_id] if base.player_id is not None else []
         )
-        ctx.proxy_in_ns = arrival
-        ctx.proxy_out_ns = _time.perf_counter_ns()
-        base.msg_data = encode_trace(ctx)
-        out = base.encode()
-        for ident in targets:
-            conn_id = self._client_conn.get(_ident_key(ident))
-            if conn_id is not None:
-                self.server.send_raw(conn_id, msg_id, out)
+        with span("trace.relay", tick=ctx.tick, seq=ctx.seq):
+            ctx.proxy_in_ns = arrival
+            ctx.proxy_out_ns = _time.perf_counter_ns()
+            base.msg_data = encode_trace(ctx)
+            out = base.encode()
+            for ident in targets:
+                conn_id = self._client_conn.get(_ident_key(ident))
+                if conn_id is not None:
+                    self.server.send_raw(conn_id, msg_id, out)
         self.traces_relayed += 1
         done = _time.perf_counter_ns()
         self.games.counters.count_relay(msg_id, done - arrival)
@@ -414,9 +416,8 @@ class ProxyRole(ServerRole):
         self.games.counters.count_relay(msg_id, done - self._relay_arrival_ns)
         self._relay_hist.observe((done - self._relay_arrival_ns) / 1e9)
 
-    def execute(self, now: Optional[float] = None) -> None:
-        now = _time.monotonic() if now is None else now
-        super().execute(now)
+    def _pump(self, now: float) -> None:
+        super()._pump(now)
         self._parking_pump(now)
 
     def _parking_pump(self, now: float) -> None:

@@ -325,9 +325,8 @@ class WorldRole(ServerRole):
         self._push_game_list()
 
     # ------------------------------------------------------------ pump
-    def execute(self, now: Optional[float] = None) -> None:
-        now = _time.monotonic() if now is None else now
-        super().execute(now)
+    def _pump(self, now: float) -> None:
+        super()._pump(now)
         self._sweep_leases(now)
         if self.failover is not None:
             self.failover.execute(now)

@@ -354,16 +354,19 @@ def _build_pair_counting(
     ordinal among SUB members of its cell — same contract as the sort
     path's segmented cumsum (a row overflowing the full table can still
     hold a valid subset slot)."""
-    slot_of = _counting_slots(key, n_cells, bucket)
-    full = table_from_slots(
-        features, active, slot_of, n_cells, cell_size, width, bucket, height
-    )
-    sub_key = jnp.where(sub_mask, key, n_cells)
-    sub_slots = _counting_slots(sub_key, n_cells, sub_bucket)
-    sub = table_from_slots(
-        sub_features, sub_mask, sub_slots, n_cells, cell_size, width,
-        sub_bucket, height,
-    )
+    with jax.named_scope("nf.aoe.rank"):
+        slot_of = _counting_slots(key, n_cells, bucket)
+        sub_key = jnp.where(sub_mask, key, n_cells)
+        sub_slots = _counting_slots(sub_key, n_cells, sub_bucket)
+    with jax.named_scope("nf.aoe.table"):
+        full = table_from_slots(
+            features, active, slot_of, n_cells, cell_size, width, bucket,
+            height,
+        )
+        sub = table_from_slots(
+            sub_features, sub_mask, sub_slots, n_cells, cell_size, width,
+            sub_bucket, height,
+        )
     return full, sub
 
 
@@ -557,40 +560,55 @@ def build_cell_table_pair(
     segmented cumsum over the shared sorted order.
 
     cell/height: precomputed cell ids over a rectangular [height, width]
-    grid (spatial slab shards); default square grid derived from pos."""
+    grid (spatial slab shards); default square grid derived from pos.
+
+    The one call here that both ranks and builds, and only combat makes
+    it, so it opens the device scopes `nf.aoe.rank` and `nf.aoe.table`
+    itself; every other scope of the neighbour engine is opened by the
+    caller (game/combat.py), because the interest programs share this
+    file."""
     n_rows = height if height > 0 else width
+    n = pos.shape[0]
     mode = binning_mode()
     if mode == "count":
-        n_cells, key = _cell_keys(
-            pos, active, cell_size, width, cell=cell,
-            n_cells=(n_rows * width if cell is not None else None),
-        )
+        with jax.named_scope("nf.aoe.rank"):
+            n_cells, key = _cell_keys(
+                pos, active, cell_size, width, cell=cell,
+                n_cells=(n_rows * width if cell is not None else None),
+            )
         return _build_pair_counting(
             features, active, sub_mask, sub_features, key, n_cells,
             cell_size, width, bucket, sub_bucket, height,
         )
     if mode != "sort":
         raise ValueError(f"unhandled binning mode {mode!r}")  # pragma: no cover
-    n_cells, order, skey, seg_start, rank = _sorted_segments(
-        pos, active, cell_size, width, cell=cell,
-        n_cells=(n_rows * width if cell is not None else None),
-    )
-    full = _finish_table(
-        features, active, n_cells, order, skey, rank, cell_size, width,
-        bucket, height,
-    )
-    # subset ranks via segmented exclusive cumsum: ex is non-decreasing,
-    # so "ex at my segment's head" is a cummax over heads — no gather.
-    # Non-members get an out-of-range rank so _finish_table sends them
-    # to the dump slot.
-    sub_sorted = sub_mask[order]
-    ex = jnp.cumsum(sub_sorted.astype(jnp.int32)) - sub_sorted.astype(jnp.int32)
-    head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
-    sub_rank = jnp.where(sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
-    sub = _finish_table(
-        sub_features, sub_mask, n_cells, order, skey, sub_rank,
-        cell_size, width, sub_bucket, height,
-    )
+    with jax.named_scope("nf.aoe.rank"):
+        n_cells, order, skey, seg_start, rank = _sorted_segments(
+            pos, active, cell_size, width, cell=cell,
+            n_cells=(n_rows * width if cell is not None else None),
+        )
+        slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
+        # subset ranks via segmented exclusive cumsum: ex is
+        # non-decreasing, so "ex at my segment's head" is a cummax over
+        # heads — no gather.  Non-members get an out-of-range rank so
+        # _slots_from_ranks sends them to the dump slot.
+        sub_sorted = sub_mask[order]
+        ex = (jnp.cumsum(sub_sorted.astype(jnp.int32))
+              - sub_sorted.astype(jnp.int32))
+        head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
+        sub_rank = jnp.where(
+            sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
+        sub_slot_of = _slots_from_ranks(
+            n, n_cells, order, skey, sub_rank, sub_bucket)
+    with jax.named_scope("nf.aoe.table"):
+        full = table_from_slots(
+            features, active, slot_of, n_cells, cell_size, width, bucket,
+            height,
+        )
+        sub = table_from_slots(
+            sub_features, sub_mask, sub_slot_of, n_cells, cell_size, width,
+            sub_bucket, height,
+        )
     return full, sub
 
 

@@ -39,6 +39,8 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
+from .tracing import span
+
 __all__ = [
     "StageClock",
     "TraceContext",
@@ -75,7 +77,7 @@ def stage_timing_enabled() -> bool:
     """``NF_STAGE_TIMING=1``: honest per-stage device timing.
 
     Inserts a ``block_until_ready`` after the compiled dispatch so the
-    ``kernel.dispatch`` span measures real device time instead of async
+    ``nf.kernel.dispatch`` span measures real device time instead of async
     dispatch latency.  Never on by default — it serializes the device
     queue and de-fuses the production overlap.
     """
@@ -92,18 +94,21 @@ class _StageCtx:
     Exclusive-time accounting: on exit the *full* interval is charged to
     the parent's child-counter while only ``interval - child_time`` is
     charged to this stage, so nesting ``send`` inside ``encode`` never
-    double-counts.
+    double-counts.  The interval is also the host span
+    ``nf.stage.<name>`` (telemetry/tracing.py), outside the clock reads.
     """
 
-    __slots__ = ("_clock", "_name", "_t0", "_child_ns")
+    __slots__ = ("_clock", "_name", "_t0", "_child_ns", "_span")
 
     def __init__(self, clock: "StageClock", name: str):
         self._clock = clock
         self._name = name
         self._t0 = 0
         self._child_ns = 0
+        self._span = span("stage." + name)
 
     def __enter__(self):
+        self._span.__enter__()
         self._t0 = time.perf_counter_ns()
         self._child_ns = 0
         self._clock._stack.append(self)
@@ -118,6 +123,7 @@ class _StageCtx:
         )
         if clock._stack:
             clock._stack[-1]._child_ns += dur
+        self._span.__exit__(*exc)
         return False
 
 
@@ -126,8 +132,8 @@ class StageClock:
 
     Usage (one frame)::
 
-        sc.frame_begin(tick)
-        with sc.stage("tick"): ...
+        sc.frame_begin(tick)             # opens the host span nf.frame
+        with sc.stage("tick"): ...        # ... and nf.stage.tick
         with sc.stage("encode"):
             with sc.stage("send"): ...   # excluded from "encode"
         sc.frame_end()
@@ -152,6 +158,7 @@ class StageClock:
         # waterfall (`last`, `other`, wall) stays exact.
         self._scale: Dict[str, int] = {}
         self._frame_t0 = 0
+        self._frame_span = None  # nf.frame, open from begin to end
         self.last: Dict[str, int] = {}
         self.last_tick = -1
         self.last_wall_ns = 0
@@ -186,10 +193,21 @@ class StageClock:
         self._stack = []
         self._scale = {}
         self.last_tick = int(tick)
+        self._close_frame_span()  # a frame abandoned by an exception
+        # the frame's tick is the id every span of one served frame
+        # shares: children by nesting, the wire spans carry it
+        self._frame_span = span("frame", tick=self.last_tick)
+        self._frame_span.__enter__()
         self._frame_t0 = time.perf_counter_ns()
+
+    def _close_frame_span(self) -> None:
+        if self._frame_span is not None:
+            self._frame_span.__exit__(None, None, None)
+            self._frame_span = None
 
     def frame_end(self) -> Dict[str, int]:
         wall = time.perf_counter_ns() - self._frame_t0
+        self._close_frame_span()
         acc = self._acc
         attributed = sum(acc.values())
         acc["other"] = max(0, wall - attributed)

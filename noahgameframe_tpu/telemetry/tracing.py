@@ -1,20 +1,26 @@
-"""Host-side span tracing: ring buffer + Chrome trace-event export.
+"""Host-side spans: one seam, one vocabulary (``nf.*``), one clock.
 
-Two complementary timelines answer "where does a tick go":
+Two timelines answer "where does a tick go", and both carry the same
+names:
 
-- DEVICE stages: the kernel wraps every phase of the compiled tick in
-  ``jax.named_scope``, so an XProf capture (``jax.profiler``) shows
-  per-stage device time under those names.  Nothing to do here — the
-  scopes ride the HLO metadata.
-- HOST framing: this tracer records wall-clock spans (dispatch, summary
-  fetch, post-tick fan-out, sync flush, net pump) into a fixed-size
-  ring buffer and exports them as Chrome trace-event JSON —
-  ``chrome://tracing`` / https://ui.perfetto.dev load the file directly.
+- DEVICE: the kernel wraps every phase of the compiled tick in
+  ``jax.named_scope`` (``nf.schedule``, ``nf.phase.*``, ``nf.aoe.*``,
+  ``nf.diff``, ``nf.digest``, ``nf.summary``); the scopes ride the HLO
+  metadata, so a profiler capture attributes device time to them.
+- HOST: :func:`span` is the only way the program opens a host span.
+  It enters a ``jax.profiler.TraceAnnotation("nf." + name, **args)``,
+  which lands in the ``/host:CPU`` plane of the same ``xplane.pb`` as
+  the device's ``XLA Ops``, on the same clock.  An open profiler session
+  (``jax.profiler.start_trace``) is the one switch: with none open a
+  span is a shared no-op context manager, and without jax (the client
+  SDK imports this module) it always is.
 
-The tracer is DISABLED by default: ``span()`` then returns a shared
-no-op context manager, so instrumented hot paths pay one attribute read
-and a truthiness check per span.  scripts/export_trace.py shows the
-intended capture workflow.
+:class:`SpanTracer` adds the operator's ring: with ``enabled`` set,
+``tracer.span`` ALSO records the span (same ``nf.*`` name, wall clock)
+into a fixed-size buffer that exports Chrome trace-event JSON for
+``chrome://tracing`` / https://ui.perfetto.dev — no profiler needed.
+scripts/export_trace.py shows that capture workflow;
+docs/OBSERVABILITY.md lists the vocabulary.
 """
 
 from __future__ import annotations
@@ -26,27 +32,47 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+except ImportError:  # the SDK runs without jax: every span is a no-op
+    _Annotation = None
+
+PREFIX = "nf."
 _NULL_CTX = contextlib.nullcontext()
 
 
+def span(name: str, **args):
+    """Context manager: the host span ``nf.<name>`` in the profiler's
+    trace while a profiler session is open, else a shared no-op.  Keep
+    it out of loops over entities or over all sessions."""
+    if _Annotation is None or not _Annotation.is_enabled():
+        return _NULL_CTX
+    return _Annotation(PREFIX + name, **args)
+
+
 class _Span:
-    """Re-entrant-safe timed block writing one complete ("X") event."""
+    """Re-entrant-safe timed block writing one complete ("X") event to
+    the ring, around the profiler's span of the same name."""
 
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "inner")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0
+        self.inner = span(name, **args)
 
     def __enter__(self) -> "_Span":
+        self.inner.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter_ns()
-        self.tracer._record(self.name, self.t0, t1 - self.t0, self.args)
+        self.tracer._record(PREFIX + self.name, self.t0, t1 - self.t0,
+                            self.args or None)
+        self.inner.__exit__(*exc)
 
 
 class SpanTracer:
@@ -62,16 +88,16 @@ class SpanTracer:
 
     # ------------------------------------------------------------ record
     def span(self, name: str, **args):
-        """Context manager timing a block; no-op when disabled."""
+        """:func:`span`, and with the ring ``enabled`` a record there."""
         if not self.enabled:
-            return _NULL_CTX
-        return _Span(self, name, args or None)
+            return span(name, **args)
+        return _Span(self, name, args)
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event."""
         if not self.enabled:
             return
-        self._record(name, time.perf_counter_ns(), -1, args or None)
+        self._record(PREFIX + name, time.perf_counter_ns(), -1, args or None)
 
     def _record(self, name: str, t0_ns: int, dur_ns: int,
                 args: Optional[dict]) -> None:
@@ -159,17 +185,3 @@ def _jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
     return str(v)
-
-
-@contextlib.contextmanager
-def device_annotation(name: str):
-    """jax.profiler.TraceAnnotation when available (shows the host block
-    on the XProf timeline next to the device stream), else a no-op —
-    keeps call sites importable without jax."""
-    try:
-        import jax
-
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    except Exception:  # noqa: BLE001 — profiler backends vary by platform
-        yield

@@ -194,10 +194,10 @@ def test_chrome_trace_export(tmp_path):
     evs = doc["traceEvents"]
     assert any(e["ph"] == "M" and e["name"] == "process_name" for e in evs)
     xs = [e for e in evs if e["ph"] == "X"]
-    assert {e["name"] for e in xs} == {"outer", "inner"}
+    assert {e["name"] for e in xs} == {"nf.outer", "nf.inner"}
     for e in xs:
         assert e["ts"] >= 0 and e["dur"] >= 0
-    assert any(e["ph"] == "i" and e["name"] == "marker" for e in evs)
+    assert any(e["ph"] == "i" and e["name"] == "nf.marker" for e in evs)
 
 
 def test_tracer_disabled_records_nothing():
@@ -214,7 +214,7 @@ def test_tracer_ring_overwrites():
             pass
     names = [e[0] for e in tr.events()]
     assert len(names) == 4
-    assert names == ["s6", "s7", "s8", "s9"]
+    assert names == ["nf.s6", "nf.s7", "nf.s8", "nf.s9"]
 
 
 # ------------------------------------------------- satellites: utils.metrics

@@ -218,9 +218,9 @@ class TestChromeTraceMerge:
         from noahgameframe_tpu.telemetry.tracing import SpanTracer
 
         a, b = SpanTracer(enabled=True), SpanTracer(enabled=True)
-        with a.span("game.tick"):
+        with a.span("stage.tick"):
             pass
-        with b.span("proxy.relay"):
+        with b.span("trace.relay"):
             pass
         off = (b.epoch_ns - a.epoch_ns) / 1e3  # same-clock alignment
         merged = merge_chrome_traces(
@@ -228,5 +228,5 @@ class TestChromeTraceMerge:
             offsets_us=[0.0, off],
         )
         names = {e["name"] for e in merged["traceEvents"]}
-        assert {"game.tick", "proxy.relay"} <= names
+        assert {"nf.stage.tick", "nf.trace.relay"} <= names
         assert {e["pid"] for e in merged["traceEvents"]} == {1, 2}
