@@ -576,6 +576,17 @@ class GameRole(ServerRole):
             "nf_train_fetch_bytes_total",
             "stacked [K, ...] summary-lane bytes fetched per train",
         )
+        # property fan-out accounting (mirrors kernel.fanout_mask_*)
+        self._fanout_mask_fetches = sreg.counter(
+            "nf_fanout_mask_fetches_total",
+            "diff masks read whole for the property fan-out, one per "
+            "(class, bank) with a subscribed column on a tick that "
+            "changed the class",
+        )
+        self._fanout_mask_bytes = sreg.counter(
+            "nf_fanout_mask_bytes_total",
+            "bytes of diff mask the property fan-out read from the device",
+        )
         self._stage_timing = stage_timing_enabled()
         self.kernel.stage_timing = self._stage_timing
         self._trace_sample = trace_sample_n()
@@ -1919,6 +1930,8 @@ class GameRole(ServerRole):
             with sc.stage("tick"):
                 t0 = _time.perf_counter()
                 pm.execute_modules()
+                f0, fb0 = (self.kernel.fanout_mask_fetches,
+                           self.kernel.fanout_mask_bytes)
                 if pend_classes:
                     # double-buffered serve: fetch the deferred lanes'
                     # deltas from the pre-tick state (the donated buffers
@@ -1952,6 +1965,10 @@ class GameRole(ServerRole):
                         self.kernel.train_fetch_bytes - b0)
                 else:
                     self.kernel.tick()
+                self._fanout_mask_fetches.inc(
+                    self.kernel.fanout_mask_fetches - f0)
+                self._fanout_mask_bytes.inc(
+                    self.kernel.fanout_mask_bytes - fb0)
                 pm.frame += ticks_this_frame
                 # per-tick latency even under trains: one train frame is
                 # K ticks of device work behind one dispatch

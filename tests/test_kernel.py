@@ -5,6 +5,10 @@ objects with property callbacks, heartbeats and events, driven through the
 plugin-manager lifecycle (reference Tutorial/Tutorial3/HelloWorld3Module).
 """
 
+import contextlib
+import logging
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -309,3 +313,217 @@ def test_set_phases_replaces_not_accumulates():
     kernel.compile()
     pm.run(2)  # one firing
     assert kernel.get_property(g, "HP") == 15  # +5 once, not twice
+
+
+# ------------------------------------------------ property fan-out (PR 25)
+
+class ChurnModule(Module):
+    """Writes i32, f32 and vec columns of two classes on a schedule that
+    leaves some columns, and every fourth tick the whole world, unchanged."""
+
+    name = "ChurnModule"
+
+    def init(self):
+        self.add_phase("churn", self.phase_churn, order=60)
+
+    def phase_churn(self, state, ctx):
+        t = state.tick
+        busy = t % 4 != 3  # a tick of every four changes nothing
+        classes = dict(state.classes)
+
+        def bump(cname, pname, where, by):
+            cs = classes[cname]
+            slot = ctx.store.spec(cname).slot(pname)
+            bank = getattr(cs, slot.bank.value)
+            rows = jnp.arange(bank.shape[0])
+            hit = where(rows) & busy
+            hit = hit.reshape(hit.shape + (1,) * (bank.ndim - 2))
+            col = bank[:, slot.col] + jnp.where(hit, by, 0).astype(bank.dtype)
+            classes[cname] = cs.replace(
+                **{slot.bank.value: bank.at[:, slot.col].set(col)})
+
+        bump("NPC", "HP", lambda r: (r + t) % 3 == 0, 1)
+        bump("NPC", "ATK_VALUE", lambda r: (r % 2 == 0) & (t % 2 == 0), 1)
+        bump("NPC", "MoveSpeed", lambda r: r >= 0, 0.25)  # no diff flag
+        bump("NPC", "Position", lambda r: (r % 4 == 1) & (t % 2 == 1), 1.0)
+        bump("Player", "MoveSpeed", lambda r: (r + t) % 2 == 0, 0.5)
+        bump("Player", "Level", lambda r: (r >= 0) & (t % 3 == 0), 1)
+        return state.replace(classes=classes)
+
+
+# registration order, classes interleaved; NPC.HP has two subscribers
+FANOUT_SUBS = [
+    ("NPC", "HP", "a"), ("Player", "MoveSpeed", "a"), ("NPC", "Position", "a"),
+    ("NPC", "HP", "b"), ("Player", "Level", "a"), ("NPC", "ATK_VALUE", "a"),
+    ("NPC", "MAXHP", "a"), ("Player", "Position", "a"),
+    ("NPC", "MoveSpeed", "a"), ("NPC", "TargetPos", "a"),
+]
+
+
+def build_churn(subscribe=True, cap=64):
+    pm = PluginManager()
+    kernel = Kernel(
+        base_registry(),
+        StoreConfig(default_capacity=cap, capacities={"NPC": cap, "Player": cap}),
+        dt=1.0,
+        class_names=["IObject", "Player", "NPC"],
+    )
+    pm.register_plugin(Plugin("ChurnPlugin", [kernel, ChurnModule()]))
+    pm.start()
+    calls = []
+    if subscribe:
+        kernel.force_diff_property("NPC", "ATK_VALUE")
+        for cname, pname, tag in FANOUT_SUBS:
+            kernel.register_property_event(
+                cname, pname,
+                lambda c, p, rows, tag=tag: calls.append((tag, c, p, rows)))
+    for i in range(11):
+        kernel.create_object("NPC", {"HP": 10 + i, "MAXHP": 100})
+    for i in range(5):
+        kernel.create_object("Player", {"Level": 1 + i})
+    return kernel, calls
+
+
+def calls_from_raw_masks(kernel, outs):
+    """The fan-out worked out with plain numpy from each tick's raw
+    `out.diff` masks: per tick, per subscribed (class, property) in order
+    of first registration, the changed rows, once per subscriber."""
+    order = {}
+    for cname, pname, tag in FANOUT_SUBS:
+        order.setdefault((cname, pname), []).append(tag)
+    want = []
+    for out in outs:
+        for (cname, pname), tags in order.items():
+            slot = kernel.store.spec(cname).slot(pname)
+            m = out.diff.get(cname, {}).get(slot.bank.value)
+            if m is None:
+                continue
+            rows = np.flatnonzero(np.asarray(m)[:, slot.col])
+            if rows.size:
+                want += [(tag, cname, pname, rows.tolist()) for tag in tags]
+    return want
+
+
+def _drive(kernel, path, ticks):
+    if path == "tick":
+        return [kernel.tick() for _ in range(ticks)]
+    if path == "train":
+        kernel.configure_train(3)  # whole trains and a ragged tail
+        return kernel.train(ticks)
+    from noahgameframe_tpu.parallel import ShardedKernel
+
+    sk = ShardedKernel(kernel, n_devices=8)
+    sk.place()
+    return [sk.tick() for _ in range(ticks)]
+
+
+@pytest.mark.parametrize("path", ["tick", "train", "sharded"])
+def test_property_fanout_equals_raw_diff_masks(path):
+    kernel, calls = build_churn()
+    outs = _drive(kernel, path, 8)
+    assert len(outs) == 8
+    got = [(tag, c, p, rows.tolist()) for tag, c, p, rows in calls]
+    assert got == calls_from_raw_masks(kernel, outs)
+    for _, _, _, rows in calls:
+        assert rows.dtype == np.intp and rows.ndim == 1 and rows.size
+        assert (np.diff(rows) > 0).all()
+    seen = {(c, p) for _, c, p, _ in got}
+    # every bank of both classes, the forced column and both subscribers
+    assert {("NPC", "HP"), ("NPC", "ATK_VALUE"), ("NPC", "Position"),
+            ("Player", "MoveSpeed"), ("Player", "Level")} == seen
+    assert [g[0] for g in got if g[1:3] == ("NPC", "HP")][:2] == ["a", "b"]
+    # an unchanged column, and a changed one with no diff flag, stay silent
+    assert not seen & {("NPC", "MAXHP"), ("Player", "Position"),
+                       ("NPC", "MoveSpeed"), ("NPC", "TargetPos")}
+
+
+class _HostOnly:
+    """Stands where a diff mask stands: it can be read whole, and
+    counts the reads; indexing it (a device program per call) fails."""
+
+    def __init__(self, mask, reads):
+        self._mask, self._reads = mask, reads
+
+    def __array__(self, *a, **kw):
+        self._reads.append(self._mask.shape)
+        return np.asarray(self._mask)
+
+    def __getitem__(self, idx):
+        raise AssertionError("the fan-out indexed a device array")
+
+
+class _SpanProbe:
+    """A tracer that notes which log records fall inside one span."""
+
+    def __init__(self, name, records):
+        self.name, self.records = name, records
+        self.entered, self.inside = 0, []
+
+    @contextlib.contextmanager
+    def span(self, name, **args):
+        n0 = len(self.records)
+        yield
+        if name == self.name:
+            self.entered += 1
+            self.inside += self.records[n0:]
+
+
+@pytest.mark.parametrize("path", ["tick", "train", "sharded"])
+def test_fanout_mask_counters_and_round_trips(path):
+    """More than three subscribed properties a class cost at most one
+    read per (class, bank); a tick that changed nothing costs none."""
+    kernel, calls = build_churn()
+    pairs = {(c, kernel.store.spec(c).slot(p).bank.value)
+             for c, p, _ in FANOUT_SUBS}
+    assert len({p for c, p, _ in FANOUT_SUBS if c == "NPC"}) > 3
+    reads, per_tick, masked = [], [], set()
+    post = kernel._post_tick
+
+    def guarded(out, summary, **kw):
+        masked.update((c, b) for c, d in out.diff.items() for b in d)
+        out.diff = {c: {b: _HostOnly(m, reads) for b, m in d.items()}
+                    for c, d in out.diff.items()}
+        was = (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
+               len(reads), len(calls))
+        post(out, summary, **kw)
+        now = (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
+               len(reads), len(calls))
+        per_tick.append(tuple(b - a for a, b in zip(was, now)))
+
+    kernel._post_tick = guarded
+    _drive(kernel, path, 8)
+    assert len(per_tick) == 8
+    # NPC has no flagged f32 column, so no f32 mask: five pairs of six
+    pairs &= masked
+    assert len(pairs) == 5
+    for fetches, nbytes, n_reads, n_calls in per_tick:
+        assert fetches == n_reads <= len(pairs)
+        assert (fetches > 0) == (nbytes > 0) == (n_calls > 0)
+    # state.tick 3 and 7 (the fourth and eighth ticks) change nothing
+    assert [f for f, *_ in per_tick][3::4] == [0, 0]
+    assert max(f for f, *_ in per_tick) == len(pairs)
+    assert kernel.fanout_mask_fetches == len(reads)
+    assert kernel.fanout_mask_bytes == sum(
+        int(np.prod(shape)) for shape in reads)
+
+
+def test_fanout_counters_stay_zero_without_subscribers():
+    """The tick-1m situation: diffs every tick, nobody subscribed."""
+    kernel, _ = build_churn(subscribe=False)
+    outs = [kernel.tick() for _ in range(4)]
+    assert any(int(v) for o in outs for v in o.diff_count.values())
+    assert (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes) == (0, 0)
+
+
+def test_fanout_props_compiles_nothing(caplog):
+    """Mask shapes no other test has, so any device program the block ran
+    would have to be compiled inside its span."""
+    kernel, calls = build_churn(cap=88)
+    probe = _SpanProbe("fanout.props", caplog.records)
+    kernel.tracer = probe
+    with caplog.at_level(logging.WARNING), jax.log_compiles():
+        for _ in range(6):
+            kernel.tick()
+    assert any("Compiling" in r.getMessage() for r in caplog.records)
+    assert probe.entered == 6 and calls
+    assert [r.getMessage() for r in probe.inside] == []

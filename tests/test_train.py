@@ -352,6 +352,42 @@ def test_role_train_election_yields_to_overlap():
         role.shut()
 
 
+@pytest.mark.parametrize("mode", ["plain", "train", "overlap"])
+def test_role_mirrors_fanout_mask_counters(mode):
+    """Each of the role's three ways to tick carries the kernel's
+    property fan-out accounting into the registry."""
+    from noahgameframe_tpu.net.defines import ServerType
+    from noahgameframe_tpu.net.roles.base import RoleConfig
+    from noahgameframe_tpu.net.roles.game import GameRole
+
+    w = GameWorld(WorldConfig(npc_capacity=32, player_capacity=8,
+                              extent=64.0, seed=11, middleware=False,
+                              combat=True, movement=True, regen=True,
+                              verlet_skin=2.0)).start()
+    w.scene.create_scene(1, width=64.0)
+    w.seed_npcs(16, rng=np.random.default_rng(111))
+    kwargs = {"plain": {}, "train": {"tick_train": 4},
+              "overlap": {"interest_radius": 8.0, "serve_batch": True,
+                          "serve_overlap": True}}[mode]
+    role = GameRole(
+        RoleConfig(6, int(ServerType.GAME), "Fanout", "127.0.0.1", 0,
+                   targets=[]),
+        backend="auto", world=w, **kwargs)
+    role.server.send_raw = lambda _conn, _msg, _body: True
+    try:
+        now = 1000.0
+        for _ in range(4):
+            now += w.config.dt + 1e-6
+            role.execute(now=now)
+        reg = role.telemetry.registry
+        k = role.kernel
+        assert k.fanout_mask_fetches > 0  # NPCs walk every tick
+        assert reg.value("nf_fanout_mask_fetches_total") == k.fanout_mask_fetches
+        assert reg.value("nf_fanout_mask_bytes_total") == k.fanout_mask_bytes
+    finally:
+        role.shut()
+
+
 # ------------------------------------------------- contract plumbing
 
 def test_assert_train_lanes_gates_both_directions():
