@@ -795,250 +795,6 @@ def run_reshard(args) -> dict:
     }
 
 
-def run_rooms(args) -> dict:
-    """ISSUE 19 r12 evidence: the many-worlds rooms ladder.  Each rung
-    admits R independent rooms into ONE vmapped RoomBatch sharded
-    room-major over the mesh — one recipe world built per rung, packed
-    once, admitted R times with per-room rng variation, so setup stays
-    O(1) worlds.  Reported per rung: admit cost, per-batch-tick p50/p99
-    (tick() with the per-room counter-bank fetch — the served-path
-    honest frame), fused room-ticks/sec, then a re-home churn phase
-    with a zero-dropped-rows account and the same CostBook
-    zero-unexplained-recompile gate as the migration ladders."""
-    jax = _jax_on(args.platform, args.rooms)
-
-    import numpy as np
-
-    from noahgameframe_tpu.game import GameWorld
-    from noahgameframe_tpu.game.world import WorldConfig
-    from noahgameframe_tpu.parallel.mesh import ROOMS_AXIS, make_mesh
-    from noahgameframe_tpu.parallel.rooms import RoomBatch, RoomBinPacker
-
-    counts = [int(x) for x in (args.rooms_count or "16,64,256").split(",")]
-    per_room = int(args.rooms_entities)
-    seeded = max(1, per_room // 2)
-    ticks = int(args.rooms_ticks)
-    train_k = int(getattr(args, "train", 0) or 0)
-    mesh = make_mesh(args.rooms, axis=ROOMS_AXIS)
-
-    def r12_point(n_rooms):
-        """The committed r12 (K=1) rung matching this one, for honest
-        speedup ratios in the train arm; None when no artifact."""
-        name = ("r12_rooms_tpu.json" if args.platform == "tpu"
-                else "r12_rooms_cpu.json")
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "bench_runs", name)
-        try:
-            with open(path) as f:
-                for p in json.load(f)["detail"]["points"]:
-                    if p.get("rooms") == n_rooms:
-                        return p
-        except Exception:  # noqa: BLE001
-            return None
-        return None
-
-    def point(n_rooms):
-        if n_rooms % args.rooms:
-            raise ValueError(f"--rooms-count {n_rooms} not divisible by "
-                             f"the {args.rooms}-device rooms mesh")
-        t0 = time.perf_counter()
-        w = GameWorld(WorldConfig(
-            npc_capacity=per_room, player_capacity=8, extent=64.0,
-            seed=args.seed, middleware=False, combat=True,
-            movement=True, regen=True, verlet_skin=2.0))
-        w.start()
-        w.scene.create_scene(1, width=64.0)
-        w.seed_npcs(seeded, rng=np.random.default_rng(args.seed + 100))
-        w.kernel._ensure_aux()
-        batch = RoomBatch(w.kernel, n_rooms, mesh=mesh)
-        packer = RoomBinPacker(batch.capacity,
-                               n_blocks=mesh.devices.size)
-        build_s = time.perf_counter() - t0
-
-        def room_of(i):
-            return w.kernel.state.replace(
-                rng=jax.random.PRNGKey(args.seed + i))
-
-        # warm-up compiles every entry once (admit/step/run/extract,
-        # plus the K-tick train when elected), then the no-recompile
-        # gate arms: churn after the mark must be free (slot indices
-        # are traced scalars)
-        batch.admit(packer.alloc(), room_of(0))
-        batch.tick()
-        batch.run(1)
-        if train_k > 1:
-            batch.configure_train(train_k)
-            batch.train(train_k)
-        batch.extract(0)
-        batch.rehome(0, 1)
-        packer.free(0)
-        mark = batch.costbook.mark()
-
-        # fill every lane but one — the spare slot is what the churn
-        # phase rotates rooms through
-        t0 = time.perf_counter()
-        used = []
-        while packer.free_count > 1:
-            slot = packer.alloc()
-            batch.admit(slot, room_of(len(used)))
-            used.append(slot)
-        jax.block_until_ready(batch.state)
-        admit_s = time.perf_counter() - t0
-
-        # per-frame latency: tick() includes the [R,L] counter fetch
-        lat = []
-        for _ in range(ticks):
-            t0 = time.perf_counter()
-            counters = batch.tick()
-            lat.append(time.perf_counter() - t0)
-        lat_ms = np.sort(np.asarray(lat)) * 1e3
-        p50 = float(lat_ms[len(lat_ms) // 2])
-        p99 = float(lat_ms[min(len(lat_ms) - 1,
-                               int(len(lat_ms) * 0.99))])
-
-        # fused throughput: one dispatch, zero host syncs inside
-        t0 = time.perf_counter()
-        batch.run(2 * ticks)
-        jax.block_until_ready(batch.state)
-        run_s = time.perf_counter() - t0
-        room_ticks = n_rooms * 2 * ticks / run_s
-
-        # K-tick train throughput (ISSUE 20): same 2*ticks span as the
-        # fused window, but every tick's [R, L] counter lane comes back
-        # to the host — the OBSERVED path at ceil(n/K) dispatches.  The
-        # dispatch gate pins the count exactly; a retrace or a silent
-        # per-tick fallback would break it.
-        train = {}
-        if train_k > 1:
-            n_train = 2 * ticks
-            d0 = batch.train_dispatches
-            t0 = time.perf_counter()
-            lanes = batch.train(n_train)
-            train_s = time.perf_counter() - t0
-            t_dispatches = batch.train_dispatches - d0
-            want = n_train // train_k  # tail singles ride _jit_step
-            train = {
-                "tick_train": train_k,
-                "train_ticks_timed": n_train,
-                "train_tick_ms": round(train_s * 1e3 / n_train, 3),
-                "train_room_ticks_per_sec": round(
-                    n_rooms * n_train / train_s, 1),
-                "train_dispatches": t_dispatches,
-                "train_dispatch_gate": t_dispatches == want,
-                "train_rows": int(lanes.shape[0]),
-                "train_fetch_bytes": batch.train_fetch_bytes,
-            }
-            # honest ratios against the committed K=1 round: both the
-            # observed path it replaces (r12 tick_p50, per-tick fetch)
-            # and the fused path it cannot beat on fetch volume
-            base = r12_point(n_rooms)
-            if base:
-                b_ms = float(base["tick_p50_ms"])
-                b_obs = n_rooms / b_ms * 1e3
-                train["baseline_r12_k1_tick_ms"] = b_ms
-                train["baseline_r12_k1_room_ticks_per_sec"] = round(
-                    b_obs, 1)
-                train["speedup_vs_r12_k1_observed"] = round(
-                    train["train_room_ticks_per_sec"] / b_obs, 2)
-                b_fused = float(base["room_ticks_per_sec"])
-                train["baseline_r12_fused_room_ticks_per_sec"] = b_fused
-                train["speedup_vs_r12_fused"] = round(
-                    train["train_room_ticks_per_sec"] / b_fused, 2)
-
-        # churn: rotate rooms through the spare slot, nothing may drop
-        def rows():
-            return int(np.asarray(
-                batch.state.classes["NPC"].alive)[used].sum())
-
-        before = rows()
-        rng = np.random.default_rng(args.seed)
-        for _ in range(int(args.rooms_churn)):
-            src = used.pop(int(rng.integers(0, len(used))))
-            dst = packer.alloc()
-            batch.rehome(src, dst)
-            packer.free(src)
-            used.append(dst)
-        dropped = before - rows()
-        unexplained = batch.costbook.unexplained_since(mark)
-
-        # digest parity (ISSUE 20 acceptance): fresh train batch vs a
-        # fresh single-ticking control, 120 ticks — every tick's
-        # state_digest lane bit-identical across all R rooms, ragged
-        # tail included.  Runs after the gates: enable_digest() is a
-        # sanctioned retrace and must not pollute the churn CostBook.
-        parity = {}
-        if train_k > 1:
-            w.kernel.enable_digest()
-
-            def parity_batch():
-                pb = RoomBatch(w.kernel, n_rooms, mesh=mesh)
-                pk = RoomBinPacker(pb.capacity,
-                                   n_blocks=mesh.devices.size)
-                for i in range(n_rooms):
-                    pb.admit(pk.alloc(), room_of(i))
-                return pb
-
-            pb_t, pb_c = parity_batch(), parity_batch()
-            pb_t.configure_train(train_k)
-            p_ticks = 120
-            lanes_p = pb_t.train(p_ticks)
-            ok = True
-            for i in range(p_ticks):
-                c = pb_t.kernel.decode_counters(lanes_p[i])
-                ctl = pb_c.tick()
-                if not (np.array_equal(c["state_digest"],
-                                       ctl["state_digest"])
-                        and np.array_equal(c["tick"], ctl["tick"])):
-                    ok = False
-                    break
-            parity = {"digest_parity_ticks": p_ticks,
-                      "digest_parity": ok}
-
-        return {
-            "rooms": n_rooms,
-            "rooms_admitted": len(used),
-            "entities_per_room": seeded,
-            "build_wall_s": round(build_s, 2),
-            "admit_wall_s": round(admit_s, 2),
-            "admit_ms_per_room": round(admit_s * 1e3 / n_rooms, 3),
-            "tick_p50_ms": round(p50, 3),
-            "tick_p99_ms": round(p99, 3),
-            "room_ticks_per_sec": round(room_ticks, 1),
-            "entity_ticks_per_sec": round(room_ticks * seeded, 1),
-            "counters_sample": {k: int(np.asarray(v).sum())
-                                for k, v in counters.items()},
-            "rehomed": int(args.rooms_churn),
-            "dropped_rows": int(dropped),
-            "unexplained_recompiles": len(unexplained),
-            **train,
-            **parity,
-            "costbook": _costbook_detail(batch.costbook),
-        }
-
-    points = [point(n) for n in counts]
-    head = points[-1]
-    return {
-        "metric": ("rooms_train_room_ticks_per_sec" if train_k > 1
-                   else "rooms_room_ticks_per_sec"),
-        "value": (head["train_room_ticks_per_sec"] if train_k > 1
-                  else head["room_ticks_per_sec"]),
-        "unit": "room-ticks/s",
-        "detail": {
-            "devices": args.rooms,
-            "seed": args.seed,
-            "platform": jax.devices()[0].platform,
-            "ticks_timed": int(args.rooms_ticks),
-            "tick_train": train_k,
-            "all_gates": all(
-                p["dropped_rows"] == 0
-                and p["unexplained_recompiles"] == 0
-                and p.get("train_dispatch_gate", True)
-                and p.get("digest_parity", True) for p in points),
-            "points": points,
-        },
-    }
-
-
 def run_bench(args) -> dict:
     import jax
 
@@ -1475,40 +1231,11 @@ def main() -> None:
              "budget knobs reuse --mig-entities/--mig-budgets",
     )
     ap.add_argument(
-        "--rooms", type=int, default=0, metavar="N",
-        help="many-worlds rooms ladder over an N-device room-major "
-             "mesh (virtual CPU devices, or the real chips with "
-             "--platform tpu): R independent rooms vmapped as one "
-             "batch, per-batch-tick p50/p99, fused room-ticks/sec, and "
-             "a re-home churn phase gated on zero dropped rows + zero "
-             "unexplained recompiles (r12 evidence)",
-    )
-    ap.add_argument(
-        "--rooms-count", default=None, metavar="R,R,...",
-        help="rooms ladder rungs (default 16,64,256; each must divide "
-             "by --rooms)",
-    )
-    ap.add_argument(
-        "--rooms-entities", type=int, default=64,
-        help="per-room NPC capacity (half of it seeded live)",
-    )
-    ap.add_argument(
-        "--rooms-churn", type=int, default=8,
-        help="re-homes rotated through the spare slot per rung",
-    )
-    ap.add_argument(
-        "--rooms-ticks", type=int, default=30,
-        help="individually-timed batch ticks per rung (the fused "
-             "throughput window runs 2x this)",
-    )
-    ap.add_argument(
         "--train", type=int, default=0, metavar="K",
         help="K-tick observed trains (NF_TICK_TRAIN): one lax.scan "
              "dispatch covers K ticks with every per-tick lane stacked "
              "[K,...] for the host.  Device-loop mode times k.train() "
-             "instead of run_device(); the rooms ladder adds a train "
-             "throughput arm + a 120-tick per-tick digest-parity gate "
-             "against a K=1 control (r13 evidence).  0/1 = off",
+             "instead of run_device().  0/1 = off",
     )
     ap.add_argument(
         "--mig-entities", default=None, metavar="N,N,...",
@@ -1573,10 +1300,6 @@ def main() -> None:
                      "--platform cpu) or on real chips: the ladder grows "
                      "to a 4-wide mesh")
         _emit(run_reshard(args))
-        return
-
-    if args.rooms:
-        _emit(run_rooms(args))
         return
 
     if args.mesh_migrate:

@@ -37,7 +37,12 @@ from .social import (
     TeamModule,
 )
 from .stats import PropertyModule
-from .world import GameWorld, WorldConfig, build_benchmark_world
+from .world import (
+    BenchmarkRoomRecipe,
+    GameWorld,
+    WorldConfig,
+    build_benchmark_world,
+)
 
 __all__ = [
     "ATTACK_TIMER",
@@ -82,6 +87,7 @@ __all__ = [
     "STAT_NAMES",
     "SkillModule",
     "WorldConfig",
+    "BenchmarkRoomRecipe",
     "build_benchmark_world",
     "standard_registry",
 ]

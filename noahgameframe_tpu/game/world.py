@@ -75,6 +75,15 @@ class WorldConfig:
     placement: Optional["object"] = None
 
 
+def draw_npcs(rng: np.random.Generator, n: int, extent: float, camps: int):
+    """What a seed decides about n spawned NPCs, in the one order the
+    generator is consumed in: positions (z = 0), walk targets, camps."""
+    pos = rng.uniform(0.0, extent, (n, 3)).astype(np.float32)
+    pos[:, 2] = 0.0
+    target = rng.uniform(0.0, extent, (n, 2)).astype(np.float32)
+    return pos, target, rng.integers(0, camps, n)
+
+
 class GameWorld:
     """The assembled standard stack; `.pm` is the plugin manager."""
 
@@ -273,18 +282,16 @@ class GameWorld:
         benchmark scale."""
         # the world-owned generator advances across calls — two waves must
         # not land on identical coordinates
-        r = rng or self._rng
-        ext = self.config.extent
-        pos = r.uniform(0.0, ext, (n, 3)).astype(np.float32)
-        pos[:, 2] = 0.0
+        pos, target, camp = draw_npcs(rng or self._rng, n,
+                                      self.config.extent, camps)
         k = self.kernel
         values = {
             "SceneID": np.full(n, scene, np.int64).tolist(),
             "GroupID": np.full(n, group, np.int64).tolist(),
             "Position": [tuple(p) for p in pos],
-            "TargetPos": [tuple(p[:2]) for p in r.uniform(0.0, ext, (n, 2)).astype(np.float32)],
+            "TargetPos": [tuple(p) for p in target],
             "HP": [hp] * n,
-            "Camp": r.integers(0, camps, n).tolist(),
+            "Camp": camp.tolist(),
         }
         k.state, guids, rows = k.store.create_many(k.state, "NPC", n, values=values)
         # combat stats go through the EFFECTVALUE group of the stat record —
@@ -353,3 +360,55 @@ def build_benchmark_world(
     w.scene.create_scene(1, width=extent)
     w.seed_npcs(n_npcs)
     return w
+
+
+class BenchmarkRoomRecipe:
+    """`build_benchmark_world` as the recipe of a
+    `parallel.rooms.RoomDirectory`.
+
+    Called with a seed it builds that room's world, as any recipe does.
+    `seeded_rows` is what lets the directory admit thousands of rooms
+    without a world each: a seed decides a room's random key and its
+    NPCs' positions, walk targets and camps and nothing else, so the
+    leaves that hold those are made on the host for all the seeds at
+    once, from the same draws in the same order, and every other leaf
+    is the template room's."""
+
+    CLASS = "NPC"
+    CAMPS = 2  # seed_npcs' default
+
+    def __init__(self, n_npcs: int, extent: float, **world):
+        self.n_npcs = int(n_npcs)
+        self.extent = float(extent)
+        self.world = world
+
+    def __call__(self, seed: int) -> GameWorld:
+        return build_benchmark_world(self.n_npcs, extent=self.extent,
+                                     seed=int(seed), **self.world)
+
+    def seeded_rows(self, template: Kernel, seeds) -> Dict[str, np.ndarray]:
+        """`[R, ...]` host leaves, by `ROOM_PACK_SPEC` path, of the
+        rooms `self(seed)` would build, given the kernel of one."""
+        seeds = [int(s) for s in seeds]
+        spec = template.store.spec(self.CLASS)
+        cs = template.state.classes[self.CLASS]
+        rows = np.flatnonzero(np.asarray(cs.alive))
+        if rows.size != self.n_npcs:
+            raise ValueError(f"the template room holds {rows.size} NPCs, "
+                             f"the recipe seeds {self.n_npcs}")
+        i32 = np.repeat(np.asarray(cs.i32)[None], len(seeds), axis=0)
+        vec = np.repeat(np.asarray(cs.vec)[None], len(seeds), axis=0)
+        pos_col, target_col, camp_col = (
+            spec.slot(n).col for n in ("Position", "TargetPos", "Camp"))
+        for j, seed in enumerate(seeds):
+            pos, target, camp = draw_npcs(np.random.default_rng(seed),
+                                          self.n_npcs, self.extent,
+                                          self.CAMPS)
+            vec[j, rows, pos_col] = pos
+            vec[j, rows, target_col, :2] = target
+            i32[j, rows, camp_col] = camp
+        # jax.random.PRNGKey(seed) with 32-bit types: (0, seed mod 2^32)
+        rng = np.zeros((len(seeds), 2), np.uint32)
+        rng[:, 1] = np.asarray(seeds, np.uint64) & np.uint64(0xFFFFFFFF)
+        return {"rng": rng, f"classes.{self.CLASS}.i32": i32,
+                f"classes.{self.CLASS}.vec": vec}

@@ -1969,6 +1969,11 @@ class GameRole(ServerRole):
                     self.kernel.fanout_mask_fetches - f0)
                 self._fanout_mask_bytes.inc(
                     self.kernel.fanout_mask_bytes - fb0)
+                if self.rooms is not None:
+                    # the attached fleet keeps the world's clock: one
+                    # vmapped frame for every room slot per world tick
+                    for _ in range(ticks_this_frame):
+                        self.rooms.tick()
                 pm.frame += ticks_this_frame
                 # per-tick latency even under trains: one train frame is
                 # K ticks of device work behind one dispatch
@@ -2082,8 +2087,10 @@ class GameRole(ServerRole):
     # ------------------------------------------------------- many worlds
     def attach_rooms(self, directory) -> None:
         """Host a many-worlds RoomDirectory (parallel/rooms.py) beside
-        the single world: room status rides the heartbeat ext and the
-        room churn verbs below become drill-addressable."""
+        the single world: the pump ticks it once per world tick, inside
+        the tick stage (so nobody else may), room status rides the
+        heartbeat ext and the room churn verbs below become
+        drill-addressable."""
         self.rooms = directory
 
     def _rooms_or_raise(self):

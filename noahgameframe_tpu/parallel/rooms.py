@@ -23,6 +23,11 @@ Host side mirrors the serving layer's slot discipline:
 
 * ``RoomBinPacker`` — slots group into per-device blocks; create picks
   the least-loaded block's lowest free slot (or first-fit).
+* rooms are admitted in BULK: ``create_rooms(seeds)`` makes the rows
+  of all of them (a recipe with ``seeded_rows`` makes only the leaves a
+  seed decides, on the host, for every seed at once; a recipe that is
+  only a world builder has its worlds' states stacked) and writes them
+  with one scatter per leaf.  ``create_room`` is the batch of one.
 * create/destroy are SLOT RECYCLING with lazy wipe (SessionTable's
   ``_stale`` discipline): destroy only frees the host slot; admit's
   full-leaf scatter overwrites every byte, so no device wipe runs and —
@@ -213,6 +218,24 @@ def room_digest(
     return int(acc)
 
 
+def _rebuild_room(template: WorldState, class_order: Sequence[str],
+                  leaves: Sequence[Any]) -> WorldState:
+    """Inverse of :func:`world_room_leaf_items`: ``template`` with its
+    packed leaves replaced, in walk order, and no aux.  The leaves may
+    carry a leading rooms axis; nothing here looks at shapes."""
+    it = iter(leaves)
+    tick, rng = next(it), next(it)
+    classes = {}
+    for cname in class_order:
+        cs = template.classes[cname]
+        n = len(class_row_leaf_items(cs))
+        classes[cname] = rebuild_class_state(
+            cs, [jnp.asarray(next(it)) for _ in range(n)])
+    return template.replace(
+        classes={**template.classes, **classes},
+        tick=jnp.asarray(tick), rng=jnp.asarray(rng), aux={})
+
+
 # -- room blob (re-home / cross-engine snapshot framing) --------------------
 
 _ROOM_MAGIC = b"NFRM"
@@ -288,20 +311,9 @@ def unpack_room_blob(blob: bytes, template: WorldState,
         off += nbytes
     if off != len(payload):
         raise RowBlobError("room blob has trailing bytes")
-    it = iter(leaves)
-    tick, rng = next(it), next(it)
     names = list(class_order) if class_order is not None \
         else sorted(template.classes)
-    classes = {}
-    for cname in names:
-        cs = template.classes[cname]
-        n = len(class_row_leaf_items(cs))
-        classes[cname] = rebuild_class_state(
-            cs, [jnp.asarray(next(it)) for _ in range(n)])
-    out = template.replace(
-        classes={**template.classes, **classes},
-        tick=jnp.asarray(tick), rng=jnp.asarray(rng), aux={},
-    )
+    out = _rebuild_room(template, names, leaves)
     got = room_digest(out, class_order)
     if got != digest:
         raise RowBlobError(
@@ -421,6 +433,11 @@ class RoomBatch:
         self.costbook = template.costbook
         self.tick_count = 0
         self.last_counters: Dict[str, np.ndarray] = {}
+        # room slots the device has ticked, occupied or not: the whole
+        # bank rides every frame (= nf_rooms_slots_ticked_total)
+        self.slots_ticked = 0
+        # bytes of room leaves handed to the admit scatter so far
+        self.admit_bytes = 0
         self._seed = int(seed)
         self._jit_step = None
         self._jit_run = None
@@ -505,7 +522,7 @@ class RoomBatch:
             return self._jit_step
         k = self.kernel
 
-        def vstep(st):
+        def rooms_step(st):  # the device module's name: jit_rooms_step
             st2, out = jax.vmap(k._trace_step)(st)
             # only the [R, L] summary survives to the host; everything
             # else (fired masks, diffs, events) is DCE'd like run_device
@@ -520,7 +537,7 @@ class RoomBatch:
                    "out_shardings": (sh, NamedSharding(
                        self.mesh, PartitionSpec(ROOMS_AXIS)))}
         self._jit_step = self.costbook.wrap(
-            "rooms.step", vstep, donate_argnums=0, stage="tick",
+            "rooms.step", rooms_step, donate_argnums=0, stage="tick",
             jit_kwargs=jkw)
         return self._jit_step
 
@@ -531,9 +548,15 @@ class RoomBatch:
         single-world counter bank."""
         self._sync_generation()
         step = self._compile_step()
-        self.state, summary = step(self.state)
+        span = self.kernel.tracer.span
+        with span("rooms.dispatch"):
+            self.state, summary = step(self.state)
         self.tick_count += 1
-        self.last_counters = self.kernel.decode_counters(np.asarray(summary))
+        self.slots_ticked += self.capacity
+        with span("rooms.fetch"):  # the frame's one blocking read
+            summary = np.asarray(summary)
+        with span("rooms.decode"):
+            self.last_counters = self.kernel.decode_counters(summary)
         return self.last_counters
 
     def run(self, n: int) -> Dict[str, np.ndarray]:
@@ -554,7 +577,7 @@ class RoomBatch:
                 st2, out = jax.vmap(k._trace_step)(st)
                 return st2, out["summary"]
 
-            def runner(st, t):
+            def rooms_run(st, t):
                 st1, out = jax.vmap(k._trace_step)(st)
                 return jax.lax.fori_loop(0, t - 1, body, (st1, out["summary"]))
 
@@ -567,10 +590,11 @@ class RoomBatch:
                        "out_shardings": (sh, NamedSharding(
                            self.mesh, PartitionSpec(ROOMS_AXIS)))}
             self._jit_run = self.costbook.wrap(
-                "rooms.run", runner,
+                "rooms.run", rooms_run,
                 donate_argnums=0, stage="tick", jit_kwargs=jkw)
         self.state, summary = self._jit_run(self.state, jnp.int32(int(n)))
         self.tick_count += int(n)
+        self.slots_ticked += int(n) * self.capacity
         self.last_counters = self.kernel.decode_counters(np.asarray(summary))
         return self.last_counters
 
@@ -656,7 +680,8 @@ class RoomBatch:
 
     # ---------------------------------------------------- slot plumbing
     def _room_payload(self, room: WorldState) -> WorldState:
-        """A full room pytree structurally matching one batched lane:
+        """A full room pytree structurally matching one batched lane
+        (its packed leaves may carry a leading rooms axis):
         the room's packed leaves + FRESH aux caches (blank for
         registered entries, zeros for trace-added ones like migration
         stats) — the admit scatter is then one tree_map."""
@@ -669,32 +694,85 @@ class RoomBatch:
                     lambda l: jnp.zeros(l.shape[1:], l.dtype), cur)
         for cname in self.kernel.store.class_order:
             want = np.asarray(self._blank.classes[cname].alive).shape[0]
-            got = np.asarray(room.classes[cname].alive).shape[0]
+            got = room.classes[cname].alive.shape[-1]
             if want != got:
                 raise ValueError(
                     f"admitted room's {cname!r} capacity {got} != batch "
                     f"template {want} — recipes must share StoreConfig")
         return room.replace(aux=aux)
 
-    def admit(self, slot: int, room: WorldState) -> int:
-        """Scatter one room's state into ``slot``.  Full-leaf overwrite:
-        whatever the slot held before (a destroyed room's remains — lazy
-        wipe) is unreadable afterwards.  The slot index is a traced
-        scalar, so admitting to any slot reuses one compiled scatter."""
+    def admit_rooms(self, slots: Sequence[int], rows: Dict[str, Any],
+                    proto: Optional[WorldState] = None) -> None:
+        """Scatter ``len(slots)`` rooms into their slots, one scatter
+        per leaf.  ``rows`` holds, by ``ROOM_PACK_SPEC`` path, the
+        ``[m, ...]`` leaves in which the rooms differ; every other leaf
+        is ``proto``'s (the blank room's when None) for all of them.
+        Full-leaf overwrite: whatever a slot held before (a destroyed
+        room's remains, lazy wipe) is unreadable afterwards.  The slots
+        are a TRACED vector padded to a power of two (repeating the
+        first room: an idempotent duplicate write), so one compiled
+        scatter serves every slot and a handful serve every count."""
         self._sync_generation()
+        slots = np.asarray(slots, np.int32).reshape(-1)
+        m = int(slots.size)
+        if m == 0:
+            return
+        if slots.min() < 0 or slots.max() >= self.capacity:
+            # a scatter drops an out-of-range index without a word
+            raise IndexError(f"room slot outside 0..{self.capacity - 1}")
+        proto = self._blank if proto is None else proto
+        order = self.kernel.store.class_order
+        items = world_room_leaf_items(proto, order)
+        unknown = set(rows) - {path for path, _ in items}
+        if unknown:
+            raise RowBlobError(
+                f"admit rows name no packed leaf: {sorted(unknown)}")
+        pad = next_pow2(m) - m
+
+        def padded(a):
+            return jnp.concatenate(
+                [a, jnp.repeat(a[:1], pad, axis=0)]) if pad else a
+
+        leaves = []
+        for path, leaf in items:
+            got = rows.get(path)
+            if got is not None:
+                want = (m,) + tuple(leaf.shape)
+                if tuple(got.shape) != want or got.dtype != leaf.dtype:
+                    raise ValueError(
+                        f"admit rows {path!r}: {got.dtype}"
+                        f"{list(got.shape)} where the batch takes "
+                        f"{leaf.dtype}{list(want)}")
+                self.admit_bytes += int(got.nbytes)
+                leaf = padded(jnp.asarray(got))
+            leaves.append(leaf)
+        payload = self._room_payload(_rebuild_room(proto, order, leaves))
         if self._jit_admit is None:
+            def scatter(batch, rooms, s):
+                def put(b, r):
+                    if r.ndim < b.ndim:  # proto's leaf: the same for all
+                        r = jnp.broadcast_to(r[None], s.shape + r.shape)
+                    return b.at[s].set(r)
+
+                return jax.tree.map(put, batch, rooms)
+
             # the batch comes back under the batch's own shardings:
             # left to the compiler, a zero-width bank returns
             # replicated, and rooms.step (pinned in_shardings) refuses it
             jkw = ({} if self.mesh is None
                    else {"out_shardings": self.shardings()})
             self._jit_admit = self.costbook.wrap(
-                "rooms.admit",
-                lambda b, r, s: jax.tree.map(
-                    lambda bb, ll: bb.at[s].set(ll), b, r),
+                "rooms.admit", scatter,
                 donate_argnums=0, stage="tick", jit_kwargs=jkw)
-        payload = self._room_payload(room)
-        self.state = self._jit_admit(self.state, payload, jnp.int32(int(slot)))
+        self.state = self._jit_admit(self.state, payload,
+                                     jnp.asarray(padded(slots)))
+
+    def admit(self, slot: int, room: WorldState) -> int:
+        """Admit one room's state into ``slot``: the batch of one, every
+        packed leaf its own row."""
+        rows = {path: jnp.asarray(leaf)[None] for path, leaf in
+                world_room_leaf_items(room, self.kernel.store.class_order)}
+        self.admit_rooms([slot], rows)
         return int(slot)
 
     def extract(self, slot: int) -> WorldState:
@@ -782,7 +860,11 @@ class RoomDirectory:
     """The host face of the many-worlds engine: room ids -> slots.
 
     ``recipe(seed)`` builds one fresh single-room world (a GameWorld or
-    a bare built Kernel); room 0's build becomes the vmap TEMPLATE.
+    a bare built Kernel); room 0's build becomes the vmap TEMPLATE.  A
+    recipe that also has ``seeded_rows(template_kernel, seeds)`` (the
+    ``[R, ...]`` host leaves a seed decides, by ``ROOM_PACK_SPEC``
+    path; ``game.BenchmarkRoomRecipe``) is never called again: its
+    rooms are the template room with those leaves replaced.
     create/destroy/re-home recycle slots through the bin-packer;
     ``attach_control`` keeps a room's recipe world alive and ticks it in
     LOCKSTEP with the batch — the parity oracle drill's RoomIsolation
@@ -797,9 +879,12 @@ class RoomDirectory:
         if capacity is None:
             capacity = int(os.environ.get(ENV_ROOM_SLOTS, "16"))
         self._recipe = recipe
-        template = self._kernel_of(recipe(template_seed))
+        self.template_world = recipe(template_seed)
+        template = self._kernel_of(self.template_world)
         self.batch = RoomBatch(template, capacity, mesh=mesh,
                                seed=template_seed)
+        # the template room as built: what every seeded room starts from
+        self._proto = template.state
         n_blocks = mesh.devices.size if mesh is not None else 1
         self.packer = RoomBinPacker(self.batch.capacity, n_blocks,
                                     policy=policy)
@@ -810,6 +895,10 @@ class RoomDirectory:
         self.created = 0
         self.destroyed = 0
         self.rehomed = 0
+        self.admitted_rows = 0  # entity rows of the rooms admitted
+        # per-room counters summed over the OCCUPIED slots of every
+        # observed tick() (a fused run() leaves only its last frame)
+        self.counter_totals: Dict[str, int] = {}
         self._metrics = None
         if registry is not None:
             self._metrics = {
@@ -823,6 +912,16 @@ class RoomDirectory:
                     "nf_rooms_destroyed_total", "rooms destroyed"),
                 "rehomed": registry.counter(
                     "nf_rooms_rehomed_total", "room re-homes"),
+                "admitted_rows": registry.counter(
+                    "nf_rooms_admitted_rows_total",
+                    "entity rows of the rooms admitted"),
+                "admit_bytes": registry.counter(
+                    "nf_rooms_admit_bytes_total",
+                    "bytes of room leaves handed to the admit scatter"),
+                "slots_ticked": registry.counter(
+                    "nf_rooms_slots_ticked_total",
+                    "room slots ticked, occupied or not (the whole "
+                    "bank rides every frame)"),
             }
             self._publish()
 
@@ -830,46 +929,95 @@ class RoomDirectory:
     def _kernel_of(world: Any) -> Kernel:
         return world if isinstance(world, Kernel) else world.kernel
 
-    @staticmethod
-    def _load_of(state: WorldState) -> float:
-        return float(sum(int(np.asarray(cs.alive).sum())
-                         for cs in state.classes.values()))
-
     def _publish(self) -> None:
         if self._metrics is None:
             return
         self._metrics["active"].set(len(self.rooms))
         self._metrics["slots_free"].set(self.packer.free_count)
+        for name in ("admit_bytes", "slots_ticked"):  # the batch counts
+            counter = self._metrics[name]
+            counter.inc(getattr(self.batch, name) - counter.value())
 
     # ----------------------------------------------------------- churn
+    def create_rooms(self, seeds: Sequence[int],
+                     room_ids: Optional[Sequence[int]] = None,
+                     control: bool = False) -> List[int]:
+        """Make the rooms of ``seeds`` and admit them all at once, each
+        into the least-loaded free slot: the one way a room comes to be.
+        A recipe with ``seeded_rows`` has its rooms made on the host for
+        the whole batch, with no world, kernel or device program a
+        room; one that is only a world builder is called per seed and
+        the worlds' states are stacked.  With ``control=True`` the
+        rooms are built as worlds either way, the worlds stay alive and
+        ``tick``/``run`` advance them in lockstep: the independent
+        oracle for isolation/parity gates."""
+        seeds = [int(s) for s in seeds]
+        if room_ids is None:
+            room_ids = range(self._next_room_id,
+                             self._next_room_id + len(seeds))
+        room_ids = [int(r) for r in room_ids]
+        if len(room_ids) != len(seeds) or len(set(room_ids)) != len(seeds):
+            raise ValueError("one distinct room id per seed")
+        for room_id in room_ids:
+            if room_id in self.rooms:
+                raise ValueError(f"room {room_id} already exists")
+        if not seeds:
+            return []
+        kernel = self.batch.kernel
+        order = kernel.store.class_order
+        with kernel.tracer.span("rooms.admit", rooms=len(seeds)):
+            seeded = getattr(self._recipe, "seeded_rows", None)
+            if control or seeded is None:
+                worlds = [self._recipe(seed) for seed in seeds]
+                items = []
+                for world in worlds:
+                    k = self._kernel_of(world)
+                    k._ensure_aux()
+                    items.append(world_room_leaf_items(k.state, order))
+                rows = {path: jnp.stack([room[i][1] for room in items])
+                        for i, (path, _) in enumerate(items[0])}
+                proto = None
+            else:
+                worlds = []
+                rows = seeded(kernel, seeds)
+                proto = self._proto
+            loads = np.zeros(len(seeds))
+            for cname in order:
+                alive = rows.get(f"classes.{cname}.alive")
+                loads += (np.asarray(alive).sum(axis=1) if alive is not None
+                          else float(np.asarray(
+                              self._proto.classes[cname].alive).sum()))
+            slots: List[int] = []
+            try:
+                for load in loads:
+                    slots.append(self.packer.alloc(load=float(load)))
+                self.batch.admit_rooms(slots, rows, proto)
+            except Exception:
+                for slot in slots:
+                    self.packer.free(slot)
+                raise
+        self._next_room_id = max(self._next_room_id, max(room_ids) + 1)
+        for room_id, seed, slot in zip(room_ids, seeds, slots):
+            self.rooms[room_id] = slot
+            self.seeds[room_id] = seed
+        if control:
+            self.controls.update(zip(room_ids, worlds))
+        self.created += len(seeds)
+        self.admitted_rows += int(loads.sum())
+        if self._metrics is not None:
+            self._metrics["created"].inc(len(seeds))
+            self._metrics["admitted_rows"].inc(int(loads.sum()))
+        self._publish()
+        return room_ids
+
     def create_room(self, seed: Optional[int] = None,
                     room_id: Optional[int] = None,
                     control: bool = False) -> int:
-        """Build a fresh room from the recipe and admit it into the
-        least-loaded free slot.  With ``control=True`` the recipe world
-        stays alive host-side and ``tick``/``run`` advance it in
-        lockstep — the independent oracle for isolation/parity gates."""
+        """:meth:`create_rooms` of one; the seed defaults to the id."""
         if room_id is None:
             room_id = self._next_room_id
-            self._next_room_id += 1
-        room_id = int(room_id)
-        if room_id in self.rooms:
-            raise ValueError(f"room {room_id} already exists")
-        seed = int(seed) if seed is not None else room_id
-        world = self._recipe(seed)
-        k = self._kernel_of(world)
-        k._ensure_aux()
-        slot = self.packer.alloc(load=self._load_of(k.state))
-        self.batch.admit(slot, k.state)
-        self.rooms[room_id] = slot
-        self.seeds[room_id] = seed
-        if control:
-            self.controls[room_id] = world
-        self.created += 1
-        if self._metrics is not None:
-            self._metrics["created"].inc()
-        self._publish()
-        return room_id
+        seed = int(room_id) if seed is None else seed
+        return self.create_rooms([seed], [room_id], control=control)[0]
 
     def destroy_room(self, room_id: int) -> int:
         """Free the room's slot (lazy wipe — admit's full overwrite is
@@ -912,16 +1060,44 @@ class RoomDirectory:
 
     # ----------------------------------------------------------- ticks
     def tick(self) -> Dict[str, np.ndarray]:
-        """One frame for every room + every lockstep control."""
-        counters = self.batch.tick()
-        for world in self.controls.values():
-            self._kernel_of(world).run_device(1, reconcile=False)
+        """One frame for every room + every lockstep control; returns
+        the per-room counter columns (slot-indexed ``[capacity]``)."""
+        with self.batch.kernel.tracer.span(
+                "rooms.tick", tick=self.batch.tick_count + 1):
+            counters = self.batch.tick()
+            with self.batch.kernel.tracer.span("rooms.decode"):
+                used = self.packer.used
+                for name, column in counters.items():
+                    if name not in ("state_digest", "tick"):
+                        self.counter_totals[name] = self.counter_totals.get(
+                            name, 0) + int(column[used].sum())
+            for world in self.controls.values():
+                self._kernel_of(world).run_device(1, reconcile=False)
+        self._publish()
         return counters
 
     def run(self, n: int) -> None:
         self.batch.run(n)
         for world in self.controls.values():
             self._kernel_of(world).run_device(int(n), reconcile=False)
+        self._publish()
+
+    # ----------------------------------------------------- cell depth
+    def combat_geometry(self, class_name: str = "NPC"
+                        ) -> Optional[Dict[str, Any]]:
+        """The cell depths every room's neighbour engine runs at: what
+        the template world's combat module resolves for the room's
+        capacity, baked into ``rooms.step`` when it was traced.  No
+        per-room boost exists: what a room's cells drop at this depth
+        is counted (``counter_totals["aoi_*_overflow_drops"]``) and
+        stays dropped.  None for a recipe without a combat module."""
+        combat = getattr(self.template_world, "combat", None)
+        if combat is None:
+            return None
+        cap = int(self.batch.kernel.store.capacity(class_name))
+        return {"cell_size": combat.cell_size, "width": combat.width,
+                "bucket": combat.resolved_bucket(cap),
+                "att_bucket": combat.resolved_att_bucket(cap)}
 
     # ---------------------------------------------------------- oracle
     def slot_of(self, room_id: int) -> int:
@@ -944,6 +1120,8 @@ class RoomDirectory:
             "created": self.created,
             "destroyed": self.destroyed,
             "rehomed": self.rehomed,
+            "admitted_rows": self.admitted_rows,
+            "slots_ticked": self.batch.slots_ticked,
             "tick": self.batch.tick_count,
             "policy": self.packer.policy,
             "blocks": self.packer.n_blocks,

@@ -5,8 +5,9 @@ described (`v5e:2x2`) and not attached, so these cases guard every later
 PR at no chip time: the Pallas fold at the real 100k and 1M geometries,
 the fused neighbourhood kernel's refusal (strict xfail: the day Mosaic
 lowers its gather, this file says so), the program that seeds a 1M
-world, the default tick, and one sharded tick over the described 2x2
-mesh.  A compile that passes is not a chip
+world, the default tick, one sharded tick over the described 2x2
+mesh, and the clone-scene fleet's `rooms.step` at its benchmarked size.
+A compile that passes is not a chip
 run: nothing executes, so no result or time is checked here.
 
 This is the only file that describes a topology.  The description
@@ -192,3 +193,34 @@ def test_sharded_tick_compiles_for_the_2x2_mesh(topo):
     # each device holds a quarter of the banks, not all of them
     bank = 4 * sw.bank_size * (5 + 3) * 4  # i32[cap,5] + f32[cap,1,3]
     assert compiled.memory_analysis().argument_size_in_bytes < bank
+
+
+def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip):
+    """`rooms.step` of `clone-rooms-5k` (benchmarks/configs): the tick
+    of a 96-NPC, 16-unit room vmapped over 8,192 slots.  The bank and
+    the program's temporaries fit a chip with room for the benchmark's
+    six bank copies, and the named scopes survive `vmap` in the compiled
+    text, where the per-layer readers look for them."""
+    from noahgameframe_tpu.game import BenchmarkRoomRecipe
+
+    k = BenchmarkRoomRecipe(96, 16.0, player_capacity=4)(0).kernel
+    k._ensure_aux()
+    assert k.store.capacity("NPC") == 128
+    fleet = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((8192,) + np.shape(x),
+                                       jnp.asarray(x).dtype,
+                                       sharding=one_chip), k.state)
+
+    def rooms_step(st):
+        st2, out = jax.vmap(k._trace_step)(st)
+        return st2, out["summary"]
+
+    compiled = jax.jit(rooms_step, donate_argnums=0).lower(fleet).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 6 * 1024 ** 3
+    text = compiled.as_text()
+    for scope in ("nf.schedule", "nf.phase.CombatModule.aoe", "nf.diff",
+                  "nf.aoe.rank", "nf.aoe.table", "nf.aoe.fold",
+                  "nf.aoe.pull", "nf.summary"):
+        assert f"vmap({scope})" in text or f")/{scope}" in text, scope
