@@ -11,7 +11,8 @@ Reference behavior being matched:
 
 TPU inversion (BASELINE config 4's 1M-entity AoE resolve): all alive
 entities are binned once into the cell-table (ops/stencil.py — one sort,
-one scatter); every entity then PULLS incoming damage from the nine
+one scatter; this tick's attackers into a second, by a second sort and a
+duty-sized chunk); every entity then PULLS incoming damage from the nine
 dense-shifted neighbor blocks within the skill radius — a fused pairwise
 masked reduction with zero gathers and zero scatter collisions — applies
 `max(sum_atk - def, 0)`, picks the strongest in-range attacker as
@@ -32,11 +33,13 @@ from ..core.store import HANDLE_ROW_BITS, WorldState, with_class
 from ..kernel.module import Module
 from ..ops.stencil import (
     auto_bucket,
+    binning_mode,
     build_cell_slots_pair,
     build_cell_table_pair,
     pull_slots,
     slots_from_assignment,
     stencil_fold,
+    sub_chunks,
 )
 from ..ops.verlet import (
     full_table,
@@ -351,6 +354,20 @@ class CombatModule(Module):
             self.resolved_bucket(capacity),
         )
 
+    def resolved_att_rows(self, capacity: int) -> int:
+        """Rows of the sorted attacker list the table build gathers and
+        scatters a trip (`build_cell_table_pair`'s `sub_rows`): about
+        twice the attackers a tick can hold under the arming this module
+        knows (capacity * duty), rounded up to whole sublanes of 8, the
+        whole bank when everyone can fire at once.  A tick with more
+        attackers than this sends more chunks, not fewer attacks."""
+        import math
+
+        if self._attacker_duty >= 1.0:
+            return capacity
+        eff = max(1, int(math.ceil(capacity * self._attacker_duty)))
+        return min(-(-2 * eff // 8) * 8, capacity)
+
     def resolved_engine(self) -> int:
         """The combat engine this trace will bake in: 0 (XLA fold over
         split tables), 1 (Pallas fold, same tables) or 2 (fused
@@ -511,13 +528,22 @@ class CombatModule(Module):
                     self.cell_size, self.width, bucket, att_bucket,
                 )
         else:
-            # one argsort feeds both tables (attackers subset of alive);
+            # one key pass feeds both tables (attackers subset of alive);
             # this one call ranks and builds: it opens nf.aoe.rank and
-            # nf.aoe.table itself
+            # nf.aoe.table itself.  The attacker side gathers and
+            # scatters att_rows sorted attackers a trip, not the bank.
+            att_rows = self.resolved_att_rows(n)
             vic_bin, att_bin = build_cell_table_pair(
                 pos, cs.alive, vic_feats, attacking, att_feats,
                 self.cell_size, self.width, bucket, att_bucket,
+                sub_rows=att_rows,
             )
+            if binning_mode() == "sort":
+                # how the chunk engages: 1 a tick under the arming it was
+                # sized for (the count engine sends the bank, unchunked)
+                chunks = sub_chunks(attacking, att_rows)
+                ctx.count("aoe_attacker_chunks", chunks)
+                ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
         nbr = None
         with jax.named_scope("nf.aoe.fold"):
             if engine == 2:

@@ -11,12 +11,19 @@ shifted-window arithmetic rides the VPU at full throughput.
 So the engine inverts the layout ONCE per query instead of gathering per
 candidate:
 
-1. `build_cell_table` sorts entities by cell id (one cheap argsort), packs
-   caller-chosen per-entity features into a dense `[n_cells*K + 1, F+1]`
-   payload table with ONE permutation-gather and ONE scatter (unique slot
-   indices, deterministic), and remembers each row's slot (`slot_of`).
-   Entities beyond a cell's K slots land in the dump slot and are counted
-   in `dropped` — size K from `auto_bucket` to keep that ~zero.
+1. `build_cell_table` sorts `(cell id, row)` pairs (one cheap sort that
+   hands back the sorted keys with the order, so nothing is gathered to
+   rank them), packs caller-chosen per-entity features into a dense
+   `[n_cells*K + 1, F+1]` payload table with ONE un-sort scatter of the
+   slots and ONE payload scatter (unique slot indices, deterministic),
+   and remembers each row's slot (`slot_of`).  Entities beyond a cell's
+   K slots land in the dump slot and are counted in `dropped` — size K
+   from `auto_bucket` to keep that ~zero.  `build_cell_table_pair` adds
+   a SUBSET table (combat: this tick's attackers) whose irregular passes
+   are priced by the subset, not by the bank: a second sort compacts the
+   members to the front in cell order, their ranks and slots are
+   streaming passes over that list, and only `sub_rows`-sized chunks of
+   it are gathered and scattered.
 2. `stencil_fold` walks the 3x3 neighborhood as NINE DENSE SHIFTS of the
    [H, W, K, F] grid view (one pad + nine fused slices — no index math,
    no gathers).  The caller folds candidate blocks against the resident
@@ -245,16 +252,28 @@ def _cell_keys(pos, active, cell_size: float, width: int,
     return n_cells, key
 
 
-def _sorted_segments(pos, active, cell_size: float, width: int,
-                     cell=None, n_cells: int | None = None):
-    """Shared build prefix of the SORT engine: the ONE stable argsort by
-    cell id plus per-element segment ranks.  Returns (n_cells, order,
-    skey, seg_start, rank) — everything both table builders derive slots
-    from."""
-    n = pos.shape[0]
-    n_cells, key = _cell_keys(
-        pos, active, cell_size, width, cell=cell, n_cells=n_cells
+def _segment_ranks(skey: jnp.ndarray):
+    """Streaming half of a sorted build: from the SORTED keys, the head
+    flag of each run of equal keys and every element's ordinal inside its
+    run.  Returns (seg_start, rank)."""
+    idx = jnp.arange(skey.shape[0], dtype=jnp.int32)
+    seg_start = jnp.concatenate(
+        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
     )
+    # index of each sorted element's segment head, via running max
+    start_idx = jax.lax.cummax(jnp.where(seg_start, idx, 0))
+    return seg_start, idx - start_idx
+
+
+def _key_segments(key: jnp.ndarray, n_cells: int):
+    """The SORT engine's one sort and the streaming passes behind it, for
+    any per-row key in [0, n_cells]: (order, skey, seg_start, rank).
+    The sort is the stable sort of `(key, row)` that `jnp.argsort(key)`
+    runs inside, with both results kept: `skey` is the sort's own key
+    result, not a `key[order]` gather (an N-row irregular pass, 9 ms at
+    2^20 on a v5e, to re-read what the sort had just put in order).
+    Only the NF_RADIX knob path, which produces an order alone, gathers
+    it."""
     # nf-lint: disable=trace-safety -- sanctioned A/B knob: trace-time
     # read baked into the compilation; flipping needs a fresh jit cache
     radix = os.environ.get("NF_RADIX", "")
@@ -262,17 +281,26 @@ def _sorted_segments(pos, active, cell_size: float, width: int,
         # NF_RADIX=<bits per pass>: 1 = binary partition passes,
         # 2/3 = 4-way/8-way digits (fewer irregular scatters)
         order = _radix_argsort(key, _bits_for(n_cells), int(radix))
+        skey = key[order]
     else:
-        order = jnp.argsort(key)  # stable: preserves row order within a cell
-    skey = key[order]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
+        # stable: preserves row order within a cell
+        rows = jnp.arange(key.shape[0], dtype=jnp.int32)
+        skey, order = jax.lax.sort((key, rows), num_keys=1, is_stable=True)
+    seg_start, rank = _segment_ranks(skey)
+    return order, skey, seg_start, rank
+
+
+def _sorted_segments(pos, active, cell_size: float, width: int,
+                     cell=None, n_cells: int | None = None):
+    """Shared build prefix of the SORT engine: the key pass, the ONE
+    stable sort by cell id (which returns the sorted keys with the
+    order) and per-element segment ranks.  Returns (n_cells, order,
+    skey, seg_start, rank) — everything both table builders derive slots
+    from."""
+    n_cells, key = _cell_keys(
+        pos, active, cell_size, width, cell=cell, n_cells=n_cells
     )
-    # index of each sorted element's segment head, via running max
-    start_idx = jax.lax.cummax(jnp.where(seg_start, idx, 0))
-    rank = idx - start_idx
-    return n_cells, order, skey, seg_start, rank
+    return (n_cells,) + _key_segments(key, n_cells)
 
 
 # --- the COUNT engine (NF_BINNING=count): histogram + bounded-rank
@@ -370,6 +398,15 @@ def _build_pair_counting(
     return full, sub
 
 
+def _sorted_slots(n_cells: int, skey, rank, bucket: int) -> jnp.ndarray:
+    """Flat payload slot of every SORTED element: `skey * bucket + rank`
+    where the rank fits the cell, the dump slot otherwise (overflow,
+    inactive).  The one placement rule of the sort engine."""
+    dump = n_cells * bucket
+    placed = (rank < bucket) & (skey < n_cells)
+    return jnp.where(placed, skey * bucket + rank, dump)
+
+
 def _slots_from_ranks(
     n: int, n_cells: int, order, skey, rank, bucket: int
 ) -> jnp.ndarray:
@@ -379,9 +416,57 @@ def _slots_from_ranks(
     builders below so the placement math cannot drift between the
     payload and fused engines."""
     dump = n_cells * bucket
-    placed = (rank < bucket) & (skey < n_cells)
-    flat_sorted = jnp.where(placed, skey * bucket + rank, dump)
+    flat_sorted = _sorted_slots(n_cells, skey, rank, bucket)
     return jnp.full((n,), dump, jnp.int32).at[order].set(flat_sorted)
+
+
+def sub_chunks(sub_mask: jnp.ndarray, sub_rows: int) -> jnp.ndarray:
+    """Chunks of `sub_rows` sorted entries that `build_cell_table_pair`
+    sends for this subset: ceil(members / sub_rows) as a traced i32
+    scalar, and 1 with no member (the first chunk always goes).  The
+    build sends exactly this many; callers count it to see how the chunk
+    they chose engages."""
+    sub_rows = max(1, min(sub_rows, sub_mask.shape[0]))
+    n_sub = jnp.sum(sub_mask, dtype=jnp.int32)
+    return jnp.maximum((n_sub + (sub_rows - 1)) // sub_rows, 1)
+
+
+def _chunked_payload(
+    features, order, flat_sorted, n_chunks, dump: int, sub_rows: int
+) -> jnp.ndarray:
+    """Payload table of a COMPACTED sorted list (members first): gather
+    and scatter `sub_rows` sorted entries a chunk, `n_chunks` chunks.
+    An entry past the members carries the dump slot, so a chunk that
+    reaches beyond them writes nothing that stays.
+
+    The first chunk is a static slice and always goes: it is the whole
+    job whenever `sub_rows` was sized for the subset.  Further chunks
+    run in a loop with a traced trip count (under `vmap` it runs to the
+    busiest lane's) and take their entries by an index vector, not by
+    `dynamic_slice`: under `vmap` the trip counter is per lane, and
+    XLA:TPU lowers a dynamic slice with a batched start as a loop over
+    the batch.  The last chunk of a list `sub_rows` does not divide is
+    clamped back inside it; writing an entry twice writes the same row
+    to the same slot."""
+    n, f = features.shape
+    occ = jnp.ones((sub_rows, 1), features.dtype)
+    lanes = jnp.arange(sub_rows, dtype=jnp.int32)
+
+    def put(payload, rows, slots):
+        feats = jnp.concatenate([features[rows], occ], axis=-1)
+        return payload.at[slots].set(feats)
+
+    def one_chunk(c, payload):
+        at = jnp.minimum(c * sub_rows, n - sub_rows) + lanes
+        return put(payload, order[at], flat_sorted[at])
+
+    payload = put(
+        jnp.zeros((dump + 1, f + 1), features.dtype),
+        order[:sub_rows], flat_sorted[:sub_rows],
+    )
+    payload = jax.lax.fori_loop(1, n_chunks, one_chunk, payload)
+    # dump slot may have been written by any loser; force it empty
+    return payload.at[dump].set(0.0)
 
 
 def slots_from_assignment(
@@ -544,31 +629,42 @@ def build_cell_table_pair(
     sub_bucket: int,
     cell: jnp.ndarray | None = None,
     height: int = -1,
+    sub_rows: int | None = None,
 ) -> Tuple[CellTable, CellTable]:
     """Build the full table AND a subset table from ONE key pass.
 
-    Dispatches on NF_BINNING: the sort engine derives both tables from a
-    single stable argsort; the count engine runs bounded scatter-min
-    selection per table (no sort at all).  Both produce bit-identical
-    tables — including which rows overflow to the dump slot.
+    Dispatches on NF_BINNING: the sort engine sorts twice (the whole
+    population, then the subset's keys) and ranks both from the sorted
+    keys; the count engine runs bounded scatter-min selection per table
+    (no sort at all).  Both produce bit-identical tables — including
+    which rows overflow to the dump slot.
 
     `sub_mask` must be a subset of `active` (combat: attackers among all
     alive entities).  Placement is bit-identical to two independent
     `build_cell_table` calls — within a cell both tables hold rows in
     ascending order, and the subset ranks are the subset's own ordinal
-    positions — but the second sort and its key gather are replaced by a
-    segmented cumsum over the shared sorted order.
+    positions.  What differs is the price: the subset's irregular passes
+    go by its members, not by the bank.  The second sort (1 ms at 2^20
+    rows on a v5e, where one N-row gather or scatter is 5-40) puts the
+    members first, so their features are gathered and their payload rows
+    scattered `sub_rows` sorted entries at a time, ceil(members /
+    sub_rows) chunks (`sub_chunks`): one chunk when the caller sized
+    `sub_rows` for the subset it expects, more when a tick exceeds it —
+    slower then, never different.  Default `sub_rows` is the whole bank
+    (one chunk: an N-row gather and scatter).
 
     cell/height: precomputed cell ids over a rectangular [height, width]
     grid (spatial slab shards); default square grid derived from pos.
 
     The one call here that both ranks and builds, and only combat makes
-    it, so it opens the device scopes `nf.aoe.rank` and `nf.aoe.table`
-    itself; every other scope of the neighbour engine is opened by the
-    caller (game/combat.py), because the interest programs share this
-    file."""
+    it, so it opens the device scopes `nf.aoe.rank` (sorts, heads, ranks,
+    slots) and `nf.aoe.table` (the victim scatter, the subset's chunk
+    gather and scatter) itself; every other scope of the neighbour
+    engine is opened by the caller (game/combat.py), because the
+    interest programs share this file."""
     n_rows = height if height > 0 else width
     n = pos.shape[0]
+    sub_rows = n if sub_rows is None else max(1, min(sub_rows, n))
     mode = binning_mode()
     if mode == "count":
         with jax.named_scope("nf.aoe.rank"):
@@ -583,32 +679,45 @@ def build_cell_table_pair(
     if mode != "sort":
         raise ValueError(f"unhandled binning mode {mode!r}")  # pragma: no cover
     with jax.named_scope("nf.aoe.rank"):
-        n_cells, order, skey, seg_start, rank = _sorted_segments(
+        n_cells, key = _cell_keys(
             pos, active, cell_size, width, cell=cell,
             n_cells=(n_rows * width if cell is not None else None),
         )
+        order, skey, _seg_start, rank = _key_segments(key, n_cells)
         slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
-        # subset ranks via segmented exclusive cumsum: ex is
-        # non-decreasing, so "ex at my segment's head" is a cummax over
-        # heads — no gather.  Non-members get an out-of-range rank so
-        # _slots_from_ranks sends them to the dump slot.
-        sub_sorted = sub_mask[order]
-        ex = (jnp.cumsum(sub_sorted.astype(jnp.int32))
-              - sub_sorted.astype(jnp.int32))
-        head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
-        sub_rank = jnp.where(
-            sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
+        # the subset, compacted by a second sort: members first, in cell
+        # order, rows ascending inside a cell — the order their ranks
+        # count in.  Heads, ranks and slots are streaming passes over
+        # that list; nothing here gathers or scatters by row.
+        sub_key = jnp.where(sub_mask, key, n_cells)
+        sub_order, sub_skey, _sub_start, sub_rank = _key_segments(
+            sub_key, n_cells)
+        sub_sorted_slots = _sorted_slots(
+            n_cells, sub_skey, sub_rank, sub_bucket)
+        sub_dump = n_cells * sub_bucket
+        sub_dropped = (
+            jnp.sum(sub_mask, dtype=jnp.int32)
+            - jnp.sum(sub_sorted_slots != sub_dump, dtype=jnp.int32)
+        )
+        n_chunks = sub_chunks(sub_mask, sub_rows)
+        # per-row slots keep their contract for the callers that pull
+        # through them (one un-sort scatter); the combat fold does not,
+        # and the compiler drops the scatter from the tick program
         sub_slot_of = _slots_from_ranks(
-            n, n_cells, order, skey, sub_rank, sub_bucket)
+            n, n_cells, sub_order, sub_skey, sub_rank, sub_bucket)
     with jax.named_scope("nf.aoe.table"):
         full = table_from_slots(
             features, active, slot_of, n_cells, cell_size, width, bucket,
             height,
         )
-        sub = table_from_slots(
-            sub_features, sub_mask, sub_slot_of, n_cells, cell_size, width,
-            sub_bucket, height,
+        sub_payload = _chunked_payload(
+            sub_features, sub_order, sub_sorted_slots, n_chunks, sub_dump,
+            sub_rows,
         )
+    sub = CellTable(
+        sub_payload, sub_slot_of, sub_dropped, width, cell_size, sub_bucket,
+        height,
+    )
     return full, sub
 
 

@@ -105,10 +105,17 @@ def test_cell_depth_is_stated_and_drops_are_summed(fleet):
     used = d.packer.used
     assert used.sum() == len(ids)
     for name in ("aoi_victim_overflow_drops", "aoi_attacker_overflow_drops",
+                 "aoe_attacker_chunks", "aoe_attacker_rows_sent",
                  "combat_hits", "diff_cells"):
         assert cols[name].shape == (d.batch.capacity,)
         assert d.counter_totals[name] - was.get(name, 0) \
             == int(cols[name][used].sum())
+    # a room's attacker chunk is sized from its arming (1/30 of its rows,
+    # twice over): one chunk a room-tick holds every room's attackers
+    rows = combat.resolved_att_rows(cap)
+    assert rows < cap
+    assert (cols["aoe_attacker_chunks"][used] == 1).all()
+    assert (cols["aoe_attacker_rows_sent"][used] == rows).all()
     assert "tick" not in d.counter_totals
 
 

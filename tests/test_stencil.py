@@ -4,14 +4,17 @@ determinism, and combat-phase equivalence with an O(N^2) reference."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from noahgameframe_tpu.game import GameWorld, WorldConfig
 from noahgameframe_tpu.game.defines import PropertyGroup
 from noahgameframe_tpu.ops.stencil import (
     auto_bucket,
     build_cell_table,
+    build_cell_table_pair,
     pull,
     stencil_fold,
+    sub_chunks,
 )
 
 
@@ -405,3 +408,264 @@ def test_cell_table_radix_parity(monkeypatch):
             np.asarray(t0.payload), np.asarray(t1.payload)
         )
         assert int(t0.dropped) == int(t1.dropped)
+
+
+# ---------------------------------------------- the chunked attacker side
+#
+# build_cell_table_pair compacts the subset by a second sort and gathers
+# and scatters `sub_rows` sorted members a trip.  Every case holds both
+# tables, field for field, against two INDEPENDENT single-table builds.
+
+
+def _assert_tables_equal(got, want):
+    np.testing.assert_array_equal(
+        np.asarray(got.payload), np.asarray(want.payload))
+    np.testing.assert_array_equal(
+        np.asarray(got.slot_of), np.asarray(want.slot_of))
+    np.testing.assert_array_equal(
+        np.asarray(got.dropped), np.asarray(want.dropped))
+
+
+def _pair_world(n, seed, p_active=0.9, p_sub=0.2, extent=40.0):
+    rng = np.random.RandomState(seed)
+    pos = rng.uniform(0, extent, (n, 2)).astype(np.float32)
+    active = rng.rand(n) < p_active
+    sub = active & (rng.rand(n) < p_sub)
+    feats = rng.randn(n, 3).astype(np.float32)
+    sub_feats = rng.randn(n, 2).astype(np.float32)
+    return pos, active, feats, sub, sub_feats
+
+
+def _straddle_world(n_sub):
+    """64 rows in a 2 x 2 grid of 8-unit cells; the first 12 attackers
+    share cell 0, so an 8-row chunk boundary falls inside that cell."""
+    n = 64
+    pos = np.full((n, 2), 12.0, np.float32)     # cell 3
+    pos[:12] = 2.0                              # cell 0: attackers 0..11
+    pos[12:24, 0] = 12.0                        # cell 1
+    pos[12:24, 1] = 2.0
+    active = np.ones(n, bool)
+    sub = np.zeros(n, bool)
+    sub[:n_sub] = True
+    rng = np.random.RandomState(n_sub)
+    return (pos, active, rng.randn(n, 3).astype(np.float32), sub,
+            rng.randn(n, 2).astype(np.float32))
+
+
+PAIR_CASES = {
+    # name: (world, cell, width, kv, ka, sub_rows, trips)
+    "no_attacker": (
+        lambda: _pair_world(500, 1, p_sub=0.0), 5.0, 8, 24, 4, 16, 1),
+    "everyone_attacks_many_trips": (
+        lambda: _pair_world(500, 2, p_active=1.0, p_sub=1.0),
+        5.0, 8, 24, 24, 48, 11),
+    "whole_bank_default": (
+        lambda: _pair_world(500, 3), 5.0, 8, 24, 6, None, 1),
+    "chunk_boundary_exact": (lambda: _straddle_world(16), 8.0, 2, 64, 16, 8, 2),
+    "chunk_boundary_one_under": (
+        lambda: _straddle_world(15), 8.0, 2, 64, 16, 8, 2),
+    "chunk_boundary_one_over": (
+        lambda: _straddle_world(17), 8.0, 2, 64, 16, 8, 3),
+    "overfull_attacker_cell": (
+        lambda: _straddle_world(20), 8.0, 2, 64, 5, 8, 3),
+    "sub_rows_not_dividing_n": (
+        lambda: _pair_world(500, 4, p_sub=0.5), 5.0, 8, 24, 8, 56, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAIR_CASES))
+def test_chunked_pair_build_matches_independent_builds(case):
+    world, cell, width, kv, ka, sub_rows, trips = PAIR_CASES[case]
+    pos, active, feats, sub, sub_feats = map(jnp.asarray, world())
+    vt, at = build_cell_table_pair(
+        pos, active, feats, sub, sub_feats, cell, width, kv, ka,
+        sub_rows=sub_rows,
+    )
+    _assert_tables_equal(
+        vt, build_cell_table(pos, active, feats, cell, width, kv))
+    want = build_cell_table(pos, sub, sub_feats, cell, width, ka)
+    _assert_tables_equal(at, want)
+    n_sub = int(np.asarray(sub).sum())
+    rows = pos.shape[0] if sub_rows is None else sub_rows
+    assert int(sub_chunks(sub, rows)) == max(1, -(-n_sub // rows))
+    if trips is not None:
+        assert max(1, -(-n_sub // rows)) == trips, "the case lost its shape"
+    if case == "overfull_attacker_cell":
+        # 12 attackers in cell 0 and 8 in cell 1, over a depth of 5
+        assert int(at.dropped) == (12 - ka) + (8 - ka)
+
+
+def test_chunked_pair_build_rectangular_grid():
+    """The `cell` / `height` form (spatial slabs): a 3-row, 8-wide grid
+    of precomputed cell ids, several trips."""
+    from noahgameframe_tpu.ops.stencil import _finish_table, _sorted_segments
+
+    pos, active, feats, sub, sub_feats = map(
+        jnp.asarray, _pair_world(400, 5, p_sub=0.4))
+    width, height, kv, ka = 8, 3, 40, 12
+    cell = (jnp.asarray(np.random.RandomState(6).randint(0, height, 400))
+            * width + jnp.clip((pos[:, 0] / 5.0).astype(jnp.int32), 0, 7))
+    vt, at = build_cell_table_pair(
+        pos, active, feats, sub, sub_feats, 5.0, width, kv, ka,
+        cell=cell, height=height, sub_rows=32,
+    )
+    assert vt.height == at.height == height
+    assert at.payload.shape == (width * height * ka + 1, 3)
+    for got, mask, f, k in ((vt, active, feats, kv), (at, sub, sub_feats, ka)):
+        n_cells, order, skey, _s, rank = _sorted_segments(
+            pos, mask, 5.0, width, cell=cell, n_cells=width * height)
+        _assert_tables_equal(got, _finish_table(
+            f, mask, n_cells, order, skey, rank, 5.0, width, k, height))
+
+
+def test_chunked_pair_build_under_vmap_runs_to_the_busiest_room():
+    """Four rooms of 32 rows, 4-row chunks: one room needs three chunks,
+    one needs one, two have no attacker at all (and send the first
+    chunk, as every build does)."""
+    rooms, n = 4, 32
+    worlds = [_pair_world(n, 10 + r, p_active=1.0, p_sub=0.0, extent=16.0)
+              for r in range(rooms)]
+    pos, active, feats, sub, sub_feats = (
+        np.stack([w[i] for w in worlds]) for i in range(5))
+    sub[2, 3:13] = True      # 10 attackers: three trips of 4
+    sub[3, [5, 20]] = True   # 2 attackers: one trip
+
+    def build(p, a, f, s, sf):
+        vt, at = build_cell_table_pair(
+            p, a, f, s, sf, 4.0, 4, 16, 6, sub_rows=4)
+        return (vt.payload, vt.slot_of, vt.dropped,
+                at.payload, at.slot_of, at.dropped, sub_chunks(s, 4))
+
+    got = jax.jit(jax.vmap(build))(pos, active, feats, sub, sub_feats)
+    np.testing.assert_array_equal(np.asarray(got[6]), [1, 1, 3, 1])
+    for r in range(rooms):
+        args = [jnp.asarray(x[r]) for x in (pos, active, feats, sub, sub_feats)]
+        vt = build_cell_table(args[0], args[1], args[2], 4.0, 4, 16)
+        at = build_cell_table(args[0], args[3], args[4], 4.0, 4, 6)
+        for g, w in zip(got[:6], (vt.payload, vt.slot_of, vt.dropped,
+                                  at.payload, at.slot_of, at.dropped)):
+            np.testing.assert_array_equal(np.asarray(g[r]), np.asarray(w))
+
+
+def test_chunked_pair_build_inside_scan():
+    """The fused loop's shape: the build inside `lax.scan`, a different
+    attacker set (0, 3 and 40 members; 8-row chunks) each step."""
+    pos, active, feats, _sub, sub_feats = map(
+        jnp.asarray, _pair_world(96, 20, p_active=1.0, extent=20.0))
+    subs = np.zeros((3, 96), bool)
+    subs[1, [4, 50, 90]] = True
+    subs[2, 10:50] = True
+
+    def step(carry, s):
+        _vt, at = build_cell_table_pair(
+            pos, active, feats, s, sub_feats, 5.0, 4, 24, 8, sub_rows=8)
+        return carry + sub_chunks(s, 8), (at.payload, at.slot_of, at.dropped)
+
+    trips, (payloads, slots, dropped) = jax.jit(
+        lambda xs: jax.lax.scan(step, jnp.int32(0), xs))(jnp.asarray(subs))
+    assert int(trips) == 1 + 1 + 5
+    for t in range(3):
+        want = build_cell_table(
+            pos, jnp.asarray(subs[t]), sub_feats, 5.0, 4, 8)
+        np.testing.assert_array_equal(
+            np.asarray(payloads[t]), np.asarray(want.payload))
+        np.testing.assert_array_equal(
+            np.asarray(slots[t]), np.asarray(want.slot_of))
+        assert int(dropped[t]) == int(want.dropped)
+
+
+# the 2,000-NPC benchmark world (seed 27), state digest after observed
+# ticks 1, 20 and 40, read from the parent of PR 27 (commit b541343) on
+# the CPU: the world drops a victim and boosts its buckets on the way
+PARENT_DIGESTS_2K = {1: 0x90A5E9F3, 20: 0x65B6B10E, 40: 0xE2EEBE7F}
+
+
+def _digests_2k(ticks=40):
+    from noahgameframe_tpu.game import build_benchmark_world
+
+    w = build_benchmark_world(2000, seed=27)
+    k = w.kernel
+    k.enable_digest()
+    out = {}
+    for t in range(1, ticks + 1):
+        w.tick()
+        out[t] = int(k.last_counters["state_digest"]) & 0xFFFFFFFF
+    return w, out
+
+
+def test_benchmark_world_digest_equals_the_parents(monkeypatch):
+    """40 observed ticks of the benchmark world end in the state the
+    parent's table build gave, digest for digest.  The pinned values are
+    CPU readings; the count engine (code this PR does not touch, the
+    same tables by contract) says whether this machine rounds as the
+    one they were read on did, and holds the sort engine either way."""
+    w, got = _digests_2k()
+    totals = w.kernel.counter_totals
+    assert totals["aoi_victim_overflow_drops"] == 1
+    assert totals["aoi_attacker_overflow_drops"] == 0
+    monkeypatch.setenv("NF_BINNING", "count")
+    _w, control = _digests_2k()
+    assert got == control
+    pinned = {t: control[t] for t in PARENT_DIGESTS_2K}
+    if pinned != PARENT_DIGESTS_2K:
+        pytest.skip("this CPU rounds differently from the one the "
+                    "parent's digests were read on")
+    assert {t: got[t] for t in PARENT_DIGESTS_2K} == PARENT_DIGESTS_2K
+
+
+def test_attacker_chunk_counters_follow_the_arming():
+    """`aoe_attacker_chunks` / `aoe_attacker_rows_sent`: one duty-sized
+    trip a tick under staggered arming; the whole bank in one trip after
+    `arm_all(stagger=False)`; and ceil(attackers / chunk) trips, with the
+    same hits as one whole-bank trip, when the timers are synchronised
+    behind the module's back (its chunk still sized for 1/30)."""
+    from noahgameframe_tpu.game import build_benchmark_world
+    from noahgameframe_tpu.game.combat import ATTACK_TIMER
+
+    def fire_all(w):
+        # arm every live row to fire on one tick (the second from now,
+        # as a delay of 1 does), without telling the module: a spawn
+        # wave armed synchronously
+        k = w.kernel
+        rows = np.flatnonzero(np.asarray(k.state.classes["NPC"].alive))
+        k.state = k.schedule.set_timer_rows(
+            k.state, "NPC", rows, ATTACK_TIMER, w.combat.attack_period_s,
+            start_delay_ticks=np.ones(len(rows), np.int64))
+        k.tick()
+        assert k.last_counters["combat_hits"] == 0  # nobody fires yet
+        k.tick()
+        return k.last_counters
+
+    w = build_benchmark_world(1000, seed=3)
+    k, combat = w.kernel, w.combat
+    cap = k.store.capacity("NPC")
+    rows = combat.resolved_att_rows(cap)
+    assert cap == 1024 and rows == 72  # 2 * ceil(1024 / 30), whole sublanes
+    for _ in range(4):  # the first chunk goes whether or not anyone fires
+        k.tick()
+        assert k.last_counters["aoe_attacker_chunks"] == 1
+        assert k.last_counters["aoe_attacker_rows_sent"] == rows
+    many = fire_all(w)
+    assert many["aoe_attacker_chunks"] == -(-1000 // rows) == 14
+    assert many["aoe_attacker_rows_sent"] == 14 * rows
+
+    # the same world with the chunk forced to the whole bank: same hits
+    w1 = build_benchmark_world(1000, seed=3)
+    w1.combat.resolved_att_rows = lambda capacity: capacity
+    w1.kernel.invalidate()
+    for _ in range(4):
+        w1.kernel.tick()
+        assert w1.kernel.last_counters["aoe_attacker_rows_sent"] == cap
+    one = fire_all(w1)
+    assert one["aoe_attacker_chunks"] == 1
+    assert one["aoe_attacker_rows_sent"] == cap
+    for name in ("combat_hits", "combat_damage_total",
+                 "aoi_attacker_overflow_drops", "aoi_victim_overflow_drops"):
+        assert many[name] == one[name], name
+    assert many["combat_hits"] > 0
+
+    combat.arm_all(stagger=False)
+    assert combat.resolved_att_rows(cap) == cap
+    k.tick()
+    assert k.last_counters["aoe_attacker_chunks"] == 1
+    assert k.last_counters["aoe_attacker_rows_sent"] == cap

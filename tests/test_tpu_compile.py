@@ -65,6 +65,25 @@ def _geometry(n):
             m.resolved_att_bucket(cap))
 
 
+def _attacker_side(text, table, rank_rows):
+    """What PR 27 holds the compiled tick to: (the `gather`s under
+    `nf.aoe.rank` whose result is `rank_rows`, the update operand of
+    every `scatter` into the `table`-shaped attacker payload)."""
+    import re
+
+    shape_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
+    rank_gathers = [
+        line for line in text.splitlines()
+        if re.search(r"= \w+\[%s\]\S* gather\(" % rank_rows, line)
+        and "nf.aoe.rank" in line]
+    updates = [
+        shape_of[m.group(1)] for m in re.finditer(
+            r"= f32\[%s\]\S* scatter\(%%[\w.\-]+, %%[\w.\-]+, "
+            r"(%%[\w.\-]+)\)" % table, text)]
+    return rank_gathers, updates
+
+
 def _shapes(tree, sharding):
     return jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
@@ -151,7 +170,8 @@ def test_world_seeding_fits_one_chip_at_1m(one_chip):
 def test_default_tick_compiles_for_one_chip(one_chip):
     """kernel._trace_step of the benchmark world (engine 0, the shipped
     default) at a 32,768-row capacity."""
-    k = build_benchmark_world(20_000, seed=0).kernel
+    w = build_benchmark_world(20_000, seed=0)
+    k, combat = w.kernel, w.combat
     k._ensure_aux()
     assert k.store.capacity("NPC") == 32_768
     state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
@@ -159,6 +179,16 @@ def test_default_tick_compiles_for_one_chip(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes < 16 * 1024 ** 3
+    # the attacker side is priced by attackers: nothing under the rank
+    # scope gathers by row, and the attacker table is scattered a
+    # duty-sized chunk at a time (once outright, once in the loop's body)
+    rows = combat.resolved_att_rows(32_768)
+    assert rows == 2192  # 2 * ceil(32768 / 30), whole sublanes
+    table = combat.width ** 2 * combat.resolved_att_bucket(32_768) + 1
+    rank_gathers, updates = _attacker_side(
+        compiled.as_text(), f"{table},8", "32768")
+    assert rank_gathers == []
+    assert updates == [f"f32[{rows},8]"] * 2
 
 
 def test_sharded_tick_compiles_for_the_2x2_mesh(topo):
@@ -224,3 +254,9 @@ def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip):
                   "nf.aoe.rank", "nf.aoe.table", "nf.aoe.fold",
                   "nf.aoe.pull", "nf.summary"):
         assert f"vmap({scope})" in text or f")/{scope}" in text, scope
+    # 16 sorted attackers a room are gathered and scattered, not its 128
+    # rows; the chunk's slices did not turn into loops over the rooms
+    rank_gathers, updates = _attacker_side(text, "794624,8", "8192,128")
+    assert rank_gathers == []
+    assert updates == ["f32[8192,16,8]"] * 2
+    assert "while/body/dynamic_slice" not in text
