@@ -128,13 +128,12 @@ def _costbook_detail(book, pipeline_stats=None) -> dict:
 
 def _combat_cost_probe(world) -> dict:
     """Attribute the combat fold's compiled cost to a per-engine
-    CostBook entry (``combat.fold_p0/p1/p2``) from the final world
-    state, OUTSIDE the timed region — so ``detail.costbook.entries``
-    carries the split-vs-fused ``bytes_accessed`` the r11 A/B compares
-    from the same ledger as everything else.  Probes the engine the run
-    actually used (including the fused path's VMEM downgrade), one
-    compile + one call; the fold math and geometry are exactly the
-    combat phase's (`game/combat.py` is the source of truth)."""
+    CostBook entry (``combat.fold_p0/p1``) from the final world state,
+    OUTSIDE the timed region — so ``detail.costbook.entries`` carries the
+    fold's ``bytes_accessed`` in the same ledger as everything else.
+    Probes the engine the run's trace baked in, one compile + one call;
+    the fold math and geometry are exactly the combat phase's
+    (`game/combat.py` is the source of truth)."""
     combat = getattr(world, "combat", None)
     if combat is None:
         return {}
@@ -144,14 +143,11 @@ def _combat_cost_probe(world) -> dict:
 
         from noahgameframe_tpu.game.combat import combat_fold_xla
         from noahgameframe_tpu.ops.stencil import (
-            CellSlots,
             CellTable,
-            build_cell_slots_pair,
             build_cell_table_pair,
         )
         from noahgameframe_tpu.ops.stencil_pallas import (
             combat_fold_pallas,
-            fused_neighborhood,
             pallas_interpret,
         )
 
@@ -165,11 +161,8 @@ def _combat_cost_probe(world) -> dict:
         cell_size, width = combat.cell_size, combat.width
         bucket = combat.resolved_bucket(cap)
         att_bucket = combat.resolved_att_bucket(cap)
-        # the engine the run's trace baked in (after any VMEM downgrade)
-        requested = combat.resolved_engine()
-        engine = (requested if combat.engine_baked is None
+        engine = (combat.resolved_engine() if combat.engine_baked is None
                   else combat.engine_baked)
-        fell_back = engine != requested
 
         f32 = jnp.float32
         camp_f = cs.i32[:, spec.slot("Camp").col].astype(f32)
@@ -179,71 +172,37 @@ def _combat_cost_probe(world) -> dict:
         interval = max(1, k.schedule.ticks_of(combat.attack_period_s))
         attacking = alive & ((jnp.arange(cap) % interval) == 0)
         interp = pallas_interpret()
-        book = k.costbook
         entry = f"combat.fold_p{engine}"
         radius = combat.radius
 
-        if engine == 2:
-            vic_s, att_s = build_cell_slots_pair(
-                pos, alive, attacking, cell_size, width, bucket, att_bucket
-            )
-            bank = jnp.stack(
-                [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f, atk_f], -1
-            )
-            fold = book.wrap(
-                entry,
-                lambda bk, vso, aso: fused_neighborhood(
-                    bk,
-                    CellSlots(vso, jnp.int32(0), width, cell_size, bucket),
-                    CellSlots(aso, jnp.int32(0), width, cell_size,
-                              att_bucket),
-                    radius, interpret=interp,
-                ),
-                stage="aoe",
-            )
-            jax.block_until_ready(fold(bank, vic_s.slot_of, att_s.slot_of))
-        else:
-            rows_f = jnp.arange(cap, dtype=f32)
-            vic_f = jnp.stack(
-                [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f], -1
-            )
-            att_f = jnp.stack(
-                [pos[:, 0], pos[:, 1], atk_f, camp_f, scene_f, group_f,
-                 rows_f], -1
-            )
-            vt, at = build_cell_table_pair(
-                pos, alive, vic_f, attacking, att_f,
-                cell_size, width, bucket, att_bucket,
+        rows_f = jnp.arange(cap, dtype=f32)
+        vic_f = jnp.stack(
+            [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f], -1
+        )
+        att_f = jnp.stack(
+            [pos[:, 0], pos[:, 1], atk_f, camp_f, scene_f, group_f,
+             rows_f], -1
+        )
+        vt, at = build_cell_table_pair(
+            pos, alive, vic_f, attacking, att_f,
+            cell_size, width, bucket, att_bucket,
+        )
+
+        def fold_of(vp, vs, ap, as_):
+            tables = (
+                CellTable(vp, vs, jnp.int32(0), width, cell_size, bucket),
+                CellTable(ap, as_, jnp.int32(0), width, cell_size,
+                          att_bucket),
             )
             if engine == 1:
-                fold = book.wrap(
-                    entry,
-                    lambda vp, vs, ap, as_: combat_fold_pallas(
-                        CellTable(vp, vs, jnp.int32(0), width, cell_size,
-                                  bucket),
-                        CellTable(ap, as_, jnp.int32(0), width, cell_size,
-                                  att_bucket),
-                        radius, interpret=interp,
-                    ),
-                    stage="aoe",
-                )
-            else:
-                fold = book.wrap(
-                    entry,
-                    lambda vp, vs, ap, as_: combat_fold_xla(
-                        CellTable(vp, vs, jnp.int32(0), width, cell_size,
-                                  bucket),
-                        CellTable(ap, as_, jnp.int32(0), width, cell_size,
-                                  att_bucket),
-                        radius,
-                    ),
-                    stage="aoe",
-                )
-            jax.block_until_ready(
-                fold(vt.payload, vt.slot_of, at.payload, at.slot_of)
-            )
-        return {"engine": engine, "vmem_fallback": fell_back,
-                "entry": entry}
+                return combat_fold_pallas(*tables, radius, interpret=interp)
+            return combat_fold_xla(*tables, radius)
+
+        fold = k.costbook.wrap(entry, fold_of, stage="aoe")
+        jax.block_until_ready(
+            fold(vt.payload, vt.slot_of, at.payload, at.slot_of)
+        )
+        return {"engine": engine, "entry": entry}
     except Exception as e:  # noqa: BLE001 — evidence, never a bench kill
         return {"error": f"{type(e).__name__}: {e}"}
 
@@ -342,7 +301,6 @@ def run_served(args) -> dict:
     from noahgameframe_tpu.net.roles.base import RoleConfig
     from noahgameframe_tpu.net.roles.game import GameRole, Session
     from noahgameframe_tpu.net.wire import Ident, ident_key
-    from noahgameframe_tpu.ops.stencil import binning_mode
     from noahgameframe_tpu.utils.platform import init_compile_cache
 
     init_compile_cache()
@@ -439,7 +397,6 @@ def run_served(args) -> dict:
             "device": str(dev),
             "platform": dev.platform,
             "device_kind": dev.device_kind,
-            "binning": binning_mode(),
             # per-stage frame waterfall (ISSUE 7): p50/p95/mean ms per
             # pipeline stage from the role's StageClock, plus the last
             # frame's exact breakdown and trace-sidecar counters
@@ -463,7 +420,6 @@ def run_sharded(args) -> dict:
     init_compile_cache()  # pay the XLA compile once
 
     from noahgameframe_tpu.game import build_benchmark_world
-    from noahgameframe_tpu.ops.stencil import binning_mode
     from noahgameframe_tpu.parallel import ShardedKernel
 
     n = args.entities
@@ -504,7 +460,6 @@ def run_sharded(args) -> dict:
             "combat": not args.no_combat,
             "grid_overflow_max": grid_drop,
             "att_overflow_max": att_drop,
-            "binning": binning_mode(),
             "costbook": _costbook_detail(k.costbook),
         },
     }
@@ -526,7 +481,7 @@ def run_mesh_migrate(args) -> dict:
 
     import numpy as np
 
-    from noahgameframe_tpu.ops.stencil import auto_bucket, binning_mode
+    from noahgameframe_tpu.ops.stencil import auto_bucket
     from noahgameframe_tpu.parallel.spatial import SpatialGeom, SpatialWorld
 
     entities = [int(x) for x in
@@ -620,7 +575,6 @@ def run_mesh_migrate(args) -> dict:
             "devices": args.mesh_migrate,
             "seed": args.seed,
             "platform": jax.devices()[0].platform,
-            "binning": binning_mode(),
             "engine": "unified (full-row ClassState migration)",
             "unexplained_recompiles": sum(p["unexplained_recompiles"]
                                           for p in points),
@@ -799,7 +753,6 @@ def run_bench(args) -> dict:
     import jax
 
     from noahgameframe_tpu.game import build_benchmark_world
-    from noahgameframe_tpu.ops.stencil import binning_mode
     from noahgameframe_tpu.ops.verlet import skin_from_env
     from noahgameframe_tpu.utils.platform import init_compile_cache
 
@@ -919,7 +872,7 @@ def run_bench(args) -> dict:
     # fetches the on-device counter bank for the detail block below
     dp50, dp95, dp99 = _hist_pcts(dev_hist)
     grid_drop, att_drop = _overflow_gauges(world)
-    # per-engine combat-fold cost attribution (combat.fold_p{0,1,2} in
+    # per-engine combat-fold cost attribution (combat.fold_p{0,1} in
     # detail.costbook.entries) — outside every timed region
     pallas_probe = _combat_cost_probe(world)
 
@@ -962,12 +915,8 @@ def run_bench(args) -> dict:
             # elected skin, whether or not Verlet caches engaged — a run
             # is only reproducible with the same (seed, skin) pair
             "verlet_skin": skin_from_env(),
-            # which slot-assignment engine built the cell tables — the
-            # label the count-vs-sort A/B (and decide_tuning) reads
-            "binning": binning_mode(),
-            # which combat fold engine ran (0 split-XLA / 1 split-Pallas
-            # / 2 fused table-free), after any VMEM downgrade — the
-            # label the NF_PALLAS tri-state A/B joins on
+            # which combat fold engine ran (0 XLA / 1 Pallas) — the
+            # label the NF_PALLAS A/B joins on
             **({"pallas_engine": pallas_probe.get("engine"),
                 "pallas_probe": pallas_probe} if pallas_probe else {}),
             **({"verlet": verlet} if verlet else {}),
@@ -1058,15 +1007,13 @@ def _run_session_sweep(args) -> dict:
 
 
 def _run_pallas_ab(args) -> dict:
-    """--sweep-ab without --sweep-sessions: waterfall the three combat
-    engines (NF_PALLAS 0 split-XLA / 1 split-Pallas fold / 2 fused
-    table-free) in one invocation.  Each engine runs in a SUBPROCESS
-    with an explicit ``--pallas`` pin — the knob is read at trace time,
-    so respawning is the only way to get three honest traces — and a
-    crash or OOM in one engine can't burn the others' points.  Each
-    point keeps its ``combat.fold_p*`` costbook entry, so the r11
-    artifact reads split-vs-fused bytes_accessed from one payload.
-    With ``--train K`` a fourth arm rides along: the winning fused
+    """--sweep-ab without --sweep-sessions: waterfall the two combat
+    fold engines (NF_PALLAS 0 XLA / 1 Pallas) in one invocation.  Each
+    engine runs in a SUBPROCESS with an explicit ``--pallas`` pin — the
+    knob is read at trace time, so respawning is the only way to get
+    honest traces — and a crash or OOM in one engine can't burn the
+    other's point.  Each point keeps its ``combat.fold_p*`` costbook
+    entry.  With ``--train K`` a third arm rides along: the default
     engine re-run under K-tick observed trains (r13)."""
     def one(engine: int, train: int = 0) -> dict:
         cmd = [
@@ -1099,7 +1046,7 @@ def _run_pallas_ab(args) -> dict:
                 point["value"] = p.get("value")
                 d = p.get("detail") or {}
                 for key in ("tick_ms", "tick_ms_p50_device", "platform",
-                            "pallas_engine", "pallas_probe", "binning",
+                            "pallas_engine", "pallas_probe",
                             "tick_train", "train_dispatches"):
                     point[key] = d.get(key)
                 entries = ((d.get("costbook") or {}).get("entries")) or {}
@@ -1112,10 +1059,10 @@ def _run_pallas_ab(args) -> dict:
         point["tail"] = (r.stderr or "").strip().splitlines()[-3:]
         return point
 
-    points = [one(e) for e in (0, 1, 2)]
+    points = [one(e) for e in (0, 1)]
     train_k = int(getattr(args, "train", 0) or 0)
     if train_k > 1:
-        points.append(one(2, train=train_k))
+        points.append(one(0, train=train_k))
     head = next(
         (p for p in points if p.get("value") and not p.get("error")), None
     )
@@ -1182,17 +1129,15 @@ def main() -> None:
         "--sweep-ab", action="store_true",
         help="with --sweep-sessions: also run the legacy engine at "
              "every count (before/after waterfall pairs).  Without "
-             "--sweep-sessions: waterfall the three combat engines "
-             "(--pallas 0/1/2), each in a subprocess, into one payload",
+             "--sweep-sessions: waterfall the two combat fold engines "
+             "(--pallas 0/1), each in a subprocess, into one payload",
     )
     ap.add_argument(
-        "--pallas", type=int, choices=(0, 1, 2), default=None,
-        help="combat fold engine: 0 split-table XLA stencil, 1 "
-             "split-table Pallas fold, 2 fused table-free neighborhood "
-             "engine (VMEM-oversize configs downgrade to 0).  Sets "
-             "NF_PALLAS for this process — the knob is read at trace "
-             "time, so A/B sweeps respawn one subprocess per engine; "
-             "overrides bench_runs/tuning.json",
+        "--pallas", type=int, choices=(0, 1), default=None,
+        help="combat fold engine: 0 XLA stencil fold, 1 Pallas fold "
+             "over the same tables.  Sets NF_PALLAS for this process — "
+             "the knob is read at trace time, so A/B sweeps respawn one "
+             "subprocess per engine; overrides bench_runs/tuning.json",
     )
     ap.add_argument(
         "--sweep-timeout", type=float, default=900.0,

@@ -323,9 +323,7 @@ def phase_determinism_and_engines(sz, seed, cache, platform) -> None:
          pallas_interpret=pallas_interpret(),
          ticks=int(p.kernel.tick_count),
          digests_equal=(da + da2) == (dp + dp2), banks_equal=banks_equal,
-         respawns=p.kernel.counter_totals.get("respawns", 0),
-         engine2="not run: the chip's compiler refuses fused_neighborhood "
-                 "(pinned by tests/test_tpu_compile.py)")
+         respawns=p.kernel.counter_totals.get("respawns", 0))
     gate(a.combat.engine_baked == 0 and p.combat.engine_baked == 1,
          "each world baked the engine it was asked for")
     gate(pallas_interpret() == (platform == "cpu"),

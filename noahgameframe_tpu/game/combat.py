@@ -33,11 +33,8 @@ from ..core.store import HANDLE_ROW_BITS, WorldState, with_class
 from ..kernel.module import Module
 from ..ops.stencil import (
     auto_bucket,
-    binning_mode,
-    build_cell_slots_pair,
     build_cell_table_pair,
     pull_slots,
-    slots_from_assignment,
     stencil_fold,
     sub_chunks,
 )
@@ -46,7 +43,6 @@ from ..ops.verlet import (
     init_cache,
     refresh,
     skin_from_env,
-    sub_slots,
     sub_table,
 )
 from .defines import GameEvent
@@ -137,7 +133,7 @@ def combat_fold_xla(vic_table, att_table, radius):
     (inc [H, W, Kv] int32 damage totals, bestr [H, W, Kv] int32 row id
     of the strongest in-range attacker, -1 = none) — and the single
     source of truth for the fold's feature-column layout and tie-break
-    semantics (scripts/profile_passes.py times this exact function).
+    semantics.
 
     Victim payload columns: x, y, camp, scene, group (+occupancy).
     Attacker payload columns: x, y, eff_atk, camp, scene, group, row.
@@ -202,26 +198,19 @@ class CombatModule(Module):
         self.overflow_total = 0
         self.overflow_alerts = 0
         self._overflow_log_muted = False
-        # tri-state Pallas engine selector (None = NF_PALLAS env knob):
-        #   0/False  XLA stencil fold over split cell tables
-        #   1/True   Pallas fold kernel over the same split tables
+        # fold engine selector (None = NF_PALLAS env knob):
+        #   0/False  XLA stencil fold over the cell tables
+        #   1/True   Pallas fold kernel over the same tables
         #            (ops/stencil_pallas.combat_fold_pallas)
-        #   2        fused table-free neighborhood engine: gather from
-        #            the SoA bank via slot ranks, fold combat + AOI
-        #            occupancy on-core, never materialize the payload
-        #            tables (ops/stencil_pallas.fused_neighborhood).
-        #            Downgrades to 0 when the tile footprint exceeds the
-        #            VMEM budget (nf_pallas_fallback_total metric).
         # Opt-in until chip-time confirms a win.  (The stencil engine is
         # the only combat engine: at honest bucket sizes it beats the old
         # per-candidate-gather pipeline even on a single CPU core —
         # 103 ms vs 186 ms at 100k — and by ~25x on a v5e, where
         # irregular gathers run at ~1% of HBM bandwidth.)
         self.use_pallas = use_pallas
-        # the engine the newest trace actually baked in, after any VMEM
-        # downgrade (None until the first trace) — read by the
-        # nf_combat_fold_engine gauge, bench.py and chip_smoke.py, so a
-        # kernel that gave way to the reference is visible in the run
+        # the engine the newest trace baked in (None until the first
+        # trace) — read by the nf_combat_fold_engine gauge, bench.py,
+        # chip_smoke.py and the benchmark's tick driver
         self.engine_baked: Optional[int] = None
         # fraction of the population the attacker candidate table is sized
         # for; 1.0 (safe default) means "everyone could fire on one tick".
@@ -369,15 +358,11 @@ class CombatModule(Module):
         return min(-(-2 * eff // 8) * 8, capacity)
 
     def resolved_engine(self) -> int:
-        """The combat engine this trace will bake in: 0 (XLA fold over
-        split tables), 1 (Pallas fold, same tables) or 2 (fused
-        table-free neighborhood).  `use_pallas` wins when set (bools keep
-        their historical meaning: True == 1); otherwise NF_PALLAS decides.
-        Unknown env values raise instead of silently running the default
-        — a typo'd engine would invalidate any A/B it labeled (same
-        contract as ops.stencil.binning_mode).  The VMEM-budget downgrade
-        for engine 2 happens at the dispatch site, not here — this is the
-        *requested* engine."""
+        """The fold engine this trace will bake in: 0 (XLA fold) or 1
+        (Pallas fold, same tables).  `use_pallas` wins when set (bools
+        keep their historical meaning: True == 1); otherwise NF_PALLAS
+        decides.  Unknown values raise instead of silently running the
+        default — a typo'd engine would invalidate any A/B it labeled."""
         mode = self.use_pallas
         if mode is None:
             import os
@@ -386,14 +371,14 @@ class CombatModule(Module):
             # trace-time read baked into the compiled fold; flipping
             # NF_PALLAS needs a fresh jit cache by design
             raw = os.environ.get("NF_PALLAS", "").strip()
-            if raw in ("", "0", "1", "2"):
+            if raw in ("", "0", "1"):
                 return int(raw or "0")
             raise ValueError(
-                f"NF_PALLAS={raw!r}: expected one of '', '0', '1', '2'"
+                f"NF_PALLAS={raw!r}: expected one of '', '0', '1'"
             )
         mode = int(mode)
-        if mode not in (0, 1, 2):
-            raise ValueError(f"use_pallas={mode!r}: expected 0, 1 or 2")
+        if mode not in (0, 1):
+            raise ValueError(f"use_pallas={mode!r}: expected 0 or 1")
         return mode
 
     # -- device phases -------------------------------------------------------
@@ -426,27 +411,7 @@ class CombatModule(Module):
         n = pos.shape[0]
         bucket = self.resolved_bucket(n)
         att_bucket = self.resolved_att_bucket(n)
-        engine = self.resolved_engine()
-        if engine == 2:
-            from ..ops.stencil_pallas import (
-                fused_fits_vmem,
-                note_fused_fallback,
-            )
-
-            # host-side VMEM gate on the static geometry: an oversize
-            # world (1M-entity bank alone outgrows a core's VMEM) must
-            # fall back to the split-table path, not fail in Mosaic
-            fits, need, budget_b = fused_fits_vmem(
-                n, self.width, bucket, att_bucket
-            )
-            if not fits:
-                note_fused_fallback(
-                    f"{cname}: n={n} width={self.width} "
-                    f"bucket={bucket}/{att_bucket}",
-                    need, budget_b,
-                )
-                engine = 0
-        self.engine_baked = engine
+        engine = self.engine_baked = self.resolved_engine()
         # TWO tables: every alive entity is RESIDENT as a victim (K deep),
         # but only this tick's attackers ride the 9x-scanned candidate
         # side (K_att deep — with staggered attack phases K_att is
@@ -483,10 +448,9 @@ class CombatModule(Module):
             # displacement-gated build (ops/verlet.py): the argsort only
             # runs when some entity drifted >= skin/2 from its binning
             # anchor (or the alive set changed); otherwise both payload
-            # scatters (or, on the fused path, just the slot bookkeeping)
-            # replay against the cached slot assignment.  The fold below
-            # masks by TRUE radius on current positions, so results stay
-            # bit-identical to rebuilding every tick.
+            # scatters replay against the cached slot assignment.  The
+            # fold below masks by TRUE radius on current positions, so
+            # results stay bit-identical to rebuilding every tick.
             aux_key = f"verlet/{cname}"
             with jax.named_scope("nf.aoe.rank"):
                 cache, rebuilt = refresh(
@@ -494,39 +458,19 @@ class CombatModule(Module):
                     self.cell_size, self.width, bucket, self.verlet_skin,
                 )
             n_cells = self.width * self.width
-            if engine == 2:
-                # slots only — the payload tables are never materialized
-                with jax.named_scope("nf.aoe.rank"):
-                    vic_bin = slots_from_assignment(
-                        cs.alive, cache.slot_of, n_cells,
-                        self.cell_size, self.width, bucket,
-                    )
-                    att_bin = slots_from_assignment(
-                        attacking,
-                        sub_slots(cache, attacking, n_cells, att_bucket),
-                        n_cells, self.cell_size, self.width, att_bucket,
-                    )
-            else:
-                with jax.named_scope("nf.aoe.table"):
-                    vic_bin = full_table(
-                        cache, vic_feats, cs.alive, n_cells,
-                        self.cell_size, self.width, bucket,
-                    )
-                    att_bin = sub_table(
-                        cache, attacking, att_feats, n_cells,
-                        self.cell_size, self.width, att_bucket,
-                    )
+            with jax.named_scope("nf.aoe.table"):
+                vic_bin = full_table(
+                    cache, vic_feats, cs.alive, n_cells,
+                    self.cell_size, self.width, bucket,
+                )
+                att_bin = sub_table(
+                    cache, attacking, att_feats, n_cells,
+                    self.cell_size, self.width, att_bucket,
+                )
             ctx.count("grid_rebuilds", rebuilt)
             ctx.count("grid_reuses", 1 - rebuilt)
             ctx.count("grid_cache_age", cache.age)
             state = state.replace(aux={**state.aux, aux_key: cache})
-        elif engine == 2:
-            # one key pass feeds both slot assignments, no payloads
-            with jax.named_scope("nf.aoe.rank"):
-                vic_bin, att_bin = build_cell_slots_pair(
-                    pos, cs.alive, attacking,
-                    self.cell_size, self.width, bucket, att_bucket,
-                )
         else:
             # one key pass feeds both tables (attackers subset of alive);
             # this one call ranks and builds: it opens nf.aoe.rank and
@@ -538,34 +482,13 @@ class CombatModule(Module):
                 self.cell_size, self.width, bucket, att_bucket,
                 sub_rows=att_rows,
             )
-            if binning_mode() == "sort":
-                # how the chunk engages: 1 a tick under the arming it was
-                # sized for (the count engine sends the bank, unchunked)
-                chunks = sub_chunks(attacking, att_rows)
-                ctx.count("aoe_attacker_chunks", chunks)
-                ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
-        nbr = None
+            # how the chunk engages: 1 a tick under the arming it was
+            # sized for
+            chunks = sub_chunks(attacking, att_rows)
+            ctx.count("aoe_attacker_chunks", chunks)
+            ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
         with jax.named_scope("nf.aoe.fold"):
-            if engine == 2:
-                from ..ops.stencil_pallas import (
-                    fused_neighborhood,
-                    pallas_interpret,
-                )
-
-                # one shared SoA bank serves both sides of the fold; the
-                # attacker row id is the gather index itself
-                bank = jnp.stack(
-                    [pos[:, 0], pos[:, 1], camp_f, scene_f, group_f, eff_atk],
-                    axis=-1,
-                )
-                inc, bestr, nbr = fused_neighborhood(
-                    bank,
-                    vic_bin,
-                    att_bin,
-                    self.radius,
-                    interpret=pallas_interpret(),
-                )
-            elif engine == 1:
+            if engine == 1:
                 from ..ops.stencil_pallas import (
                     combat_fold_pallas,
                     pallas_interpret,
@@ -605,15 +528,6 @@ class CombatModule(Module):
                 vic_bin.slot_of, jnp.stack([inc, bestr], axis=-1),
                 fill=(0, -1),
             )
-            if nbr is not None:
-                # fused-path bonus output: the AOI/interest occupancy
-                # count per entity (scope per ops.interest.scope_mask,
-                # self excluded) — a counter, not state, so digests stay
-                # bit-identical across engines
-                ctx.count(
-                    "aoi_interest_pairs",
-                    pull_slots(vic_bin.slot_of, nbr, fill=0),
-                )
         incoming = pulled[..., 0]
         # dead-but-not-yet-respawned victims take no damage
         incoming = jnp.where(cs.alive & (hp > 0), incoming, 0)

@@ -182,9 +182,8 @@ class GameWorld:
                 lambda: -1 if combat.engine_baked is None
                 else combat.engine_baked,
                 kind="gauge",
-                help="combat fold engine baked into the newest trace, "
-                     "after any VMEM downgrade (0 XLA, 1 Pallas fold, "
-                     "2 fused; -1 before the first trace)",
+                help="combat fold engine baked into the newest trace "
+                     "(0 XLA, 1 Pallas fold; -1 before the first trace)",
             )
 
         # elastic mesh surface (parallel/elastic.py): populated by

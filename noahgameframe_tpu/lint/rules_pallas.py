@@ -10,7 +10,6 @@ a pallas_call declares a literal registry::
 
     PALLAS_PARITY_TESTS = {
         "combat_fold_pallas": "tests/test_stencil_pallas.py",
-        "fused_neighborhood": "tests/test_stencil_pallas.py",
     }
 
 mapping the enclosing function name to the test file that pins it.  The

@@ -46,7 +46,6 @@ is BASELINE config 4).
 from __future__ import annotations
 
 import math
-import os
 from typing import Callable, NamedTuple, Tuple, TypeVar
 
 import jax
@@ -56,58 +55,10 @@ from .aoi import cell_of
 
 A = TypeVar("A")
 
-# NF_BINNING picks the slot-assignment engine behind build_cell_table /
-# build_cell_table_pair (and the Verlet rebuild arm).  "sort" is the
-# original stable-argsort path; "count" is the sort-free counting path
-# (_cell_counts / _counting_ranks / _counting_slots) — bit-identical
-# tables, O(K*(N + n_cells)) streaming work instead of an O(N log N)
-# comparison network.  Trace-time like NF_RADIX: flip it, then retrace.
-ENV_BINNING = "NF_BINNING"
-BINNING_MODES = ("sort", "count")
-
-
-def binning_mode() -> str:
-    """The validated NF_BINNING mode; unset/empty means "sort".
-
-    Unknown values raise instead of falling through — a typo'd mode
-    silently running the default would invalidate any A/B it labeled.
-    This is the ONLY place the env var is read (pinned by
-    tests/test_binning.py's lint guard)."""
-    # nf-lint: disable=trace-safety -- sanctioned A/B knob: read once at
-    # trace time and baked into the compiled tick; tests pin this as the
-    # only NF_BINNING read and flipping it requires a fresh jit cache
-    raw = os.environ.get(ENV_BINNING, "").strip()
-    if not raw:
-        return "sort"
-    if raw not in BINNING_MODES:
-        raise ValueError(
-            f"{ENV_BINNING}={raw!r}: expected one of {BINNING_MODES}"
-        )
-    return raw
-
 # 3x3 stencil in (dy, dx) order — must match ops.aoi._STENCIL so candidate
 # iteration order (and therefore argmax tie-breaking) is identical across
 # both engines.
 STENCIL = [(dy, dx) for dy in (-1, 0, 1) for dx in (-1, 0, 1)]
-
-
-class CellSlots(NamedTuple):
-    """A slot assignment WITHOUT the payload materialization.
-
-    The fused Pallas engine (ops/stencil_pallas.py, NF_PALLAS=2) gathers
-    features straight from the SoA banks via these slots, so the padded
-    `[n_cells*K + 1, F+1]` payload table — the biggest per-frame HBM
-    materialization of the split path — is never written.  Same slot
-    semantics as CellTable (dump slot == n_cells*K for unplaced rows,
-    `dropped` counts active overflow), minus the scatter.
-    """
-
-    slot_of: jnp.ndarray
-    dropped: jnp.ndarray
-    width: int
-    cell_size: float
-    bucket: int
-    height: int = -1
 
 
 class CellTable(NamedTuple):
@@ -165,75 +116,10 @@ def auto_bucket(
     return -(-k // align) * align
 
 
-def _radix_argsort(
-    key: jnp.ndarray, n_bits: int, bits_per_pass: int = 1
-) -> jnp.ndarray:
-    """Stable LSD radix argsort for small non-negative int keys.
-
-    XLA's TPU `sort` is a comparison network with poor large-N
-    efficiency; per docs/ROOFLINE.md it is the prime suspect for the
-    1M-tick gap.  This replaces it with ceil(n_bits / bits_per_pass)
-    stable partition passes — streaming cumsums plus two unique-index
-    scatters per pass over [N] i32 — instead of O(log^2 N) comparison
-    stages.  Bit-identical to `jnp.argsort(key)` (both stable).
-
-    bits_per_pass trades cumsum work for scatter count: the two
-    permutation scatters are the irregular (bandwidth-hostile) part of
-    a pass, so 2-3 bits per pass cuts them 2-3x while the added
-    per-digit cumsum planes ([N, 2^b] one-hot) stay cheap streaming
-    work.  Opt-in via NF_RADIX=<bits_per_pass> until chip time ranks
-    the variants against XLA's sort (virtual-CPU timing cannot)."""
-    n = key.shape[0]
-    order = jnp.arange(n, dtype=jnp.int32)
-    b = max(1, int(bits_per_pass))
-    n_digits = 1 << b
-    n_passes = -(-n_bits // b)
-    mask = n_digits - 1
-
-    if b == 1:
-        def one_pass(i, kv):
-            k, o = kv
-            bit = (k >> (i * 1)) & 1
-            zeros = jnp.cumsum(1 - bit)  # inclusive; stable in each half
-            ones = jnp.cumsum(bit)
-            pos = jnp.where(bit == 0, zeros - 1, zeros[-1] + ones - 1)
-            return (
-                jnp.zeros_like(k).at[pos].set(k),
-                jnp.zeros_like(o).at[pos].set(o),
-            )
-    else:
-        def one_pass(i, kv):
-            k, o = kv
-            digit = (k >> (i * b)) & mask
-            onehot = (
-                digit[:, None] == jnp.arange(n_digits, dtype=k.dtype)[None, :]
-            ).astype(jnp.int32)
-            incl = jnp.cumsum(onehot, axis=0)  # [N, D] running count per digit
-            totals = incl[-1]
-            base = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), jnp.cumsum(totals)[:-1]]
-            )
-            rank = jnp.take_along_axis(incl, digit[:, None], axis=1)[:, 0]
-            pos = base[digit] + rank - 1
-            return (
-                jnp.zeros_like(k).at[pos].set(k),
-                jnp.zeros_like(o).at[pos].set(o),
-            )
-
-    _, order = jax.lax.fori_loop(0, n_passes, one_pass, (key, order))
-    return order
-
-
-def _bits_for(n_cells: int) -> int:
-    """Bits needed for keys in [0, n_cells] (the inactive key IS
-    n_cells, so it must be representable)."""
-    return max(1, int(n_cells).bit_length())
-
-
 def _cell_keys(pos, active, cell_size: float, width: int,
                cell=None, n_cells: int | None = None):
-    """Shared key pass for BOTH binning engines: per-row sort/bin key
-    (cell id, or n_cells for inactive rows).  Returns (n_cells, key).
+    """The key pass of every build: per-row sort key (cell id, or n_cells
+    for inactive rows).  Returns (n_cells, key).
 
     cell/n_cells: precomputed per-row cell ids over a caller-defined
     (possibly rectangular) grid — the spatial slab shards pass local
@@ -252,156 +138,30 @@ def _cell_keys(pos, active, cell_size: float, width: int,
     return n_cells, key
 
 
-def _segment_ranks(skey: jnp.ndarray):
-    """Streaming half of a sorted build: from the SORTED keys, the head
-    flag of each run of equal keys and every element's ordinal inside its
-    run.  Returns (seg_start, rank)."""
+def _key_segments(key: jnp.ndarray):
+    """The build's one sort and the streaming passes behind it, for any
+    per-row key: (order, skey, rank), `rank` being every sorted element's
+    ordinal inside its run of equal keys.  The sort is the stable sort of
+    `(key, row)` that `jnp.argsort(key)` runs inside, with both results
+    kept: `skey` is the sort's own key result, not a `key[order]` gather
+    (an N-row irregular pass, 9 ms at 2^20 on a v5e, to re-read what the
+    sort had just put in order)."""
+    # stable: preserves row order within a cell
+    rows = jnp.arange(key.shape[0], dtype=jnp.int32)
+    skey, order = jax.lax.sort((key, rows), num_keys=1, is_stable=True)
     idx = jnp.arange(skey.shape[0], dtype=jnp.int32)
     seg_start = jnp.concatenate(
         [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
     )
     # index of each sorted element's segment head, via running max
     start_idx = jax.lax.cummax(jnp.where(seg_start, idx, 0))
-    return seg_start, idx - start_idx
-
-
-def _key_segments(key: jnp.ndarray, n_cells: int):
-    """The SORT engine's one sort and the streaming passes behind it, for
-    any per-row key in [0, n_cells]: (order, skey, seg_start, rank).
-    The sort is the stable sort of `(key, row)` that `jnp.argsort(key)`
-    runs inside, with both results kept: `skey` is the sort's own key
-    result, not a `key[order]` gather (an N-row irregular pass, 9 ms at
-    2^20 on a v5e, to re-read what the sort had just put in order).
-    Only the NF_RADIX knob path, which produces an order alone, gathers
-    it."""
-    # nf-lint: disable=trace-safety -- sanctioned A/B knob: trace-time
-    # read baked into the compilation; flipping needs a fresh jit cache
-    radix = os.environ.get("NF_RADIX", "")
-    if radix.isdigit() and int(radix) > 0:
-        # NF_RADIX=<bits per pass>: 1 = binary partition passes,
-        # 2/3 = 4-way/8-way digits (fewer irregular scatters)
-        order = _radix_argsort(key, _bits_for(n_cells), int(radix))
-        skey = key[order]
-    else:
-        # stable: preserves row order within a cell
-        rows = jnp.arange(key.shape[0], dtype=jnp.int32)
-        skey, order = jax.lax.sort((key, rows), num_keys=1, is_stable=True)
-    seg_start, rank = _segment_ranks(skey)
-    return order, skey, seg_start, rank
-
-
-def _sorted_segments(pos, active, cell_size: float, width: int,
-                     cell=None, n_cells: int | None = None):
-    """Shared build prefix of the SORT engine: the key pass, the ONE
-    stable sort by cell id (which returns the sorted keys with the
-    order) and per-element segment ranks.  Returns (n_cells, order,
-    skey, seg_start, rank) — everything both table builders derive slots
-    from."""
-    n_cells, key = _cell_keys(
-        pos, active, cell_size, width, cell=cell, n_cells=n_cells
-    )
-    return (n_cells,) + _key_segments(key, n_cells)
-
-
-# --- the COUNT engine (NF_BINNING=count): histogram + bounded-rank
-# selection + scatter.  No sort or argsort anywhere (pinned by the AST
-# guard in tests/test_binning.py) — the super-linear comparison network
-# is gone from the build.
-
-
-def _cell_counts(key: jnp.ndarray, n_cells: int) -> jnp.ndarray:
-    """Histogram pass: [n_cells + 1] i32 occupancy per cell (last bin
-    counts inactive rows, key == n_cells) via ONE segment_sum — a single
-    streaming scatter-add over [N].  In the fixed-stride dense layout the
-    exclusive-cumsum offsets this histogram implies are simply
-    `cell * bucket`, so no scan materializes on the hot path; the
-    histogram itself feeds occupancy telemetry and the per-pass profile
-    (scripts/profile_passes.py times it in isolation)."""
-    return jax.ops.segment_sum(
-        jnp.ones_like(key), key, num_segments=n_cells + 1
-    )
-
-
-def _counting_ranks(key: jnp.ndarray, n_cells: int, kmax: int) -> jnp.ndarray:
-    """Deterministic within-cell rank in stable row-id order, WITHOUT a
-    sort: `kmax` rounds of scatter-min selection.  Round r finds each
-    cell's smallest not-yet-ranked row id (one `.at[key].min` scatter +
-    one gather), assigns it rank r, and retires it.  Rows never selected
-    (rank >= kmax, or inactive key == n_cells) keep rank == kmax.
-
-    This matches the stable-argsort rank EXACTLY wherever it matters:
-    both engines place the `kmax` smallest row ids of each cell (stable
-    sort ranks ascending row ids ascending) and dump the rest, so tables
-    — including overflow drops — are bit-identical.  Cost is
-    O(kmax * (N + n_cells)) streaming work with static shapes; at the 1M
-    benchmark geometry that is ~16 passes over ~4 MB for the victim
-    table versus the ~400-stage comparison network XLA's sort runs over
-    8 MB of (key, row) pairs."""
-    n = key.shape[0]
-    sentinel = jnp.int32(n)  # > any live row id; also the "retired" mark
-    remaining = jnp.where(key < n_cells, jnp.arange(n, dtype=jnp.int32),
-                          sentinel)
-    rank = jnp.full((n,), kmax, jnp.int32)
-
-    def one_round(r, state):
-        remaining, rank = state
-        win = (
-            jnp.full((n_cells + 1,), sentinel, jnp.int32)
-            .at[key]
-            .min(remaining)
-        )
-        # the `< sentinel` guard keeps retired rows of an EXHAUSTED cell
-        # (win == sentinel) from matching sentinel == sentinel
-        is_win = (remaining < sentinel) & (remaining == win[key])
-        rank = jnp.where(is_win, r, rank)
-        remaining = jnp.where(is_win, sentinel, remaining)
-        return remaining, rank
-
-    _, rank = jax.lax.fori_loop(0, kmax, one_round, (remaining, rank))
-    return rank
-
-
-def _counting_slots(key: jnp.ndarray, n_cells: int, bucket: int) -> jnp.ndarray:
-    """Per-row flat payload slot from the counting ranks: placed rows get
-    `cell * bucket + rank` (the histogram's trivially-dense exclusive
-    offsets), everything else the dump slot.  Drop-in replacement for the
-    sort path's un-sorted `_finish_table` slot assignment."""
-    rank = _counting_ranks(key, n_cells, bucket)
-    dump = n_cells * bucket
-    return jnp.where(rank < bucket, key * bucket + rank, dump).astype(jnp.int32)
-
-
-def _build_pair_counting(
-    features, active, sub_mask, sub_features,
-    key, n_cells: int, cell_size: float, width: int,
-    bucket: int, sub_bucket: int, height: int = -1,
-) -> Tuple[CellTable, CellTable]:
-    """COUNT-engine pair build from a precomputed key: full and subset
-    tables each run their own bounded-rank selection + payload scatter.
-    The subset re-ranks over `sub_key` so a sub member's rank is its
-    ordinal among SUB members of its cell — same contract as the sort
-    path's segmented cumsum (a row overflowing the full table can still
-    hold a valid subset slot)."""
-    with jax.named_scope("nf.aoe.rank"):
-        slot_of = _counting_slots(key, n_cells, bucket)
-        sub_key = jnp.where(sub_mask, key, n_cells)
-        sub_slots = _counting_slots(sub_key, n_cells, sub_bucket)
-    with jax.named_scope("nf.aoe.table"):
-        full = table_from_slots(
-            features, active, slot_of, n_cells, cell_size, width, bucket,
-            height,
-        )
-        sub = table_from_slots(
-            sub_features, sub_mask, sub_slots, n_cells, cell_size, width,
-            sub_bucket, height,
-        )
-    return full, sub
+    return order, skey, idx - start_idx
 
 
 def _sorted_slots(n_cells: int, skey, rank, bucket: int) -> jnp.ndarray:
     """Flat payload slot of every SORTED element: `skey * bucket + rank`
     where the rank fits the cell, the dump slot otherwise (overflow,
-    inactive).  The one placement rule of the sort engine."""
+    inactive).  The one placement rule."""
     dump = n_cells * bucket
     placed = (rank < bucket) & (skey < n_cells)
     return jnp.where(placed, skey * bucket + rank, dump)
@@ -410,11 +170,10 @@ def _sorted_slots(n_cells: int, skey, rank, bucket: int) -> jnp.ndarray:
 def _slots_from_ranks(
     n: int, n_cells: int, order, skey, rank, bucket: int
 ) -> jnp.ndarray:
-    """SORT-engine slot assignment from sorted segment ranks: un-sort
+    """Per-row slot assignment from sorted segment ranks: un-sort
     `skey * bucket + rank` back to row order (one scatter).  Shared by
-    _finish_table, the Verlet rebuild (ops/verlet.py) and the slots-only
-    builders below so the placement math cannot drift between the
-    payload and fused engines."""
+    both builders and the Verlet rebuild (ops/verlet.py) so the placement
+    math cannot drift between them."""
     dump = n_cells * bucket
     flat_sorted = _sorted_slots(n_cells, skey, rank, bucket)
     return jnp.full((n,), dump, jnp.int32).at[order].set(flat_sorted)
@@ -469,77 +228,6 @@ def _chunked_payload(
     return payload.at[dump].set(0.0)
 
 
-def slots_from_assignment(
-    active, slot_of, n_cells: int,
-    cell_size: float, width: int, bucket: int, height: int = -1,
-) -> CellSlots:
-    """CellSlots from a precomputed per-row slot array: force inactive
-    rows to the dump slot and count active overflow — exactly the
-    bookkeeping half of table_from_slots, minus the payload scatter."""
-    dump = n_cells * bucket
-    slot_of = jnp.where(active, slot_of, dump)
-    dropped = jnp.sum(active & (slot_of == dump), dtype=jnp.int32)
-    return CellSlots(slot_of, dropped, width, cell_size, bucket, height)
-
-
-def build_cell_slots_pair(
-    pos: jnp.ndarray,
-    active: jnp.ndarray,
-    sub_mask: jnp.ndarray,
-    cell_size: float,
-    width: int,
-    bucket: int,
-    sub_bucket: int,
-    cell: jnp.ndarray | None = None,
-    height: int = -1,
-) -> Tuple[CellSlots, CellSlots]:
-    """build_cell_table_pair minus the payloads: the same NF_BINNING
-    dispatch, key pass, ranks and dump-slot rules, returning only the
-    two slot assignments (full population + subset).  Placement is
-    bit-identical to the table pair — including which rows drop — so
-    the fused engine inherits the split engine's overflow semantics."""
-    n_rows = height if height > 0 else width
-    n = pos.shape[0]
-    mode = binning_mode()
-    if mode == "count":
-        n_cells, key = _cell_keys(
-            pos, active, cell_size, width, cell=cell,
-            n_cells=(n_rows * width if cell is not None else None),
-        )
-        full = slots_from_assignment(
-            active, _counting_slots(key, n_cells, bucket), n_cells,
-            cell_size, width, bucket, height,
-        )
-        sub_key = jnp.where(sub_mask, key, n_cells)
-        sub = slots_from_assignment(
-            sub_mask, _counting_slots(sub_key, n_cells, sub_bucket), n_cells,
-            cell_size, width, sub_bucket, height,
-        )
-        return full, sub
-    if mode != "sort":
-        raise ValueError(f"unhandled binning mode {mode!r}")  # pragma: no cover
-    n_cells, order, skey, seg_start, rank = _sorted_segments(
-        pos, active, cell_size, width, cell=cell,
-        n_cells=(n_rows * width if cell is not None else None),
-    )
-    full = slots_from_assignment(
-        active, _slots_from_ranks(n, n_cells, order, skey, rank, bucket),
-        n_cells, cell_size, width, bucket, height,
-    )
-    # subset ranks via the same segmented exclusive cumsum as the pair
-    # builder (see build_cell_table_pair for the derivation)
-    sub_sorted = sub_mask[order]
-    ex = jnp.cumsum(sub_sorted.astype(jnp.int32)) - sub_sorted.astype(jnp.int32)
-    head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
-    sub_rank = jnp.where(sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
-    sub = slots_from_assignment(
-        sub_mask,
-        _slots_from_ranks(n, n_cells, order, skey, sub_rank, sub_bucket),
-        n_cells, cell_size, width, sub_bucket, height,
-    )
-    return full, sub
-
-
 def table_from_slots(
     features, active, slot_of, n_cells: int,
     cell_size: float, width: int, bucket: int, height: int = -1,
@@ -568,21 +256,6 @@ def table_from_slots(
     return CellTable(payload, slot_of, dropped, width, cell_size, bucket, height)
 
 
-def _finish_table(
-    features, active, n_cells: int, order, skey, rank,
-    cell_size: float, width: int, bucket: int, height: int = -1,
-) -> CellTable:
-    """Shared build suffix: slots from ranks, then the payload scatter.
-    Un-sorting the slot assignment costs one scatter instead of a
-    sorted-gather + scatter (each N-sized irregular op costs ~1 ms per
-    131k rows on a v5e; this is the hot per-tick build)."""
-    n = features.shape[0]
-    slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
-    return table_from_slots(
-        features, active, slot_of, n_cells, cell_size, width, bucket, height
-    )
-
-
 def build_cell_table(
     pos: jnp.ndarray,
     active: jnp.ndarray,
@@ -594,27 +267,18 @@ def build_cell_table(
     """Bin `active` entities into the uniform grid, carrying `features`.
 
     pos: [N, >=2] positions; active: [N] bool; features: [N, F] float32.
-    Slot assignment dispatches on NF_BINNING (bit-identical either way):
-    sort = one argsort + permutation-gather + scatter; count = bounded
-    scatter-min ranks, no sort.  All slot indices are unique so the
+    One sort, one un-sort scatter of the slots (a sorted-gather + scatter
+    would be two N-sized irregular ops, ~1 ms per 131k rows each on a
+    v5e) and one payload scatter.  All slot indices are unique so the
     payload scatter is deterministic.
     """
-    mode = binning_mode()
-    if mode == "count":
-        n_cells, key = _cell_keys(pos, active, cell_size, width)
-        slot_of = _counting_slots(key, n_cells, bucket)
-        return table_from_slots(
-            features, active, slot_of, n_cells, cell_size, width, bucket
-        )
-    if mode == "sort":
-        n_cells, order, skey, _seg_start, rank = _sorted_segments(
-            pos, active, cell_size, width
-        )
-        return _finish_table(
-            features, active, n_cells, order, skey, rank, cell_size, width,
-            bucket,
-        )
-    raise ValueError(f"unhandled binning mode {mode!r}")  # pragma: no cover
+    n_cells, key = _cell_keys(pos, active, cell_size, width)
+    order, skey, rank = _key_segments(key)
+    slot_of = _slots_from_ranks(
+        features.shape[0], n_cells, order, skey, rank, bucket)
+    return table_from_slots(
+        features, active, slot_of, n_cells, cell_size, width, bucket
+    )
 
 
 def build_cell_table_pair(
@@ -631,13 +295,9 @@ def build_cell_table_pair(
     height: int = -1,
     sub_rows: int | None = None,
 ) -> Tuple[CellTable, CellTable]:
-    """Build the full table AND a subset table from ONE key pass.
-
-    Dispatches on NF_BINNING: the sort engine sorts twice (the whole
-    population, then the subset's keys) and ranks both from the sorted
-    keys; the count engine runs bounded scatter-min selection per table
-    (no sort at all).  Both produce bit-identical tables — including
-    which rows overflow to the dump slot.
+    """Build the full table AND a subset table from ONE key pass: two
+    sorts (the whole population, then the subset's keys), both tables
+    ranked from the sorted keys.
 
     `sub_mask` must be a subset of `active` (combat: attackers among all
     alive entities).  Placement is bit-identical to two independent
@@ -665,33 +325,19 @@ def build_cell_table_pair(
     n_rows = height if height > 0 else width
     n = pos.shape[0]
     sub_rows = n if sub_rows is None else max(1, min(sub_rows, n))
-    mode = binning_mode()
-    if mode == "count":
-        with jax.named_scope("nf.aoe.rank"):
-            n_cells, key = _cell_keys(
-                pos, active, cell_size, width, cell=cell,
-                n_cells=(n_rows * width if cell is not None else None),
-            )
-        return _build_pair_counting(
-            features, active, sub_mask, sub_features, key, n_cells,
-            cell_size, width, bucket, sub_bucket, height,
-        )
-    if mode != "sort":
-        raise ValueError(f"unhandled binning mode {mode!r}")  # pragma: no cover
     with jax.named_scope("nf.aoe.rank"):
         n_cells, key = _cell_keys(
             pos, active, cell_size, width, cell=cell,
             n_cells=(n_rows * width if cell is not None else None),
         )
-        order, skey, _seg_start, rank = _key_segments(key, n_cells)
+        order, skey, rank = _key_segments(key)
         slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
         # the subset, compacted by a second sort: members first, in cell
         # order, rows ascending inside a cell — the order their ranks
         # count in.  Heads, ranks and slots are streaming passes over
         # that list; nothing here gathers or scatters by row.
         sub_key = jnp.where(sub_mask, key, n_cells)
-        sub_order, sub_skey, _sub_start, sub_rank = _key_segments(
-            sub_key, n_cells)
+        sub_order, sub_skey, sub_rank = _key_segments(sub_key)
         sub_sorted_slots = _sorted_slots(
             n_cells, sub_skey, sub_rank, sub_bucket)
         sub_dump = n_cells * sub_bucket
@@ -751,8 +397,8 @@ def pull_slots(
 ) -> jnp.ndarray:
     """Map per-slot results [H, W, K] or [H, W, K, V] back to rows [N] /
     [N, V] with one gather through a raw slot array; unplaced rows (dump
-    slot) read `fill`.  The slot-only half of `pull` — the fused engine
-    (CellSlots) has no table to pass."""
+    slot) read `fill`.  The slot-only half of `pull` (the combat phase
+    pulls two values through one victim slot array)."""
     squeeze = values.ndim == 3
     if squeeze:
         values = values[..., None]

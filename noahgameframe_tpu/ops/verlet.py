@@ -55,10 +55,8 @@ import jax.numpy as jnp
 from .stencil import (
     CellTable,
     _cell_keys,
-    _counting_slots,
+    _key_segments,
     _slots_from_ranks,
-    _sorted_segments,
-    binning_mode,
     table_from_slots,
 )
 
@@ -71,21 +69,11 @@ class VerletCache(NamedTuple):
 
     anchor_pos:    [N, 2] f32 — positions at the last rebuild.
     anchor_active: [N] bool   — active mask at the last rebuild.
-    order:         [N] i32    — the stable sort by anchor cell id
-                                (NF_BINNING=sort engine; the count engine
-                                has no sorted order and stores arange —
-                                carried but unused).
-    skey:          [N] i32    — engine-dependent: the SORTED cell keys
-                                under the sort engine, the PER-ROW anchor
-                                cell keys under the count engine (both
-                                use n_cells for inactive).  Either way it
-                                is exactly what sub_table() needs to
-                                re-rank a fresh subset on a reuse tick,
-                                and it is meaningless across engines — a
-                                cache built under one NF_BINNING value
-                                must be dropped before running the other
-                                (SpatialWorld.load() enforces this for
-                                snapshots).
+    order:         [N] i32    — the stable sort by anchor cell id.
+    skey:          [N] i32    — the SORTED anchor cell keys (n_cells for
+                                inactive): with `order`, what sub_table()
+                                needs to re-rank a fresh subset on a
+                                reuse tick.
     slot_of:       [N] i32    — full-table slot per row for the bucket the
                                 cache was built with (geometry-baked: any
                                 bucket/width change must drop the cache).
@@ -186,7 +174,7 @@ def refresh(
 ) -> Tuple[VerletCache, jnp.ndarray]:
     """The lax.cond-gated build step: returns (valid cache, rebuilt i32).
 
-    Rebuild branch = the full _sorted_segments argsort + slot assignment
+    Rebuild branch = the key pass, the sort and the slot assignment
     (everything build_cell_table derives before the payload scatter),
     re-anchored at today's positions.  Reuse branch = the cached arrays
     untouched, age bumped.  Either way the returned cache is valid for
@@ -204,24 +192,13 @@ def refresh(
         n_cells = width * width
     trig = need_rebuild(cache, pos, active, skin, axis_name=axis_name)
     n = pos.shape[0]
-    mode = binning_mode()  # trace-time, like the NF_RADIX read below it
 
     def rebuild(_):
-        if mode == "count":
-            # sort-free anchor: bounded scatter-min slots; `skey` caches
-            # the PER-ROW anchor keys (what sub_table re-ranks against),
-            # `order` degenerates to identity (see VerletCache docstring)
-            _nc, key = _cell_keys(
-                pos, active, cell_size, width, cell=cell, n_cells=n_cells
-            )
-            order = jnp.arange(n, dtype=jnp.int32)
-            skey = key
-            slot_of = _counting_slots(key, n_cells, bucket)
-        else:
-            _nc, order, skey, _seg_start, rank = _sorted_segments(
-                pos, active, cell_size, width, cell=cell, n_cells=n_cells
-            )
-            slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
+        _nc, key = _cell_keys(
+            pos, active, cell_size, width, cell=cell, n_cells=n_cells
+        )
+        order, skey, rank = _key_segments(key)
+        slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
         return VerletCache(
             anchor_pos=pos[:, :2].astype(jnp.float32),
             anchor_active=active,
@@ -259,33 +236,6 @@ def full_table(
     )
 
 
-def sub_slots(
-    cache: VerletCache,
-    sub_mask: jnp.ndarray,
-    n_cells: int,
-    sub_bucket: int,
-) -> jnp.ndarray:
-    """The raw subset slot assignment through the cached order — the
-    sort-free core of sub_table, shared with the fused Pallas engine
-    (which gathers from the SoA banks instead of scattering a payload).
-    Returns [N] i32 flat slots (dump == n_cells*sub_bucket for
-    non-members); callers wanting drop counts wrap it in
-    stencil.slots_from_assignment."""
-    if binning_mode() == "count":
-        sub_key = jnp.where(sub_mask, cache.skey, n_cells)
-        return _counting_slots(sub_key, n_cells, sub_bucket)
-    order, skey = cache.order, cache.skey
-    n = order.shape[0]
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
-    )
-    sub_sorted = sub_mask[order]
-    ex = jnp.cumsum(sub_sorted.astype(jnp.int32)) - sub_sorted.astype(jnp.int32)
-    head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
-    sub_rank = jnp.where(sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
-    return _slots_from_ranks(n, n_cells, order, skey, sub_rank, sub_bucket)
-
-
 def sub_table(
     cache: VerletCache,
     sub_mask: jnp.ndarray,
@@ -298,13 +248,20 @@ def sub_table(
 ) -> CellTable:
     """A subset table (this tick's attackers / moved entities) through the
     cached order: the subset CHANGES every tick, so its per-cell ranks are
-    recomputed — but via the same segmented exclusive cumsum
-    build_cell_table_pair uses, a streaming pass over the cached sorted
-    order instead of a second argsort.  Under NF_BINNING=count the cached
-    `skey` holds per-row anchor keys instead, and the subset re-runs the
-    bounded scatter-min selection over them.  Bit-identical to the pair
-    builder's sub table for any sub_mask subset of the anchor active set."""
+    recomputed, by a segmented exclusive cumsum over the cached sorted
+    order instead of a second sort.  Bit-identical to the pair builder's
+    sub table for any sub_mask subset of the anchor active set."""
+    order, skey = cache.order, cache.skey
+    n = order.shape[0]
+    seg_start = jnp.concatenate(
+        [jnp.ones((1,), bool), skey[1:] != skey[:-1]]
+    )
+    sub_sorted = sub_mask[order]
+    ex = jnp.cumsum(sub_sorted.astype(jnp.int32)) - sub_sorted.astype(jnp.int32)
+    head_ex = jax.lax.cummax(jnp.where(seg_start, ex, -1))
+    sub_rank = jnp.where(sub_sorted, ex - head_ex, n_cells * sub_bucket + 1)
     return table_from_slots(
-        sub_features, sub_mask, sub_slots(cache, sub_mask, n_cells, sub_bucket),
+        sub_features, sub_mask,
+        _slots_from_ranks(n, n_cells, order, skey, sub_rank, sub_bucket),
         n_cells, cell_size, width, sub_bucket, height,
     )

@@ -54,7 +54,7 @@ from ..core.store import StoreConfig, with_class
 from ..game.combat import combat_fold_closure
 from ..kernel.kernel import Kernel
 from ..kernel.module import Module
-from ..ops.stencil import binning_mode, build_cell_table_pair, pull
+from ..ops.stencil import build_cell_table_pair, pull
 from ..ops.verlet import VerletCache, full_table, refresh, sub_table
 from .mesh import SHARD_AXIS, make_mesh
 from .rowmigrate import (
@@ -646,7 +646,7 @@ class SpatialWorld:
         st = jax.tree.map(np.asarray, self.state)
         np.savez_compressed(
             path, tick=self.tick_count, bank=self.bank_size,
-            binning=binning_mode(), layout="classrow", **st._asdict(),
+            layout="classrow", **st._asdict(),
         )
 
     def load(self, path: str) -> None:
@@ -665,17 +665,19 @@ class SpatialWorld:
                 "vc_slot": np.zeros((cap,), np.int32),
                 "cstat": np.zeros((self.geom.n_shards, 3), np.int32),
             }
-            # vc_order/vc_skey are NF_BINNING-engine-specific (sorted
-            # keys vs per-row anchor keys — VerletCache docstring), and a
-            # pre-unification slab snapshot (no `layout` key) recorded
-            # binning but not the full-row layout this engine carries: in
-            # either mismatch the cache is dropped (all-False anchors =>
-            # first tick rebuilds; trajectories are unchanged) and only
-            # the row banks load.  Geometry is re-derived from this
-            # world's SpatialGeom + the stored bank size.
+            # vc_order/vc_skey are the sorted order and sorted keys
+            # (VerletCache docstring).  An older file may say it was
+            # written by another build (`binning` present and not
+            # "sort": per-row keys in vc_skey), and a pre-unification
+            # slab snapshot (no `layout` key) does not carry the full-row
+            # layout this engine does: in either case the cache is
+            # dropped (all-False anchors => first tick rebuilds;
+            # trajectories are unchanged) and only the row banks load.
+            # Geometry is re-derived from this world's SpatialGeom + the
+            # stored bank size.
             stored = str(z["binning"]) if "binning" in z.files else "sort"
             layout = str(z["layout"]) if "layout" in z.files else "slab"
-            drop_cache = stored != binning_mode() or layout != "classrow"
+            drop_cache = stored != "sort" or layout != "classrow"
 
             def pick(f):
                 if f in z.files and not (drop_cache and f.startswith("vc_")):

@@ -141,19 +141,6 @@ class TelemetryModule(Module):
                 "limit_bytes"), kind="gauge",
             help="device allocator capacity (0 when unknown)",
         )
-        self.registry.register_callback(
-            "nf_pallas_fallback_total", self._pallas_fallback_samples,
-            kind="counter",
-            help="NF_PALLAS=2 fused-engine downgrades to the split-table "
-                 "path (VMEM budget), counted per retrace",
-        )
-
-    def _pallas_fallback_samples(self) -> Iterable[Tuple[dict, float]]:
-        # lazy import: the scrape must not drag the Pallas module (and
-        # through it jax.experimental) into processes that never combat
-        from ..ops.stencil_pallas import fused_fallback_total
-
-        yield ({}, float(fused_fallback_total()))
 
     # ------------------------------------------------------------ sources
     def _hbm_samples_live(self) -> Iterable[Tuple[dict, float]]:

@@ -57,8 +57,8 @@ def main() -> None:
     k = world.kernel
     state = k.state
     # every timed prefix is a CostBook entry: phase attribution, compile
-    # wall and compiled FLOPs/bytes share one ledger with profile_passes
-    # and bench.py instead of re-deriving the phase list
+    # wall and compiled FLOPs/bytes share one ledger with bench.py
+    # instead of re-deriving the phase list
     book = k.costbook
 
     def prefix_fn(n_phases: int):

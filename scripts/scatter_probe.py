@@ -83,7 +83,7 @@ def main() -> None:
     @jax.jit
     def assign(pos, active):
         n_cells, key = _cell_keys(pos, active, CELL, WIDTH)
-        order, skey, _s, rank = _key_segments(key, n_cells)
+        order, skey, rank = _key_segments(key)
         sorted_slots = _sorted_slots(n_cells, skey, rank, VIC_BUCKET)
         slot_of = _slots_from_ranks(N, n_cells, order, skey, rank, VIC_BUCKET)
         return order, sorted_slots, slot_of

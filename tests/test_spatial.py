@@ -393,53 +393,33 @@ def test_spatial_auto_resize_disabled_keeps_geometry():
     assert world.stats_last[:, 4:].sum() > 0  # drops persist, by choice
 
 
-def test_spatial_binning_count_bit_parity(monkeypatch):
-    """The slab shards' per-shard table build through NF_BINNING=count:
-    same positions and HP as the sort engine, tick for tick."""
-    geom, pos, hp, atk, camp = _mk_world(n=600, seed=8, n_shards=2,
-                                         mig_budget=256)
-    ticks = 12
-    results = {}
-    for mode in ("sort", "count"):
-        if mode == "sort":
-            monkeypatch.delenv("NF_BINNING", raising=False)
-        else:
-            monkeypatch.setenv("NF_BINNING", mode)
-        world = SpatialWorld(geom)
-        world.place(pos, hp, atk, camp)
-        world.step(ticks)
-        results[mode] = world.gather()
-    assert results["sort"].keys() == results["count"].keys()
-    for g, (x, y, hp_) in results["sort"].items():
-        cx, cy, chp = results["count"][g]
-        assert hp_ == chp, f"gid {g} hp"
-        np.testing.assert_array_equal(np.float32([x, y]),
-                                      np.float32([cx, cy]))
-
-
-def test_spatial_snapshot_cross_engine_drops_verlet_cache(
-        tmp_path, monkeypatch):
-    """A snapshot saved under one NF_BINNING engine loads under the other
-    with its Verlet-cache leaves zeroed (the cached order/skey/slot are
-    engine-specific), forcing a first-tick rebuild — and the resumed
-    trajectory stays bit-identical to an unbroken run."""
+def test_spatial_snapshot_cross_engine_drops_verlet_cache(tmp_path):
+    """A snapshot that says another build wrote it (`binning` present
+    and not "sort": a file from before that build was deleted, whose
+    vc_skey holds per-row keys) loads with its Verlet-cache leaves
+    zeroed, forcing a first-tick rebuild — and the resumed trajectory
+    stays bit-identical to an unbroken run."""
     geom, pos, hp, atk, camp = _mk_world(n=400, seed=12, n_shards=2,
                                          cell_size=8.0, width=16,
                                          radius=4.0, speed=0.1, skin=4.0)
-    monkeypatch.delenv("NF_BINNING", raising=False)
     world = SpatialWorld(geom)
     world.place(pos, hp, atk, camp)
     world.step(6)
     p = str(tmp_path / "snap.npz")
     world.save(p)
-    # unbroken oracle, still under sort
+    # unbroken oracle
     world.step(6)
     ref = world.gather()
 
-    monkeypatch.setenv("NF_BINNING", "count")
+    with np.load(p) as z:
+        assert "binning" not in z.files  # one build: nothing to record
+        foreign = {f: z[f] for f in z.files}
+    foreign["binning"] = "count"
+    p_count = str(tmp_path / "snap_count.npz")
+    np.savez_compressed(p_count, **foreign)
     w2 = SpatialWorld(geom)
-    w2.load(p)
-    # cross-engine load: the anchor must be fully invalidated
+    w2.load(p_count)
+    # the anchor must be fully invalidated
     assert not np.asarray(w2.state.vc_active).any()
     w2.step(6)
     got = w2.gather()
@@ -450,8 +430,12 @@ def test_spatial_snapshot_cross_engine_drops_verlet_cache(
         np.testing.assert_array_equal(np.float32([x, y]),
                                       np.float32([cx, cy]))
 
-    # same-engine load keeps the cache (the cheap path stays cheap)
-    monkeypatch.delenv("NF_BINNING", raising=False)
-    w3 = SpatialWorld(geom)
-    w3.load(p)
-    assert np.asarray(w3.state.vc_active).any()
+    # this build's own file (and an older one that says "sort") keeps
+    # the cache: the cheap path stays cheap
+    foreign["binning"] = "sort"
+    p_sort = str(tmp_path / "snap_sort.npz")
+    np.savez_compressed(p_sort, **foreign)
+    for path in (p, p_sort):
+        w3 = SpatialWorld(geom)
+        w3.load(path)
+        assert np.asarray(w3.state.vc_active).any()

@@ -69,6 +69,37 @@ def test_auto_bucket_keeps_overflow_tiny_at_benchmark_density():
     assert int(t.dropped) <= n // 1000
 
 
+@pytest.mark.parametrize("config,cap,want", [
+    ("npc-1m", 1 << 20, (395, 16, 6, 32, 12, 69_912)),
+    ("npc-100k", 1 << 17, (125, 20, 6, 40, 12, 8_744)),
+    ("clone-rooms-5k", 128, (4, 20, 6, 40, 12, 16)),
+])
+def test_shipped_geometry_of_the_benchmarked_worlds(config, cap, want):
+    """(grid width, victim / attacker depth, the depths after one boost,
+    sorted attacker rows sent a trip) of the three benchmarked
+    configurations at their capacities, from the module that sizes them
+    in a world the benchmark's builder made (a few rows at the real
+    extent: the geometry follows from extent, capacity and arming, not
+    from the rows).  Every reading in PERF.md section 5 leans on these."""
+    import json
+    from pathlib import Path
+
+    from noahgameframe_tpu.game import build_benchmark_world
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                      / "configs" / f"{config}.json").read_text())
+    world = cfg["world"]
+    extent = cfg.get("rooms", {}).get("extent") or max(
+        64.0, float(np.sqrt(world["entities"] / world["density_per_unit2"])))
+    c = build_benchmark_world(
+        64, extent=extent, attack_period_s=world["attack_period_s"]).combat
+    got = (c.width, c.resolved_bucket(cap), c.resolved_att_bucket(cap))
+    c._bucket_boost = 2
+    got += (c.resolved_bucket(cap), c.resolved_att_bucket(cap),
+            c.resolved_att_rows(cap))
+    assert got == want
+
+
 def test_pair_build_matches_independent_builds():
     """build_cell_table_pair must place both tables bit-identically to
     two independent build_cell_table calls (same slots, same payloads,
@@ -361,55 +392,6 @@ def test_combat_scene_scoped_at_large_scene_ids():
     assert k.get_property(b, "HP") == 50
 
 
-def test_radix_argsort_matches_stable_argsort():
-    """NF_RADIX=1 swaps the cell-table's argsort for an LSD binary radix
-    sort (docs/ROOFLINE.md) — placement must be BIT-identical."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from noahgameframe_tpu.ops.stencil import _bits_for, _radix_argsort
-
-    rng = np.random.default_rng(11)
-    for n, hi in ((1, 2), (257, 9), (4096, 1024), (10_000, 156_026)):
-        key = jnp.asarray(rng.integers(0, hi, n).astype(np.int32))
-        want = np.asarray(jnp.argsort(key))
-        for bits_per_pass in (1, 2, 3):
-            got = np.asarray(
-                _radix_argsort(key, _bits_for(hi - 1), bits_per_pass)
-            )
-            np.testing.assert_array_equal(
-                got, want, err_msg=f"n={n} hi={hi} b={bits_per_pass}"
-            )
-
-
-def test_cell_table_radix_parity(monkeypatch):
-    """The whole table build under NF_RADIX=1 equals the default path."""
-    import os
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from noahgameframe_tpu.ops.stencil import build_cell_table
-
-    rng = np.random.default_rng(5)
-    n, extent, cell, width, bucket = 2000, 64.0, 4.0, 16, 16
-    pos = jnp.asarray(rng.uniform(0, extent, (n, 2)).astype(np.float32))
-    active = jnp.asarray(rng.random(n) < 0.8)
-    feats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32))
-
-    t0 = build_cell_table(pos, active, feats, cell, width, bucket)
-    for bits in ("1", "2", "3"):
-        monkeypatch.setenv("NF_RADIX", bits)
-        t1 = build_cell_table(pos, active, feats, cell, width, bucket)
-        np.testing.assert_array_equal(
-            np.asarray(t0.slot_of), np.asarray(t1.slot_of)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(t0.payload), np.asarray(t1.payload)
-        )
-        assert int(t0.dropped) == int(t1.dropped)
-
-
 # ---------------------------------------------- the chunked attacker side
 #
 # build_cell_table_pair compacts the subset by a second sort and gathers
@@ -498,7 +480,12 @@ def test_chunked_pair_build_matches_independent_builds(case):
 def test_chunked_pair_build_rectangular_grid():
     """The `cell` / `height` form (spatial slabs): a 3-row, 8-wide grid
     of precomputed cell ids, several trips."""
-    from noahgameframe_tpu.ops.stencil import _finish_table, _sorted_segments
+    from noahgameframe_tpu.ops.stencil import (
+        _cell_keys,
+        _key_segments,
+        _slots_from_ranks,
+        table_from_slots,
+    )
 
     pos, active, feats, sub, sub_feats = map(
         jnp.asarray, _pair_world(400, 5, p_sub=0.4))
@@ -512,10 +499,12 @@ def test_chunked_pair_build_rectangular_grid():
     assert vt.height == at.height == height
     assert at.payload.shape == (width * height * ka + 1, 3)
     for got, mask, f, k in ((vt, active, feats, kv), (at, sub, sub_feats, ka)):
-        n_cells, order, skey, _s, rank = _sorted_segments(
+        n_cells, key = _cell_keys(
             pos, mask, 5.0, width, cell=cell, n_cells=width * height)
-        _assert_tables_equal(got, _finish_table(
-            f, mask, n_cells, order, skey, rank, 5.0, width, k, height))
+        order, skey, rank = _key_segments(key)
+        slot_of = _slots_from_ranks(400, n_cells, order, skey, rank, k)
+        _assert_tables_equal(got, table_from_slots(
+            f, mask, slot_of, n_cells, 5.0, width, k, height))
 
 
 def test_chunked_pair_build_under_vmap_runs_to_the_busiest_room():
@@ -580,11 +569,14 @@ def test_chunked_pair_build_inside_scan():
 PARENT_DIGESTS_2K = {1: 0x90A5E9F3, 20: 0x65B6B10E, 40: 0xE2EEBE7F}
 
 
-def _digests_2k(ticks=40):
+def _digests_2k(ticks=40, whole_bank_chunk=False):
     from noahgameframe_tpu.game import build_benchmark_world
 
     w = build_benchmark_world(2000, seed=27)
     k = w.kernel
+    if whole_bank_chunk:
+        w.combat.resolved_att_rows = lambda capacity: capacity
+        k.invalidate()
     k.enable_digest()
     out = {}
     for t in range(1, ticks + 1):
@@ -593,18 +585,21 @@ def _digests_2k(ticks=40):
     return w, out
 
 
-def test_benchmark_world_digest_equals_the_parents(monkeypatch):
+def test_benchmark_world_digest_equals_the_parents():
     """40 observed ticks of the benchmark world end in the state the
     parent's table build gave, digest for digest.  The pinned values are
-    CPU readings; the count engine (code this PR does not touch, the
-    same tables by contract) says whether this machine rounds as the
-    one they were read on did, and holds the sort engine either way."""
+    CPU readings; the same world with the attacker side sent as one
+    whole-bank chunk (the same tables by contract) says whether this
+    machine rounds as the one they were read on did, and holds the
+    duty-sized chunk either way."""
     w, got = _digests_2k()
     totals = w.kernel.counter_totals
     assert totals["aoi_victim_overflow_drops"] == 1
     assert totals["aoi_attacker_overflow_drops"] == 0
-    monkeypatch.setenv("NF_BINNING", "count")
-    _w, control = _digests_2k()
+    w1, control = _digests_2k(whole_bank_chunk=True)
+    cap = w1.kernel.store.capacity("NPC")
+    assert w1.kernel.last_counters["aoe_attacker_rows_sent"] == cap
+    assert w.kernel.last_counters["aoe_attacker_rows_sent"] < cap
     assert got == control
     pinned = {t: control[t] for t in PARENT_DIGESTS_2K}
     if pinned != PARENT_DIGESTS_2K:

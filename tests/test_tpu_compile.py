@@ -3,12 +3,10 @@
 The TPU's compiler is installed here and compiles for a chip that is
 described (`v5e:2x2`) and not attached, so these cases guard every later
 PR at no chip time: the Pallas fold at the real 100k and 1M geometries,
-the fused neighbourhood kernel's refusal (strict xfail: the day Mosaic
-lowers its gather, this file says so), the program that seeds a 1M
-world, the default tick, one sharded tick over the described 2x2
-mesh, and the clone-scene fleet's `rooms.step` at its benchmarked size.
-A compile that passes is not a chip
-run: nothing executes, so no result or time is checked here.
+the program that seeds a 1M world, the default tick, one sharded tick
+over the described 2x2 mesh, and the clone-scene fleet's `rooms.step` at
+its benchmarked size.  A compile that passes is not a chip run: nothing
+executes, so no result or time is checked here.
 
 This is the only file that describes a topology.  The description
 happens inside the module-scoped `topo` fixture, never at import (only
@@ -26,7 +24,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from noahgameframe_tpu.game import build_benchmark_world
 from noahgameframe_tpu.game.combat import CombatModule
 from noahgameframe_tpu.ops import stencil_pallas as sp
-from noahgameframe_tpu.ops.stencil import CellSlots, CellTable
+from noahgameframe_tpu.ops.stencil import CellTable
 
 
 @pytest.fixture(scope="module")
@@ -115,32 +113,6 @@ def test_combat_fold_pallas_compiles_natively(one_chip, n, want):
         arg((cap,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="Mosaic: 'Only 2D gather is supported' — the fused kernel "
-           "gathers [K, W] rows out of a 1-D bank; restated as a 2-D "
-           "take_along_axis it is refused too ('Multiple source vregs "
-           "along gather dimension'), so engine 2 has never run natively")
-def test_fused_neighborhood_compiles_natively(one_chip):
-    cap, width, cell, kv, ka = _geometry(100_000)
-    fits, _need, _budget = sp.fused_fits_vmem(cap, width, kv, ka)
-    assert fits, "the refusal is the compiler's, not the VMEM gate's"
-
-    def fused(bank, vso, aso):
-        return sp.fused_neighborhood(
-            bank, CellSlots(vso, jnp.int32(0), width, cell, kv),
-            CellSlots(aso, jnp.int32(0), width, cell, ka),
-            4.0, interpret=False)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    jax.jit(fused).lower(
-        arg((cap, sp.N_BFEATS), jnp.float32), arg((cap,), jnp.int32),
-        arg((cap,), jnp.int32),
-    ).compile()
 
 
 def test_world_seeding_fits_one_chip_at_1m(one_chip):

@@ -275,6 +275,7 @@ def test_slab_snapshot_loads_into_unified_engine(tmp_path):
     # columns, binning marker, but no `layout` key
     with np.load(p_new) as z:
         legacy = {f: z[f] for f in z.files if f != "layout"}
+    legacy["binning"] = "sort"
     p_old = tmp_path / "slab.npz"
     np.savez_compressed(p_old, **legacy)
 
