@@ -38,6 +38,13 @@ def _emit(payload: dict) -> None:
     print(json.dumps(payload), flush=True)
 
 
+def _pin_fold(world, args) -> None:
+    """--pallas: pin the fold's engine on a freshly built world, before
+    its first trace (the choice is baked into the compiled tick)."""
+    if args.pallas is not None and getattr(world, "combat", None):
+        world.combat.use_pallas = args.pallas
+
+
 def _jax_on(platform: str, n_devices=None):
     """jax on the platform a mode was asked for: "tpu" is a TPU or an
     error (never a fallback), "cpu" is an explicit rehearsal, with
@@ -161,7 +168,7 @@ def _combat_cost_probe(world) -> dict:
         cell_size, width = combat.cell_size, combat.width
         bucket = combat.resolved_bucket(cap)
         att_bucket = combat.resolved_att_bucket(cap)
-        engine = (combat.resolved_engine() if combat.engine_baked is None
+        engine = (combat.resolved_engine(cap) if combat.engine_baked is None
                   else combat.engine_baked)
 
         f32 = jnp.float32
@@ -316,6 +323,7 @@ def run_served(args) -> dict:
         seed=args.seed,
         player_capacity=next_pow2(args.sessions + 8, lo=64),
     )
+    _pin_fold(world, args)
     role = GameRole(
         RoleConfig(6, 0, "BenchGame", "127.0.0.1", 0),
         backend="py",
@@ -425,6 +433,7 @@ def run_sharded(args) -> dict:
     n = args.entities
     world = build_benchmark_world(n, combat=not args.no_combat,
                                   seed=args.seed)
+    _pin_fold(world, args)
     sk = ShardedKernel(world.kernel, n_devices=args.sharded)
     sk.place()
     k = world.kernel
@@ -760,6 +769,7 @@ def run_bench(args) -> dict:
     n = args.entities
     world = build_benchmark_world(n, combat=not args.no_combat,
                                   seed=args.seed)
+    _pin_fold(world, args)
     k = world.kernel
 
     train_k = int(getattr(args, "train", 0) or 0)
@@ -915,8 +925,8 @@ def run_bench(args) -> dict:
             # elected skin, whether or not Verlet caches engaged — a run
             # is only reproducible with the same (seed, skin) pair
             "verlet_skin": skin_from_env(),
-            # which combat fold engine ran (0 XLA / 1 Pallas) — the
-            # label the NF_PALLAS A/B joins on
+            # which combat fold engine the tick baked in (0 XLA /
+            # 1 Pallas)
             **({"pallas_engine": pallas_probe.get("engine"),
                 "pallas_probe": pallas_probe} if pallas_probe else {}),
             **({"verlet": verlet} if verlet else {}),
@@ -1006,83 +1016,6 @@ def _run_session_sweep(args) -> dict:
     }
 
 
-def _run_pallas_ab(args) -> dict:
-    """--sweep-ab without --sweep-sessions: waterfall the two combat
-    fold engines (NF_PALLAS 0 XLA / 1 Pallas) in one invocation.  Each
-    engine runs in a SUBPROCESS with an explicit ``--pallas`` pin — the
-    knob is read at trace time, so respawning is the only way to get
-    honest traces — and a crash or OOM in one engine can't burn the
-    other's point.  Each point keeps its ``combat.fold_p*`` costbook
-    entry.  With ``--train K`` a third arm rides along: the default
-    engine re-run under K-tick observed trains (r13)."""
-    def one(engine: int, train: int = 0) -> dict:
-        cmd = [
-            sys.executable, "-u", __file__,
-            "--entities", str(args.entities), "--ticks", str(args.ticks),
-            "--seed", str(args.seed), "--platform", args.platform,
-            "--pallas", str(engine),
-        ]
-        if train > 1:
-            cmd += ["--train", str(train)]
-        if args.no_combat:
-            cmd.append("--no-combat")
-        point = {"pallas": engine, "tick_train": train}
-        try:
-            r = subprocess.run(
-                cmd, capture_output=True, text=True,
-                timeout=args.sweep_timeout,
-            )
-        except subprocess.TimeoutExpired:
-            point["error"] = f"timeout after {args.sweep_timeout:.0f}s"
-            return point
-        for ln in reversed((r.stdout or "").strip().splitlines()):
-            if ln.startswith("{"):
-                try:
-                    p = json.loads(ln)
-                except json.JSONDecodeError:
-                    break
-                if p.get("error"):
-                    point["error"] = p["error"]
-                point["value"] = p.get("value")
-                d = p.get("detail") or {}
-                for key in ("tick_ms", "tick_ms_p50_device", "platform",
-                            "pallas_engine", "pallas_probe",
-                            "tick_train", "train_dispatches"):
-                    point[key] = d.get(key)
-                entries = ((d.get("costbook") or {}).get("entries")) or {}
-                point["fold_entries"] = {
-                    name: e for name, e in entries.items()
-                    if name.startswith("combat.fold_")
-                }
-                return point
-        point["error"] = f"rc={r.returncode}"
-        point["tail"] = (r.stderr or "").strip().splitlines()[-3:]
-        return point
-
-    points = [one(e) for e in (0, 1)]
-    train_k = int(getattr(args, "train", 0) or 0)
-    if train_k > 1:
-        points.append(one(0, train=train_k))
-    head = next(
-        (p for p in points if p.get("value") and not p.get("error")), None
-    )
-    if head is None:
-        raise RuntimeError(f"no engine point succeeded: {points}")
-    return {
-        "metric": "pallas_engine_ab",
-        "value": head["value"],
-        "unit": "entity-ticks/s",
-        "vs_baseline": round(head["value"] / NORTH_STAR_RATE, 4),
-        "detail": {
-            "entities": args.entities,
-            "ticks": args.ticks,
-            "seed": args.seed,
-            "platform": args.platform,
-            "points": points,
-        },
-    }
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     # entities/ticks default to None: each mode fills in its own size
@@ -1128,16 +1061,14 @@ def main() -> None:
     ap.add_argument(
         "--sweep-ab", action="store_true",
         help="with --sweep-sessions: also run the legacy engine at "
-             "every count (before/after waterfall pairs).  Without "
-             "--sweep-sessions: waterfall the two combat fold engines "
-             "(--pallas 0/1), each in a subprocess, into one payload",
+             "every count (before/after waterfall pairs)",
     )
     ap.add_argument(
         "--pallas", type=int, choices=(0, 1), default=None,
-        help="combat fold engine: 0 XLA stencil fold, 1 Pallas fold "
-             "over the same tables.  Sets NF_PALLAS for this process — "
-             "the knob is read at trace time, so A/B sweeps respawn one "
-             "subprocess per engine; overrides bench_runs/tuning.json",
+        help="pin the combat fold engine for this run: 0 XLA stencil "
+             "fold, 1 Pallas fold over the same tables "
+             "(CombatModule.use_pallas).  Left out, the module chooses "
+             "from the grid it traces",
     )
     ap.add_argument(
         "--sweep-timeout", type=float, default=900.0,
@@ -1212,22 +1143,6 @@ def main() -> None:
     args = ap.parse_args()
     explicit_tpu = args.platform == "tpu"
     args.platform = args.platform or "tpu"
-    if args.pallas is not None:
-        # trace-time knob: must sit in the environment before the first
-        # world build; an explicit flag beats tuning.json (which applies
-        # via setdefault) and the inherited environment alike
-        os.environ["NF_PALLAS"] = str(args.pallas)
-
-    if args.sweep_ab and not args.sweep_sessions and not args.served:
-        # the engine-waterfall parent never touches jax — every engine
-        # point is a subprocess (NF_PALLAS is a trace-time knob: only a
-        # respawn gives each engine an honest fresh trace)
-        if args.entities is None:
-            args.entities = 20_000  # the r11 acceptance geometry
-        if args.ticks is None:
-            args.ticks = 30
-        _emit(_run_pallas_ab(args))
-        return
 
     if args.served and args.sweep_sessions:
         # the sweep parent never touches jax — every point is a child

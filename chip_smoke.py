@@ -18,8 +18,9 @@ One chip:
            two same-seed 100k worlds give identical state_digest streams
            on the chip; the same seed at 4,096 entities on the chip and on
            the host CPU is compared and printed, not gated.
-  engines  the Pallas fold (engine 1) runs natively at the 100k geometry
-           and matches the XLA fold (engine 0) bit for bit.
+  engines  the fold the module chose from its grid at the 100k geometry
+           (on the chip the Pallas kernel, compiled natively) against a
+           world pinned to the other fold: bit for bit the same.
   served   all five roles (LocalCluster) over a 100k world, 32 GameClient
            sessions through the whole login handshake over loopback TCP,
            then >= 60 served frames.
@@ -302,9 +303,11 @@ def phase_determinism_and_engines(sz, seed, cache, platform) -> None:
          chip_platform=platform, cpu_equals_chip=d_chip == d_cpu,
          first_differing_tick=first_diff)
 
-    # engine 1 (combat_fold_pallas) against engine 0 on the same state:
-    # world `a` is the engine-0 control, `p` runs the Pallas fold
-    p = world(engine=1)
+    # the two folds on the same state: world `a` baked the engine the
+    # module chose from its grid (nothing pins it), `p` is pinned to the
+    # other one
+    chosen = a.combat.engine_baked
+    p = world(engine=1 - chosen)
     dp = digests(p, 10)
     for w in (a, p):
         w.kernel.run_device(sz["soak"])  # deaths and respawns land
@@ -313,23 +316,26 @@ def phase_determinism_and_engines(sz, seed, cache, platform) -> None:
         np.array_equal(np.asarray(x), np.asarray(y))
         for x, y in zip(jax.tree.leaves(a.kernel.state.classes["NPC"]),
                         jax.tree.leaves(p.kernel.state.classes["NPC"])))
+    cap = p.kernel.store.capacity("NPC")
     emit("engines", entities=n, geometry={
              "width": p.combat.width,
-             "bucket": p.combat.resolved_bucket(p.kernel.store.capacity("NPC")),
-             "att_bucket": p.combat.resolved_att_bucket(
-                 p.kernel.store.capacity("NPC"))},
-         engine0_baked=a.combat.engine_baked,
-         engine1_baked=p.combat.engine_baked,
+             "bucket": p.combat.resolved_bucket(cap),
+             "att_bucket": p.combat.resolved_att_bucket(cap)},
+         engine_chosen=chosen,
+         engine_pinned=p.combat.engine_baked,
          pallas_interpret=pallas_interpret(),
          ticks=int(p.kernel.tick_count),
          digests_equal=(da + da2) == (dp + dp2), banks_equal=banks_equal,
          respawns=p.kernel.counter_totals.get("respawns", 0))
-    gate(a.combat.engine_baked == 0 and p.combat.engine_baked == 1,
-         "each world baked the engine it was asked for")
+    gate(chosen in (0, 1) and p.combat.engine_baked == 1 - chosen,
+         "the unpinned world baked a fold and the pinned one the other")
+    gate(platform == "tpu" or chosen == 0,
+         "off the chip the module chooses the XLA fold")
     gate(pallas_interpret() == (platform == "cpu"),
          "the Pallas fold is interpreted on the CPU and nowhere else")
     gate((da + da2) == (dp + dp2) and banks_equal,
-         "engine 1 matches engine 0 bit for bit", e0=da2, e1=dp2)
+         "the Pallas fold matches the XLA fold bit for bit",
+         chosen=da2, pinned=dp2)
 
 
 # ---------------------------------------------------------------- served

@@ -198,12 +198,15 @@ class CombatModule(Module):
         self.overflow_total = 0
         self.overflow_alerts = 0
         self._overflow_log_muted = False
-        # fold engine selector (None = NF_PALLAS env knob):
-        #   0/False  XLA stencil fold over the cell tables
-        #   1/True   Pallas fold kernel over the same tables
-        #            (ops/stencil_pallas.combat_fold_pallas)
-        # Opt-in until chip-time confirms a win.  (The stencil engine is
-        # the only combat engine: at honest bucket sizes it beats the old
+        # the one programmatic pin on the fold's engine: None (what every
+        # deployment runs) leaves the choice to `resolved_engine`, which
+        # makes it from the grid this module traces; 0/False forces the
+        # XLA stencil fold, 1/True the Pallas kernel over the same tables
+        # (ops/stencil_pallas.combat_fold_pallas).  It is here so that the
+        # parity tests, chip_smoke.py's engine gate and an A/B on the chip
+        # can force each side; nothing in a deployment sets it and no
+        # environment variable reaches it.  (The stencil engine is the
+        # only combat engine: at honest bucket sizes it beats the old
         # per-candidate-gather pipeline even on a single CPU core —
         # 103 ms vs 186 ms at 100k — and by ~25x on a v5e, where
         # irregular gathers run at ~1% of HBM bandwidth.)
@@ -357,26 +360,25 @@ class CombatModule(Module):
         eff = max(1, int(math.ceil(capacity * self._attacker_duty)))
         return min(-(-2 * eff // 8) * 8, capacity)
 
-    def resolved_engine(self) -> int:
-        """The fold engine this trace will bake in: 0 (XLA fold) or 1
-        (Pallas fold, same tables).  `use_pallas` wins when set (bools
-        keep their historical meaning: True == 1); otherwise NF_PALLAS
-        decides.  Unknown values raise instead of silently running the
-        default — a typo'd engine would invalidate any A/B it labeled."""
-        mode = self.use_pallas
-        if mode is None:
-            import os
+    def resolved_engine(self, capacity: int) -> int:
+        """The fold engine a tick traced now over `capacity` rows bakes
+        in: 0 (XLA fold) or 1 (Pallas fold, same tables, equal bit for
+        bit).  Unpinned it is `stencil_pallas.fold_engine`'s answer from
+        the platform the tick is traced for, this grid's width and the
+        two resolved depths, so a bucket boost can change it on the
+        retrace.  `use_pallas` pins it (bools keep their historical
+        meaning: True == 1); unknown values raise instead of silently
+        running the default — a typo'd engine would invalidate any A/B
+        it labeled."""
+        from ..ops import stencil_pallas
 
-            # nf-lint: disable=trace-safety -- sanctioned A/B knob:
-            # trace-time read baked into the compiled fold; flipping
-            # NF_PALLAS needs a fresh jit cache by design
-            raw = os.environ.get("NF_PALLAS", "").strip()
-            if raw in ("", "0", "1"):
-                return int(raw or "0")
-            raise ValueError(
-                f"NF_PALLAS={raw!r}: expected one of '', '0', '1'"
+        if self.use_pallas is None:
+            return stencil_pallas.fold_engine(
+                stencil_pallas.trace_platform(), self.width,
+                self.resolved_bucket(capacity),
+                self.resolved_att_bucket(capacity),
             )
-        mode = int(mode)
+        mode = int(self.use_pallas)
         if mode not in (0, 1):
             raise ValueError(f"use_pallas={mode!r}: expected 0 or 1")
         return mode
@@ -411,7 +413,7 @@ class CombatModule(Module):
         n = pos.shape[0]
         bucket = self.resolved_bucket(n)
         att_bucket = self.resolved_att_bucket(n)
-        engine = self.engine_baked = self.resolved_engine()
+        engine = self.engine_baked = self.resolved_engine(n)
         # TWO tables: every alive entity is RESIDENT as a victim (K deep),
         # but only this tick's attackers ride the 9x-scanned candidate
         # side (K_att deep — with staggered attack phases K_att is
@@ -489,16 +491,13 @@ class CombatModule(Module):
             ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
         with jax.named_scope("nf.aoe.fold"):
             if engine == 1:
-                from ..ops.stencil_pallas import (
-                    combat_fold_pallas,
-                    pallas_interpret,
-                )
+                from ..ops import stencil_pallas
 
-                inc, bestr = combat_fold_pallas(
+                inc, bestr = stencil_pallas.combat_fold_pallas(
                     vic_bin,
                     att_bin,
                     self.radius,
-                    interpret=pallas_interpret(),
+                    interpret=stencil_pallas.pallas_interpret(),
                 )
             else:
                 inc, bestr = combat_fold_xla(vic_bin, att_bin, self.radius)

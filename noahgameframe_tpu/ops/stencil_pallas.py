@@ -18,8 +18,10 @@ Outputs are [H, 3, Kv, W] (incoming, best-atk, best-row planes).
 Semantics are identical to CombatModule's XLA fold (same stencil order,
 same tie-breaks) — pinned by tests/test_stencil_pallas.py, which runs
 this kernel in interpret mode on CPU against the XLA path.  On real TPU
-hardware the kernel compiles natively; enable with NF_PALLAS=1 (opt-in
-until chip-time confirms a win over the already-fused XLA fold).
+hardware the kernel compiles natively.  Which of the two folds a tick
+bakes in is `fold_engine`'s answer, below: a function of the platform
+the tick is traced for, the grid's width and the two cell depths, and of
+nothing a deployment sets (game/combat.py `resolved_engine`).
 
 Victim feature planes (CombatModule's vic_feats; occupancy dropped):
     0: x   1: y   2: camp   3: scene   4: group
@@ -36,6 +38,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # "no attacker" sentinel (2^24) — must match game.combat.NO_ROW; finite
 # on purpose, an inf loop carry hangs the XLA CPU algebraic simplifier
@@ -57,12 +60,100 @@ PALLAS_PARITY_TESTS = {
 }
 
 
+# A vreg is 8 sublanes by 128 lanes of 32 bits: the kernel's blocks put
+# W on the lanes and K on the sublanes, each rounded up to a whole tile.
+LANES = 128
+SUBLANES = 8
+
+# The least share of the kernel's lanes that has to carry cells for it
+# to be chosen.  A grid program computes over W rounded up to whole
+# lanes whatever W is, so the kernel's time goes by the lanes and the
+# XLA fold's by the cells.  Placed by scripts/fold_probe.py on a v5e
+# (PERF.md section 7, PR 29), fleets of 131,072 cells at 20/6: the
+# kernel loses by 21, 13, 6.6 and 3.5 times at widths 4, 8, 16 and 32
+# (fill 0.03 to 0.25) and wins by 1.6 at 64 (0.5), 3.3 at 125, 3.0 at
+# 256 and 3.8 at 395; between 0.25 and 0.5 nothing is read, so the XLA
+# fold keeps it.
+FOLD_MIN_LANE_FILL = 0.5
+
+# The scoped VMEM the kernel asks Mosaic for, and so the most
+# `fold_vmem_bytes` may count for it to be chosen: twice Mosaic's own
+# default of 16 MiB, a quarter of a v5e core's VMEM.  At the default
+# the 1M world's own window depth (395 wide, 32/12) needs 15.4 of the
+# 16 MiB (found by bisecting the limit, compile-only), which no count
+# made from shapes can both admit and be safe beside.
+FOLD_VMEM_BUDGET = 32 * 1024 * 1024
+
+# [Kv, Ka, W] f32 temporaries counted live in one grid program.  What
+# Mosaic needs beyond the pipeline's blocks, over one such temporary,
+# read 9.7 to 24.7 over eleven shapes from 125 to 640 wide and 16/6 to
+# 80/24 deep (compile-only, PR 29): this is the most seen and a margin,
+# so the count errs high and the rule errs towards the XLA fold.
+FOLD_LIVE_TEMPS = 26
+
+
+def trace_platform() -> str:
+    """The platform a tick traced now is compiled for: the default
+    backend.  The one seam through which the fold's choice and the
+    kernel's interpret flag see the device (a compile-only test that
+    describes a chip it does not have steers this, in the test)."""
+    return jax.default_backend()
+
+
 def pallas_interpret() -> bool:
     """Whether the kernel below runs in Pallas interpret mode: exactly
-    when the default backend is the CPU (tests, rehearsals).  Every
+    when the tick is traced for the CPU (tests, rehearsals).  Every
     other backend, known or not, reaches its compiler and fails there
     rather than quietly interpreting."""
-    return jax.default_backend() == "cpu"
+    return trace_platform() == "cpu"
+
+
+def _tile(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
+
+
+def fold_lane_fill(width: int) -> float:
+    """Share of the lanes a grid program computes over that carry cells:
+    the victim row's W cells in W rounded up to whole vregs (395/512 at
+    1M entities, 125/128 at 100k, 4/128 in a 16-unit room)."""
+    return width / _tile(width, LANES)
+
+
+def fold_vmem_bytes(width: int, kv: int, ka: int) -> int:
+    """VMEM one grid program of `combat_fold_pallas` asks for, counted
+    from its shapes: the victim block [5, Kv, W], three attacker blocks
+    [7, Ka, W + 2] and the output block [3, Kv, W], each double-buffered
+    by the pipeline, plus `FOLD_LIVE_TEMPS` [Kv, Ka, W] f32
+    temporaries, K in whole sublanes and W in whole lanes.  An upper
+    bound by every compile read so far, not Mosaic's own count."""
+    kv8, ka8 = _tile(kv, SUBLANES), _tile(ka, SUBLANES)
+    wv, wa = _tile(width, LANES), _tile(width + 2, LANES)
+    blocks = (N_VFEATS + 3) * kv8 * wv + 3 * N_AFEATS * ka8 * wa
+    return 4 * (2 * blocks + FOLD_LIVE_TEMPS * kv8 * ka8 * wv)
+
+
+def fold_engine(platform: str, width: int, kv: int, ka: int) -> int:
+    """Which fold a tick traced for `platform` over a `width`-wide grid
+    with cells `kv` victims and `ka` attackers deep bakes in: 1 (the
+    kernel below) or 0 (game/combat.py `combat_fold_xla`).  The two are
+    equal bit for bit, so this is a choice of speed alone, and it is 1
+    only when all of these hold:
+
+    - the platform is a TPU: on the CPU the kernel is interpreted, a
+      test device;
+    - the kernel's lanes are filled (`fold_lane_fill`): a narrow grid
+      leaves most of every vreg empty and the XLA fold wins it;
+    - a grid program fits the VMEM the kernel asks for
+      (`fold_vmem_bytes` against `FOLD_VMEM_BUDGET`): a bucket boost
+      doubles both depths on a live tick, and a retrace into a kernel
+      Mosaic refuses would take the server down."""
+    if platform != "tpu":
+        return 0
+    if fold_lane_fill(width) < FOLD_MIN_LANE_FILL:
+        return 0
+    if fold_vmem_bytes(width, kv, ka) > FOLD_VMEM_BUDGET:
+        return 0
+    return 1
 
 
 def _kernel(vic_ref, top_ref, mid_ref, bot_ref, out_ref, *, w: int, r2: float):
@@ -154,6 +245,8 @@ def combat_fold_pallas(vic_table, att_table, radius: float, interpret: bool = Fa
         in_specs=[vic_spec, att_spec(0), att_spec(1), att_spec(2)],
         out_specs=pl.BlockSpec((1, 3, kv, w), lambda y: (y, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((h, 3, kv, w), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FOLD_VMEM_BUDGET),
         interpret=interpret,
     )(vic, att, att, att)
     inc = jax.lax.bitcast_convert_type(

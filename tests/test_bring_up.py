@@ -195,6 +195,10 @@ def test_chip_smoke_cpu_rehearsal(tmp_path, chips):
     assert phases["determinism"]["identical"] is True
     assert phases["engines"]["pallas_interpret"] is True
     assert phases["engines"]["banks_equal"] is True
+    # traced for the CPU the module chooses the XLA fold; the pin gives
+    # the other one
+    assert (phases["engines"]["engine_chosen"],
+            phases["engines"]["engine_pinned"]) == (0, 1)
     assert phases["served"]["leases_not_up"] == []
     assert "inbound_backlog_max" in phases["served"]
     assert phases["served"]["per_client_min"]["interest_msgs"] > 0

@@ -6,6 +6,35 @@ import pytest
 
 from noahgameframe_tpu.game import GameWorld, WorldConfig
 from noahgameframe_tpu.game.defines import PropertyGroup
+from noahgameframe_tpu.ops import stencil_pallas as sp
+
+# (platform, width, victim depth, attacker depth) -> engine
+RULE_CASES = [
+    # the three cells' grids at the depths they soak and run at
+    ("tpu", 395, 16, 6, 1),    # tick-1m, the soak
+    ("tpu", 395, 32, 12, 1),   # tick-1m, the window (boost 2)
+    ("tpu", 125, 20, 6, 1),    # served-100k-s32, the soak
+    ("tpu", 125, 40, 12, 1),   # served-100k-s32, the window
+    ("tpu", 4, 20, 6, 0),      # a room of rooms-fleet
+    # off the chip the kernel is interpreted: a test device
+    ("cpu", 395, 32, 12, 0),
+    ("cpu", 125, 20, 6, 0),
+    ("cpu", 4, 20, 6, 0),
+    ("gpu", 395, 32, 12, 0),
+    # the lane-fill boundary, on both sides, in the first lane tile
+    # and past it
+    ("tpu", 63, 20, 6, 0),
+    ("tpu", 64, 20, 6, 1),
+    ("tpu", 128, 20, 6, 1),
+    ("tpu", 129, 20, 6, 1),
+    # VMEM: the depths a further boost reaches
+    ("tpu", 395, 64, 24, 0),
+    ("tpu", 395, 128, 48, 0),
+    ("tpu", 125, 80, 24, 1),
+    ("tpu", 125, 160, 48, 0),
+    ("tpu", 640, 16, 6, 1),
+    ("tpu", 640, 32, 12, 0),
+]
 
 
 def build(n, seed, use_pallas, attack_period_s=1.0 / 30.0):
@@ -215,8 +244,8 @@ def test_engine_digest_parity_120():
 
 def test_pallas_replay_digest_stream_clean():
     """Per-tick digest STREAMS (not just the end state) are identical
-    with the engine knob flipped — a replay of the same seed under
-    NF_PALLAS=1 stays digest-clean at every tick."""
+    with the engine pin flipped: a replay of the same seed under the
+    other fold stays digest-clean at every tick."""
     assert _digest_stream(0, 12) == _digest_stream(1, 12)
 
 
@@ -252,16 +281,13 @@ def test_engine_baked_names_the_traced_engine(engine):
     assert w.kernel.last_counters["aoe_attacker_chunks"] == 1
 
 
-def test_engine_two_is_refused_when_the_tick_traces(monkeypatch):
-    """The deleted fused engine's number is an unknown value like any
-    other: the tick raises at trace time, by argument or by variable,
-    and bakes nothing."""
-    w = build(8, 1, use_pallas=2)
-    with pytest.raises(ValueError, match="use_pallas=2"):
-        w.tick()
-    w = build(8, 1, use_pallas=None)
-    monkeypatch.setenv("NF_PALLAS", "2")
-    with pytest.raises(ValueError, match="NF_PALLAS='2'"):
+@pytest.mark.parametrize("pin", [2, 3, -1])
+def test_an_unknown_pin_is_refused_when_the_tick_traces(pin):
+    """`use_pallas` pins 0 or 1 (the deleted fused engine's 2 is an
+    unknown value like any other): the tick raises at trace time and
+    bakes nothing."""
+    w = build(8, 1, use_pallas=pin)
+    with pytest.raises(ValueError, match=f"use_pallas={pin}"):
         w.tick()
     assert w.combat.engine_baked is None
 
@@ -324,26 +350,75 @@ def test_pallas_fold_under_vmap_matches_xla():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_resolved_engine_validation(monkeypatch):
-    """Bools keep their historical meaning; unknown values (the deleted
-    engine 2 among them) raise instead of silently running the default."""
-    w = build(8, 1, use_pallas=None)
-    c = w.combat
-    for env, want in (("", 0), ("0", 0), ("1", 1)):
-        monkeypatch.setenv("NF_PALLAS", env)
-        assert c.resolved_engine() == want
-    monkeypatch.delenv("NF_PALLAS")
-    assert c.resolved_engine() == 0
-    for bad in ("2", "fused"):
-        monkeypatch.setenv("NF_PALLAS", bad)
-        with pytest.raises(ValueError):
-            c.resolved_engine()
-    monkeypatch.delenv("NF_PALLAS")
-    c.use_pallas = True
-    assert c.resolved_engine() == 1
-    c.use_pallas = False
-    assert c.resolved_engine() == 0
-    for bad in (2, 3):
-        c.use_pallas = bad
-        with pytest.raises(ValueError):
-            c.resolved_engine()
+# ------------------------------------------ the fold's choice of engine
+
+
+@pytest.mark.parametrize("platform,width,kv,ka,want", RULE_CASES)
+def test_fold_engine_rule(platform, width, kv, ka, want):
+    """`fold_engine` is a function of the platform, the grid's width and
+    the two depths, and answers as the cells' grids need it to."""
+    assert sp.fold_engine(platform, width, kv, ka) == want
+
+
+def test_fold_engine_rule_in_its_own_terms():
+    """The rule's two measures: the share of lanes that carry cells
+    goes by the width alone, and the VMEM count grows with either depth
+    and with the lanes, so a boost can only move a grid from the kernel
+    to the XLA fold, never back."""
+    assert sp.fold_lane_fill(395) == 395 / 512
+    assert sp.fold_lane_fill(125) == 125 / 128
+    assert sp.fold_lane_fill(4) == 4 / 128
+    assert sp.fold_lane_fill(128) == 1.0 > sp.fold_lane_fill(129)
+    for width in (125, 395):
+        need = [sp.fold_vmem_bytes(width, 16 * b, 6 * b) for b in (1, 2, 4, 8)]
+        assert need == sorted(need) and len(set(need)) == 4
+        answers = [sp.fold_engine("tpu", width, 16 * b, 6 * b)
+                   for b in (1, 2, 4, 8)]
+        assert answers == sorted(answers, reverse=True)
+    # K rides the sublanes in whole tiles of 8: 12 deep costs what 16 does
+    assert sp.fold_vmem_bytes(395, 32, 12) == sp.fold_vmem_bytes(395, 32, 16)
+    assert sp.fold_vmem_bytes(512, 32, 12) > sp.fold_vmem_bytes(395, 32, 12)
+
+
+@pytest.mark.parametrize("boost,want", [(1, 1), (2, 1), (4, 0), (8, 0)])
+def test_a_bucket_boost_retraces_into_a_fold_that_compiles(
+        monkeypatch, boost, want):
+    """`resolved_engine` hands the rule this module's own width and
+    resolved depths: `npc-1m`'s grid gets the kernel at the depths it
+    soaks (16/6) and runs (32/12) at, and the XLA fold from the next
+    doubling on, which Mosaic refuses (tests/test_tpu_compile.py)."""
+    from noahgameframe_tpu.game.combat import CombatModule
+
+    monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
+    m = CombatModule(extent=float(np.sqrt(1_000_000 / 0.4)), radius=4.0)
+    m._attacker_duty = 1.0 / 30.0
+    m._bucket_boost = boost
+    cap = 1 << 20
+    assert (m.width, m.resolved_bucket(cap), m.resolved_att_bucket(cap)) \
+        == (395, 16 * boost, 6 * boost)
+    assert m.resolved_engine(cap) == want
+    for pin in (0, 1, False, True):
+        m.use_pallas = pin
+        assert m.resolved_engine(cap) == int(pin)
+
+
+def test_a_world_traced_on_the_cpu_bakes_the_xla_fold():
+    """Nothing pinned, the tick traced for the CPU bakes engine 0 at
+    any grid (the kernel is interpreted here, a test device); pinned to
+    1 the same world bakes the kernel, and the two digest streams are
+    equal."""
+    def stream(pin):
+        w = build(120, 3, use_pallas=pin)
+        k = w.kernel
+        k.enable_digest()
+        out = []
+        for _ in range(8):
+            k.tick()
+            out.append(int(k.last_counters["state_digest"]) & 0xFFFFFFFF)
+        return w.combat.engine_baked, out
+
+    assert sp.trace_platform() == "cpu" and sp.pallas_interpret()
+    chosen, a = stream(None)
+    pinned, b = stream(1)
+    assert (chosen, pinned) == (0, 1)
+    assert a == b

@@ -279,11 +279,10 @@ PKG = Path(__file__).resolve().parent.parent / "noahgameframe_tpu"
 # whole string literal where it is read): a knob added or left behind
 # changes this list in the PR that does it
 NF_ENV_NAMES = {
-    "NF_FAILOVER_DEADLINE_S", "NF_NATIVE_DIR", "NF_PALLAS",
-    "NF_PARK_MAX_FRAMES", "NF_ROOM_SLOTS", "NF_SERVE_BATCH",
-    "NF_SERVE_CHUNK", "NF_SERVE_OVERLAP", "NF_SERVE_SLOTS",
-    "NF_STAGE_TIMING", "NF_TICK_TRAIN", "NF_TRACE_SAMPLE",
-    "NF_VERLET_SKIN",
+    "NF_FAILOVER_DEADLINE_S", "NF_NATIVE_DIR", "NF_PARK_MAX_FRAMES",
+    "NF_ROOM_SLOTS", "NF_SERVE_BATCH", "NF_SERVE_CHUNK",
+    "NF_SERVE_OVERLAP", "NF_SERVE_SLOTS", "NF_STAGE_TIMING",
+    "NF_TICK_TRAIN", "NF_TRACE_SAMPLE", "NF_VERLET_SKIN",
 }
 
 
@@ -309,9 +308,10 @@ def test_census_of_nf_environment_names():
     assert found == NF_ENV_NAMES
 
 
-def test_neighbour_engine_reads_two_names_from_the_environment():
+def test_neighbour_engine_reads_one_name_from_the_environment():
     """Under `ops/` and in `game/combat.py` the environment is read in
-    two places: the fold engine (NF_PALLAS) and the Verlet skin."""
+    one place, the Verlet skin: the fold's engine is chosen from the
+    grid (`stencil_pallas.fold_engine`), not from a variable."""
     reads = {}
     for path in sorted(PKG.glob("ops/*.py")) + [PKG / "game" / "combat.py"]:
         tree = ast.parse(path.read_text(), str(path))
@@ -332,5 +332,4 @@ def test_neighbour_engine_reads_two_names_from_the_environment():
             name = (arg.value if isinstance(arg, ast.Constant)
                     else consts.get(getattr(arg, "id", None), ast.dump(arg)))
             reads.setdefault(path.name, set()).add(name)
-    assert reads == {"combat.py": {"NF_PALLAS"},
-                     "verlet.py": {"NF_VERLET_SKIN"}}
+    assert reads == {"verlet.py": {"NF_VERLET_SKIN"}}

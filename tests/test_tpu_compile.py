@@ -2,10 +2,12 @@
 
 The TPU's compiler is installed here and compiles for a chip that is
 described (`v5e:2x2`) and not attached, so these cases guard every later
-PR at no chip time: the Pallas fold at the real 100k and 1M geometries,
-the program that seeds a 1M world, the default tick, one sharded tick
-over the described 2x2 mesh, and the clone-scene fleet's `rooms.step` at
-its benchmarked size.  A compile that passes is not a chip run: nothing
+PR at no chip time: the Pallas fold at the real 100k and 1M geometries
+and every depth a bucket boost can take them to (held against what
+`fold_engine` answers there), the program that seeds a 1M world, the
+default tick, the 1M tick with the kernel in it, one sharded tick over
+the described 2x2 mesh, and the clone-scene fleet's `rooms.step` at its
+benchmarked size.  A compile that passes is not a chip run: nothing
 executes, so no result or time is checked here.
 
 This is the only file that describes a topology.  The description
@@ -88,31 +90,56 @@ def _shapes(tree, sharding):
         tree, sharding)
 
 
-@pytest.mark.parametrize("n,want", [
-    (100_000, (125, 20, 6)),
-    (1_000_000, (395, 16, 6)),
+@pytest.mark.parametrize("boost", [1, 2, 4, 8])
+@pytest.mark.parametrize("n,want,kernel_up_to", [
+    (100_000, (125, 20, 6), 4),
+    (1_000_000, (395, 16, 6), 2),
 ])
-def test_combat_fold_pallas_compiles_natively(one_chip, n, want):
+def test_combat_fold_pallas_compiles_natively(one_chip, n, want,
+                                              kernel_up_to, boost):
+    """The two benchmarked grids at the depths they are built with and
+    at every doubling `auto_resize` may make on a live tick (the windows
+    run at boost 2: 395 at 32/12, 125 at 40/12), up to
+    `max_bucket_boost`.  Each case compiles what `fold_engine` answers
+    there: the kernel (up to boost `kernel_up_to`), natively, or the XLA
+    fold.  At the first doubling past the kernel the kernel is tried
+    too: if Mosaic refuses it, it is for VMEM, and the rule had answered
+    0, so the retrace bakes a fold that compiles."""
+    from noahgameframe_tpu.game.combat import combat_fold_xla
+
     cap, width, cell, kv, ka = _geometry(n)
     assert (width, kv, ka) == want, "benchmark geometry moved"
+    assert boost <= CombatModule().max_bucket_boost
+    kv, ka = kv * boost, ka * boost
+    rule = sp.fold_engine("tpu", width, kv, ka)
+    assert rule == (1 if boost <= kernel_up_to else 0)
     cells = width * width
-
-    def fold(vp, vs, ap, as_):
-        return sp.combat_fold_pallas(
-            CellTable(vp, vs, jnp.int32(0), width, cell, kv),
-            CellTable(ap, as_, jnp.int32(0), width, cell, ka),
-            4.0, interpret=False)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(fold).lower(
-        arg((cells * kv + 1, sp.N_VFEATS + 1), jnp.float32),
-        arg((cap,), jnp.int32),
-        arg((cells * ka + 1, sp.N_AFEATS + 1), jnp.float32),
-        arg((cap,), jnp.int32),
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    def compiled_text(fold):
+        return jax.jit(lambda vp, vs, ap, as_: fold(
+            CellTable(vp, vs, jnp.int32(0), width, cell, kv),
+            CellTable(ap, as_, jnp.int32(0), width, cell, ka))).lower(
+            arg((cells * kv + 1, sp.N_VFEATS + 1), jnp.float32),
+            arg((cap,), jnp.int32),
+            arg((cells * ka + 1, sp.N_AFEATS + 1), jnp.float32),
+            arg((cap,), jnp.int32),
+        ).compile().as_text()
+
+    if rule == 1 or boost == 2 * kernel_up_to:
+        try:
+            text = compiled_text(lambda v, a: sp.combat_fold_pallas(
+                v, a, 4.0, interpret=False))
+        except Exception as e:  # noqa: BLE001 -- the compiler's refusal
+            assert "vmem" in str(e), e
+            assert rule == 0, f"the rule chose a kernel Mosaic refuses: {e}"
+        else:
+            assert "tpu_custom_call" in text
+    if rule == 0:
+        text = compiled_text(lambda v, a: combat_fold_xla(v, a, 4.0))
+        assert "tpu_custom_call" not in text
 
 
 def test_world_seeding_fits_one_chip_at_1m(one_chip):
@@ -197,7 +224,8 @@ def test_sharded_tick_compiles_for_the_2x2_mesh(topo):
     assert compiled.memory_analysis().argument_size_in_bytes < bank
 
 
-def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip):
+def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip,
+                                                      monkeypatch):
     """`rooms.step` of `clone-rooms-5k` (benchmarks/configs): the tick
     of a 96-NPC, 16-unit room vmapped over 8,192 slots.  The bank and
     the program's temporaries fit a chip with room for the benchmark's
@@ -205,7 +233,10 @@ def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip):
     text, where the per-layer readers look for them."""
     from noahgameframe_tpu.game import BenchmarkRoomRecipe
 
-    k = BenchmarkRoomRecipe(96, 16.0, player_capacity=4)(0).kernel
+    # the fold's choice sees the chip the program is compiled for
+    monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
+    room = BenchmarkRoomRecipe(96, 16.0, player_capacity=4)(0)
+    k = room.kernel
     k._ensure_aux()
     assert k.store.capacity("NPC") == 128
     fleet = jax.tree.map(
@@ -232,3 +263,39 @@ def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip):
     assert rank_gathers == []
     assert updates == ["f32[8192,16,8]"] * 2
     assert "while/body/dynamic_slice" not in text
+    # a room's grid is 4 cells wide, 4 lanes in 128: the XLA fold
+    assert room.combat.engine_baked == 0
+    assert "tpu_custom_call" not in text
+
+
+def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch):
+    """`kernel.step` of `npc-1m` at the depths its window runs at
+    (boost 2: 32/12), traced for the chip: the fold is the Pallas kernel
+    and it is the program's only custom call.  The world is built small
+    and its NPC bank described at 2^20 rows: every shape of the tick
+    follows from the bank's and from the module's extent."""
+    from noahgameframe_tpu.game import GameWorld, WorldConfig
+
+    monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
+    extent = float(np.sqrt(1_000_000 / 0.4))
+    w = GameWorld(WorldConfig(npc_capacity=128, extent=extent, seed=0,
+                              middleware=False))
+    w.start()
+    w.scene.create_scene(1, width=extent)
+    k, combat = w.kernel, w.combat
+    k._ensure_aux()
+    combat._attacker_duty = 1.0 / 30.0  # arm_all's staggered arming
+    combat._bucket_boost = 2
+    cap = 1 << 20
+    assert (combat.width, combat.resolved_bucket(cap),
+            combat.resolved_att_bucket(cap)) == (395, 32, 12)
+    state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
+    state = state.replace(classes={**state.classes, "NPC": jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((cap,) + x.shape[1:], x.dtype,
+                                       sharding=one_chip),
+        state.classes["NPC"])})
+    compiled = jax.jit(k._trace_step, donate_argnums=0).lower(state).compile()
+    assert combat.engine_baked == 1
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
