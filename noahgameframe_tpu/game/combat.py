@@ -31,7 +31,9 @@ import jax.numpy as jnp
 from ..core.datatypes import Guid
 from ..core.store import HANDLE_ROW_BITS, WorldState, with_class
 from ..kernel.module import Module
+from ..ops.aoi import cell_of
 from ..ops.stencil import (
+    STENCIL,
     auto_bucket,
     build_cell_table_pair,
     pull_slots,
@@ -124,7 +126,7 @@ def combat_fold_closure(v, radius: float):
     return fold, init
 
 
-def combat_fold_xla(vic_table, att_table, radius):
+def combat_fold_xla(vic_table, att_table, radius, raw: bool = False):
     """The XLA stencil fold over the split victim/attacker cell tables:
     nine shifted candidate blocks against the resident victim grid, with
     [Kv, Ka] pairwise masked reductions fused by XLA onto the VPU.
@@ -140,10 +142,190 @@ def combat_fold_xla(vic_table, att_table, radius):
     No self-exclusion compare: self always shares its own camp, so the
     no-friendly-fire mask rules self out of every pair."""
     fold, init = combat_fold_closure(vic_table.grid_view(), radius)
-    inc, _besta, bestr = stencil_fold(att_table, fold, init)
-    # NO_ROW (no attacker) -> -1; row ids are exact in f32 (< 2^24)
-    bestr = jnp.where(bestr >= NO_ROW, -1.0, bestr).astype(jnp.int32)
-    return inc, bestr
+    inc, besta, bestr = stencil_fold(att_table, fold, init)
+    if raw:
+        # the accumulators as the fold carries them, for the second
+        # level to fold on into (combat_fold_spill)
+        return inc, besta, bestr
+    return inc, _best_rows(bestr)
+
+
+def _best_rows(bestr):
+    """NO_ROW (no attacker) -> -1; row ids are exact in f32 (< 2^24)."""
+    return jnp.where(bestr >= NO_ROW, -1.0, bestr).astype(jnp.int32)
+
+
+# Hot cells a trip of the second level's victim loop takes.  A trip's
+# time goes by what its nine gathers of whole cells carry, so the size
+# only trades loop turns against the last trip's idle lanes (one trip's
+# worth a tick at most: the work list is flat).
+SPILL_CHUNK = 512
+
+# Attacker-hot cells a trip of the attacker loop takes.  A tick has a
+# handful of them or none (a cell needs some 400 rows for 13 of them to
+# fire on one tick), and a trip folds each against the whole depth of
+# its nine neighbours: small trips, none when there is no such cell.
+SPILL_ATT_CHUNK = 32
+
+# Victims of a hot cell folded a trip.  The second level's rows are as
+# deep as the deepest cell and most over-full cells are barely over, so
+# the rows are folded in blocks of this many slots and a block nobody
+# reaches is never folded: the fold is priced by the rows that spilled
+# (rounded up to a block a cell), not by cells x depth.
+SPILL_BLOCK = 32
+
+
+def _neighbour(cell, dy: int, dx: int, width: int):
+    """The cell (dy, dx) away from `cell` on a square grid, `width**2`
+    (no cell) off the grid's edge or for no cell."""
+    n_cells = width * width
+    y, x = cell // width + dy, cell % width + dx
+    ok = (cell < n_cells) & (y >= 0) & (y < width) & (x >= 0) & (x < width)
+    return jnp.where(ok, y * width + x, n_cells)
+
+
+def _first_cell(view, cell_size: float, width: int):
+    """The cell each row of a second level stands for ([cells] int32):
+    where its first member stands (a row is filled from slot 0), and
+    `width**2` for a row that holds nobody."""
+    held = view[:, 0, -1] > 0
+    return jnp.where(
+        held, cell_of(view[:, 0, :2], cell_size, width), width * width)
+
+
+def _take(table, index, fill=0.0):
+    """table[index] along axis 0, `fill` where index is out of range."""
+    return table.at[index].get(mode="fill", fill_value=fill)
+
+
+def combat_fold_spill(vic_table, att_table, radius, inc, besta, bestr):
+    """The second level's share of the fold: the three classes of pairs
+    the base fold cannot see, each pair once, with the base fold's own
+    arithmetic (`combat_fold_closure`), so a row's result is the same
+    whatever level it or its attacker sits in.
+
+    `inc, besta, bestr`: the base fold's accumulators, [H, W, Kv] (base
+    victims x base attackers, `raw=True`).  Returns (inc, bestr) of the
+    base level, [H, W, Kv] int32, and of the second level, [cells,
+    depth] int32, for one `pull_slots(..., spill=...)`.
+
+    1. spilled victims x base attackers (the victim loop): the second
+       level's rows in blocks of SPILL_BLOCK slots, every (hot cell,
+       block) that holds somebody on one flat work list, SPILL_CHUNK of
+       them a trip, each against the base attackers of its cell's nine
+       neighbours, gathered by cell.
+    2. base victims x spilled attackers and 3. spilled x spilled (the
+       attacker loop): for every attacker-hot cell (SPILL_ATT_CHUNK a
+       trip, and no trip when there is none) and each of its nine
+       neighbours in turn, that cell's base victims and, if it is a hot
+       cell, its spilled victims are gathered with their accumulators,
+       folded on with the hot cell's spilled attackers, and written
+       back.  Distinct hot cells have distinct neighbours in one
+       direction, so each write has unique indices; the merge is the
+       fold's own (sum; max attack, min row), so the order of trips is
+       immaterial.
+
+    Everything irregular here is priced by the hot cells: gathers and
+    scatters of whole cells.  Square grids only (the slab shards run no
+    second level)."""
+    i32 = jnp.int32
+    width, cell_size = vic_table.width, vic_table.cell_size
+    cells = vic_table.spill_cells
+    if vic_table.height > 0 or att_table.spill_cells != cells:
+        raise ValueError("the second level needs a square grid and one "
+                         "count of hot cells on both tables")
+    n_cells = width * width
+    kv, ka = vic_table.bucket, att_table.bucket
+    kvh = vic_table.spill_bucket
+    block = SPILL_BLOCK if kvh % SPILL_BLOCK == 0 else kvh
+    n_blocks = kvh // block
+    # by cell: the compiler copies both base tables into this tiling for
+    # the gathers (a windowed gather on the payload as it lies is lowered
+    # as a loop over the cells gathered, which is worse)
+    vgrid = vic_table.payload[: n_cells * kv].reshape(n_cells, kv, -1)
+    agrid = att_table.payload[: n_cells * ka].reshape(n_cells, ka, -1)
+    vhot, ahot = vic_table.spill_view(), att_table.spill_view()
+    vcell = _first_cell(vhot, cell_size, width)
+    acell = _first_cell(ahot, cell_size, width)
+
+    # -- 1. spilled victims x base attackers ------------------------------
+    chunk = min(cells * n_blocks, SPILL_CHUNK)
+    lanes = jnp.arange(chunk, dtype=i32)
+    depth = jnp.sum(vhot[..., -1] > 0, axis=1, dtype=i32)  # [cells]
+    deepest_first = jnp.argsort(-depth)  # stable: `cells` keys
+    # hot cells that reach block b; the work list is block 0's cells,
+    # then block 1's, ..., each in `deepest_first` order
+    reach = jnp.sum(
+        depth[None, :] > (jnp.arange(n_blocks, dtype=i32) * block)[:, None],
+        axis=1, dtype=i32)
+    reach_end = jnp.cumsum(reach)
+    vblocks = vhot.reshape(cells, n_blocks, block, -1)
+
+    def victim_trip(t, out):
+        item = t * chunk + lanes
+        mine = item < reach_end[-1]
+        b = jnp.minimum(
+            jnp.sum(item[:, None] >= reach_end[None, :], axis=1, dtype=i32),
+            n_blocks - 1)
+        row = deepest_first[
+            jnp.clip(item - (reach_end[b] - reach[b]), 0, cells - 1)]
+        centre = jnp.where(mine, vcell[row], n_cells)
+        fold, acc = combat_fold_closure(vblocks[row, b][:, None], radius)
+        for dy, dx in STENCIL:
+            nbr = _neighbour(centre, dy, dx, width)
+            acc = fold(acc, _take(agrid, nbr)[:, None])
+        row = jnp.where(mine, row, cells)  # out of range: dropped
+        return tuple(o.at[row, b].set(a[:, 0], mode="drop")
+                     for o, a in zip(out, acc))
+
+    out = (jnp.zeros((cells, n_blocks, block), i32),
+           jnp.full((cells, n_blocks, block), -1.0, jnp.float32),
+           jnp.full((cells, n_blocks, block), NO_ROW, jnp.float32))
+    hot_acc = jax.lax.fori_loop(
+        0, (reach_end[-1] + chunk - 1) // chunk, victim_trip, out)
+    hot_acc = tuple(a.reshape(cells, kvh) for a in hot_acc)
+
+    # -- 2., 3. base and spilled victims x spilled attackers --------------
+    # cell -> victim-hot row (`cells`: none); a row that holds nobody
+    # writes out of range and is dropped
+    vrow_of = jnp.full((n_cells + 1,), cells, i32).at[
+        jnp.where(vcell < n_cells, vcell, n_cells + 1)
+    ].set(jnp.arange(cells, dtype=i32), mode="drop")
+    a_chunk = min(cells, SPILL_ATT_CHUNK)
+    a_lanes = jnp.arange(a_chunk, dtype=i32)
+    fills = (0, -1.0, NO_ROW)
+
+    def fold_on(acc, victims, at, cand):
+        """Gather `at`'s victims and accumulators, fold the candidates
+        on, write back (`at` out of range: nothing read or written)."""
+        fold, _ = combat_fold_closure(_take(victims, at)[:, None], radius)
+        got = fold(
+            tuple(_take(a, at, f)[:, None] for a, f in zip(acc, fills)), cand)
+        return tuple(a.at[at].set(g[:, 0], mode="drop")
+                     for a, g in zip(acc, got))
+
+    def attacker_trip(t, carry):
+        base_acc, hot_acc = carry
+        at = t * a_chunk + a_lanes
+        mine = at < cells
+        at = jnp.minimum(at, cells - 1)
+        centre = jnp.where(mine, acell[at], n_cells)
+        cand = ahot[at][:, None]  # [chunk, 1, kah, F]
+        for dy, dx in STENCIL:
+            # the victims' cell sees the attackers' cell at (dy, dx)
+            nbr = _neighbour(centre, -dy, -dx, width)
+            base_acc = fold_on(base_acc, vgrid, nbr, cand)
+            hot_acc = fold_on(hot_acc, vhot, vrow_of[nbr], cand)
+        return base_acc, hot_acc
+
+    n_ahot = jnp.sum(acell < n_cells, dtype=i32)
+    base_acc = tuple(a.reshape(n_cells, kv) for a in (inc, besta, bestr))
+    base_acc, hot_acc = jax.lax.fori_loop(
+        0, (n_ahot + a_chunk - 1) // a_chunk, attacker_trip,
+        (base_acc, hot_acc))
+    return (base_acc[0].reshape(width, width, kv),
+            _best_rows(base_acc[2]).reshape(width, width, kv),
+            hot_acc[0], _best_rows(hot_acc[2]))
 
 
 class CombatModule(Module):
@@ -194,6 +376,11 @@ class CombatModule(Module):
         self.auto_resize = True
         self.max_bucket_boost = 8
         self._bucket_boost = 1
+        # the second level (ops/stencil.py `build_cell_table_pair`,
+        # `combat_fold_spill`): (hot cells, victims, attackers) it holds
+        # beyond the base depths; (0, 0, 0) until a breach that a few
+        # deep cells caused sizes it (`_on_overflow`)
+        self._spill = (0, 0, 0)
         self.overflow_last = (0, 0)  # (victims, attackers) latest tick
         self.overflow_total = 0
         self.overflow_alerts = 0
@@ -255,8 +442,11 @@ class CombatModule(Module):
 
     def _on_overflow(self, cname: str, _mask, params) -> None:
         """Host side of the tick's overflow signal: count, alert on
-        budget breach, and auto-resize (double the bucket + retrace) so
-        combat drops stop instead of staying a silent bench-only number."""
+        budget breach, and answer the breach by what breached
+        (`_answer_breach`: the second level for a few deep cells, the
+        doubling for a world denser everywhere) with a retrace, so
+        combat drops stop instead of staying a silent bench-only
+        number."""
         import logging
 
         dv = int(params["dropped_victims"][0])
@@ -268,14 +458,14 @@ class CombatModule(Module):
             return
         self.overflow_alerts += 1
         log = logging.getLogger("nf.combat")
-        if self.auto_resize and self._bucket_boost < self.max_bucket_boost:
-            self._bucket_boost *= 2
-            self.kernel.invalidate()  # bucket is baked into the trace
+        capacity = int(self.kernel.store.capacity(cname))
+        answer = self._answer_breach(capacity) if self.auto_resize else None
+        if answer is not None:
+            self.kernel.invalidate()  # depths are baked into the trace
             log.warning(
                 "combat cell-table overflow: dropped %d/%d victims+attackers "
-                "(budget %.4f%%) — bucket boosted x%d, tick retracing",
-                dv + da, alive, self.overflow_budget * 100,
-                self._bucket_boost,
+                "(budget %.4f%%) — %s, tick retracing",
+                dv + da, alive, self.overflow_budget * 100, answer,
             )
         elif not self._overflow_log_muted:
             # keep alert COUNTERS per-tick, but log the terminal state
@@ -288,6 +478,106 @@ class CombatModule(Module):
                 dv + da, alive, self.overflow_budget * 100,
                 "exhausted" if self.auto_resize else "disabled",
             )
+
+    # The breach policy's constants (PERF.md section 6, PR 30, has the
+    # counts behind each):
+    #
+    # A cell has to be this many times over the base depth before the
+    # second level is thought of.  Up to there the doubling that every
+    # world has had cures the cell at a price that is known (`tick-1m`'s
+    # breach at tick 245 reads a deepest cell of 25 or 26 rows at depth
+    # 16); the siege world's deepest cell is 8 to 16 times over.
+    SPILL_MIN_OVERDEPTH = 4
+    # The most a second level that just holds what was seen may take of
+    # the base table's slots.  Its streaming passes (zero fill, depths,
+    # results) go by its slots as the base level's go by the grid's, so
+    # a level as large as the grid is priced like the grid.  The siege
+    # world reads 0.8 to 0.95 of the grid at the shipped depth (8,700 to
+    # 9,700 over-full cells, 226 to 246 over: it doubles on every seed)
+    # and 0.15 to 0.17 after one doubling (3,500 to 3,900 cells): half
+    # lies a factor of 1.6 and of 3 from them.
+    SPILL_MAX_GRID_SHARE = 0.5
+    # Headroom over what the breaching tick observed, so that the crowd's
+    # drift brings no second retrace.  On the chip, where the dead stand
+    # still where the killing is, the over-full cells read 3,568 at tick
+    # 0 and 3,975 at tick 309 (+11%) and the deepest cell 249 and 299
+    # (+20%; 327 on another seed).  Both sizes are then rounded up to a
+    # power of two, so that worlds that differ by a seed trace the same
+    # program: sized at tick 4, the siege world's 3,550 to 4,100 cells
+    # and 210 to 224 rows over come to 8,192 x 512 on every seed, the
+    # next power a quarter away on either (at headroom 2 the seeds over
+    # 4,096 cells took 16,384, and their tick read 4% longer).  A cell's
+    # attackers are a 1-in-`interval` draw of its rows, so their depth
+    # is sized from the victims' (mean + 5 sigma of a Poisson draw).
+    SPILL_CELLS_HEADROOM = 1.5
+    SPILL_DEPTH_HEADROOM = 1.75
+    SPILL_ATTACKER_SIGMAS = 5.0
+
+    def _sized_spill(self, capacity: int, seen: dict):
+        """The second level that holds what the breaching tick observed,
+        with headroom, never smaller than the one there is: hot cells
+        and the victims' depth powers of two, the attackers' depth whole
+        sublanes (8)."""
+        import math
+
+        kv, ka = self.resolved_bucket(capacity), self.resolved_att_bucket(capacity)
+        hot = max(seen["aoe_hot_cells"], seen["aoe_hot_att_cells"], 1)
+        cells = 1 << math.ceil(math.log2(self.SPILL_CELLS_HEADROOM * hot))
+        over = max(seen["aoe_cell_rows_max"] - kv, 1)
+        # 32 at least: whole blocks
+        depth = 1 << max(5, math.ceil(math.log2(
+            self.SPILL_DEPTH_HEADROOM * over)))
+        # the attackers of the deepest cell the victims' side now holds
+        mean = (kv + depth) * min(self._attacker_duty, 1.0)
+        att = mean + self.SPILL_ATTACKER_SIGMAS * math.sqrt(mean) + 2.0
+        att = max(att, 1.5 * seen["aoe_cell_attackers_max"]) - ka
+        att_depth = -(-max(math.ceil(att), 8) // 8) * 8
+        was = self._spill
+        return (max(cells, was[0]), max(depth, was[1]), max(att_depth, was[2]))
+
+    def _answer_breach(self, capacity: int) -> Optional[str]:
+        """What a budget breach changes, from what the breaching tick
+        observed (the tick's own counters, `Kernel.last_counters`), or
+        None when nothing is left to change.
+
+        - Some cell is more than `SPILL_MIN_OVERDEPTH` times over its
+          depth, and a second level just large enough for what was seen
+          (over-full cells x the deepest one's excess) is at most
+          `SPILL_MAX_GRID_SHARE` of the base table (grid cells x
+          bucket), on both sides: a few deep cells.  Size the second
+          level for them, with headroom; the grid keeps its depth.
+        - Otherwise the world is over-full everywhere, or the over-full
+          cells are so many that their level would outgrow the grid's
+          and be priced like it: double both buckets, as ever.
+        - With the doubling used up, the second level is what is left,
+          whatever its size."""
+        seen = self.kernel.last_counters
+        can_double = self._bucket_boost < self.max_bucket_boost
+        names = ("aoe_hot_cells", "aoe_hot_att_cells", "aoe_cell_rows_max",
+                 "aoe_cell_attackers_max")
+        if self.verlet_skin <= 0.0 and all(k in seen for k in names):
+            kv = self.resolved_bucket(capacity)
+            ka = self.resolved_att_bucket(capacity)
+            deep = (seen["aoe_cell_rows_max"] > self.SPILL_MIN_OVERDEPTH * kv
+                    or seen["aoe_cell_attackers_max"]
+                    > self.SPILL_MIN_OVERDEPTH * ka)
+            sized = self._sized_spill(capacity, seen)
+            # what was seen, as second-level slots, against the grid's
+            share = self.SPILL_MAX_GRID_SHARE * self.width * self.width
+            few = (
+                seen["aoe_hot_cells"]
+                * max(seen["aoe_cell_rows_max"] - kv, 0) <= share * kv
+                and seen["aoe_hot_att_cells"]
+                * max(seen["aoe_cell_attackers_max"] - ka, 0) <= share * ka
+            )
+            if deep and (few or not can_double) and sized != self._spill:
+                self._spill = sized
+                return ("second level sized to %d hot cells, %d victims and "
+                        "%d attackers deep" % sized)
+        if can_double:
+            self._bucket_boost *= 2
+            return "bucket boosted x%d" % self._bucket_boost
+        return None
 
     def arm_all(self, stagger: bool = True) -> None:
         """Arm the attack heartbeat on every live row (benchmark seeding).
@@ -345,6 +635,16 @@ class CombatModule(Module):
             auto_bucket(eff, self.width, lo=4, align=2) * self._bucket_boost,
             self.resolved_bucket(capacity),
         )
+
+    def resolved_spill(self, capacity: int):
+        """(hot cells, victims, attackers) the second level holds beyond
+        the base depths in a tick traced now, as `resolved_bucket`
+        states the base depth: (0, 0, 0) until `_on_overflow` has sized
+        it.  The Verlet path runs no second level."""
+        if self.verlet_skin > 0.0:
+            return (0, 0, 0)
+        cells, depth, att_depth = self._spill
+        return (min(cells, max(capacity, 1)), depth, att_depth)
 
     def resolved_att_rows(self, capacity: int) -> int:
         """Rows of the sorted attacker list the table build gathers and
@@ -482,25 +782,44 @@ class CombatModule(Module):
             vic_bin, att_bin = build_cell_table_pair(
                 pos, cs.alive, vic_feats, attacking, att_feats,
                 self.cell_size, self.width, bucket, att_bucket,
-                sub_rows=att_rows,
+                sub_rows=att_rows, spill=self.resolved_spill(n),
             )
+            # what the breach policy reads (streaming reductions over
+            # the sorted keys and ranks)
+            ctx.count("aoe_hot_cells", vic_bin.stats.hot_cells)
+            ctx.count("aoe_cell_rows_max", vic_bin.stats.rows_max)
+            ctx.count("aoe_hot_att_cells", att_bin.stats.hot_cells)
+            ctx.count("aoe_cell_attackers_max", att_bin.stats.rows_max)
+            ctx.count("aoe_spill_rows",
+                      vic_bin.stats.spill_rows + att_bin.stats.spill_rows)
             # how the chunk engages: 1 a tick under the arming it was
             # sized for
             chunks = sub_chunks(attacking, att_rows)
             ctx.count("aoe_attacker_chunks", chunks)
             ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
+        spilling = vic_bin.spill_cells > 0
         with jax.named_scope("nf.aoe.fold"):
             if engine == 1:
                 from ..ops import stencil_pallas
 
-                inc, bestr = stencil_pallas.combat_fold_pallas(
+                folded = stencil_pallas.combat_fold_pallas(
                     vic_bin,
                     att_bin,
                     self.radius,
                     interpret=stencil_pallas.pallas_interpret(),
+                    raw=spilling,
                 )
             else:
-                inc, bestr = combat_fold_xla(vic_bin, att_bin, self.radius)
+                folded = combat_fold_xla(
+                    vic_bin, att_bin, self.radius, raw=spilling)
+        hot = None
+        if spilling:
+            with jax.named_scope("nf.aoe.spill"):
+                inc, bestr, hot_inc, hot_bestr = combat_fold_spill(
+                    vic_bin, att_bin, self.radius, *folded)
+                hot = jnp.stack([hot_inc, hot_bestr], axis=-1)
+        else:
+            inc, bestr = folded
         if self.emit_events:
             # runtime overflow signal: the duty-sized attacker bucket is
             # baked into the traced tick, so arming patterns that
@@ -525,7 +844,7 @@ class CombatModule(Module):
         with jax.named_scope("nf.aoe.pull"):
             pulled = pull_slots(
                 vic_bin.slot_of, jnp.stack([inc, bestr], axis=-1),
-                fill=(0, -1),
+                fill=(0, -1), spill=hot,
             )
         incoming = pulled[..., 0]
         # dead-but-not-yet-respawned victims take no damage

@@ -84,6 +84,52 @@ def draw_npcs(rng: np.random.Generator, n: int, extent: float, camps: int):
     return pos, target, rng.integers(0, camps, n)
 
 
+def zipf_camp_sizes(n: int, camps: int, zipf: float) -> np.ndarray:
+    """n NPCs shared out over `camps` spawn camps by a Zipf law: camp k
+    (from 1) holds n k^-zipf / sum_j j^-zipf, the shares rounded down
+    and the remainder handed to the largest fractions."""
+    w = np.arange(1, camps + 1, dtype=np.float64) ** -float(zipf)
+    share = n * w / w.sum()
+    sizes = np.floor(share).astype(np.int64)
+    short = int(n - sizes.sum())
+    sizes[np.argsort(-(share - sizes), kind="stable")[:short]] += 1
+    return sizes
+
+
+def draw_about(rng: np.random.Generator, centres: np.ndarray,
+               home: np.ndarray, leash: float, extent: float) -> np.ndarray:
+    """One walk target a row: uniform on the leash's square about the
+    row's camp, clipped to the extent (MovementModule's law)."""
+    u = rng.random((home.size, 2), dtype=np.float32)
+    return np.clip(centres[home] + np.float32(leash)
+                   * (np.float32(2.0) * u - np.float32(1.0)),
+                   np.float32(0.0), np.float32(extent)).astype(np.float32)
+
+
+def draw_camp_npcs(rng: np.random.Generator, n: int, extent: float,
+                   teams: int, camps: int, zipf: float, leash: float):
+    """What a seed decides about n NPCs spawned on Zipf-sized camps, in
+    the one order the generator is consumed in: the camps' centres
+    (uniform over the extent less the leash on every side, so that a
+    camp's square lies inside the world: a square cut by the border
+    piles its clipped draws on the border line, and by both borders on
+    the corner point), two walk targets a row about its camp, a
+    point on the segment between them (where the walk would have the
+    row; z = 0), teams.  Rows are handed out camp by camp, the largest
+    first, as a scene's seed list is spawned.  Returns (pos, target,
+    team, centres, home)."""
+    margin = min(float(leash), extent / 2.0)
+    centres = rng.uniform(margin, extent - margin,
+                          (camps, 2)).astype(np.float32)
+    home = np.repeat(np.arange(camps), zipf_camp_sizes(n, camps, zipf))
+    start = draw_about(rng, centres, home, leash, extent)
+    target = draw_about(rng, centres, home, leash, extent)
+    along = rng.random((n, 1), dtype=np.float32)
+    pos = np.zeros((n, 3), np.float32)
+    pos[:, :2] = start + along * (target - start)
+    return pos, target, rng.integers(0, teams, n), centres, home
+
+
 class GameWorld:
     """The assembled standard stack; `.pm` is the plugin manager."""
 
@@ -185,6 +231,19 @@ class GameWorld:
                 help="combat fold engine baked into the newest trace "
                      "(0 XLA, 1 Pallas fold; -1 before the first trace)",
             )
+            npc_rows = cfg.npc_capacity
+            self.telemetry.registry.register_callback(
+                "nf_combat_spill_cells",
+                lambda: combat.resolved_spill(npc_rows)[0], kind="gauge",
+                help="over-full cells the neighbour engine's second level "
+                     "holds (0 until a budget breach sized it)",
+            )
+            self.telemetry.registry.register_callback(
+                "nf_combat_spill_depth",
+                lambda: combat.resolved_spill(npc_rows)[1], kind="gauge",
+                help="victims a hot cell keeps in the second level beyond "
+                     "the base depth (0 until a budget breach sized it)",
+            )
 
         # elastic mesh surface (parallel/elastic.py): populated by
         # .shard(); None keeps the world single-device
@@ -275,14 +334,24 @@ class GameWorld:
         move_speed: int = 30000,
         camps: int = 2,
         rng: Optional[np.random.Generator] = None,
+        spawn_camps: Optional[Dict[str, float]] = None,
     ) -> None:
         """Bulk-spawn n NPCs with randomized positions/camps — the NPC seed
         spawning of scene groups (NFCSceneAOIModule RequestEnterScene) at
-        benchmark scale."""
+        benchmark scale.  `spawn_camps` = {"camps", "zipf", "leash"}
+        places them on Zipf-sized spawn camps (`draw_camp_npcs`) and
+        leashes each walker to its own (MovementModule.set_homes);
+        without it they are scattered over the extent, as ever."""
         # the world-owned generator advances across calls — two waves must
         # not land on identical coordinates
-        pos, target, camp = draw_npcs(rng or self._rng, n,
-                                      self.config.extent, camps)
+        if spawn_camps is None:
+            pos, target, camp = draw_npcs(rng or self._rng, n,
+                                          self.config.extent, camps)
+        else:
+            pos, target, camp, centres, home = draw_camp_npcs(
+                rng or self._rng, n, self.config.extent, camps,
+                int(spawn_camps["camps"]), float(spawn_camps["zipf"]),
+                float(spawn_camps["leash"]))
         k = self.kernel
         values = {
             "SceneID": np.full(n, scene, np.int64).tolist(),
@@ -311,6 +380,11 @@ class GameWorld:
                 "MOVE_SPEED": [move_speed] * n,
             },
         )
+        if spawn_camps is not None and self.movement is not None:
+            home_rows = np.zeros(k.store.capacity("NPC"), np.int32)
+            home_rows[np.asarray(rows)] = home
+            self.movement.set_homes(centres, home_rows,
+                                    float(spawn_camps["leash"]))
         if self.combat is not None:
             self.combat.arm_all()
         if self.regen is not None:
@@ -332,13 +406,16 @@ def build_benchmark_world(
     player_capacity: int = 64,
     movement: bool = True,
     placement=None,
+    spawn_camps: Optional[Dict[str, float]] = None,
 ) -> GameWorld:
     """The staged BASELINE configs: density held at ~0.4 NPCs per world
     unit² so AOI cost scales with N, not with density.  `player_capacity`
     sizes the Player bank for served-path runs (bench.py --served seats
     one live avatar per simulated session).  `placement` (a
     parallel.SpatialPlacement over class "NPC") attaches the full-row
-    migration phase for a world that is then `.shard()`ed over a mesh."""
+    migration phase for a world that is then `.shard()`ed over a mesh.
+    `spawn_camps` = {"camps", "zipf", "leash"} stands the NPCs on
+    Zipf-sized spawn camps at the same mean density (`seed_npcs`)."""
     if extent is None:
         extent = max(64.0, float(np.sqrt(n_npcs / 0.4)))
     cap = 1 << int(np.ceil(np.log2(max(n_npcs, 64))))
@@ -357,7 +434,7 @@ def build_benchmark_world(
     )
     w.start()
     w.scene.create_scene(1, width=extent)
-    w.seed_npcs(n_npcs)
+    w.seed_npcs(n_npcs, spawn_camps=spawn_camps)
     return w
 
 

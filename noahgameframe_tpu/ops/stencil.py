@@ -33,6 +33,14 @@ candidate:
    row-gather through `slot_of` (dropped/inactive rows read the appended
    identity element).
 
+A cell far over its K slots (a spawn camp, a city) is not answered by
+a deeper grid: `build_cell_table_pair(..., spill=...)` hangs a SECOND
+LEVEL off the same sort, priced by the over-full cells.  Their rows
+beyond K get slots behind the dump slot (`_spill_slots`: streaming
+passes over the ranks) and ride the same scatters; the caller folds the
+pairs the grid's fold cannot see (game/combat.py `combat_fold_spill`)
+and `pull_slots(..., spill=...)` brings both levels back in one gather.
+
 Everything is static-shaped, jit/vmap/shard_map-friendly, and
 deterministic (stable sort + unique-index scatter + fixed fold order).
 
@@ -46,7 +54,7 @@ is BASELINE config 4).
 from __future__ import annotations
 
 import math
-from typing import Callable, NamedTuple, Tuple, TypeVar
+from typing import Callable, NamedTuple, Optional, Tuple, TypeVar
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +69,20 @@ A = TypeVar("A")
 STENCIL = [(dy, dx) for dy in (-1, 0, 1) for dx in (-1, 0, 1)]
 
 
+class CellStats(NamedTuple):
+    """What one build saw of its cells' depths: traced int32 scalars,
+    streaming reductions over the sorted keys and ranks.
+
+    hot_cells:  cells holding more members than the table's `bucket`
+    rows_max:   members of the fullest cell
+    spill_rows: members the second level placed (0 without one)
+    """
+
+    hot_cells: jnp.ndarray
+    rows_max: jnp.ndarray
+    spill_rows: jnp.ndarray
+
+
 class CellTable(NamedTuple):
     """Sorted cell-dense payload table.
 
@@ -72,6 +94,13 @@ class CellTable(NamedTuple):
              (== n_cells*K) for rows not placed.
     dropped: scalar int32 — active entities that overflowed their cell.
     width, cell_size, bucket: static grid geometry.
+
+    A table built with a SECOND LEVEL (`build_cell_table_pair(...,
+    spill=...)`) carries, behind the dump slot, `spill_cells` rows of
+    `spill_bucket` slots: the rows the first `spill_cells` over-full
+    cells (in cell order) hold beyond `bucket`, in row order.  A member
+    placed there has `slot_of = n_cells*K + 1 + hot*spill_bucket + j`;
+    `spill_view` is that part, `grid_view` stays the base level.
     """
 
     payload: jnp.ndarray
@@ -84,13 +113,27 @@ class CellTable(NamedTuple):
     # means square (height == width).  Trailing default keeps the many
     # existing 6-field positional constructions valid.
     height: int = -1
+    spill_cells: int = 0
+    spill_bucket: int = 0
+    stats: Optional[CellStats] = None
+
+    @property
+    def n_cells(self) -> int:
+        return (self.height if self.height > 0 else self.width) * self.width
 
     def grid_view(self) -> jnp.ndarray:
-        """[H, W, K, F+1] dense view (dump slot excluded)."""
+        """[H, W, K, F+1] dense view (dump slot and second level
+        excluded)."""
         h = self.height if self.height > 0 else self.width
         w = self.width
         k = self.bucket
-        return self.payload[:-1].reshape(h, w, k, self.payload.shape[-1])
+        return self.payload[: h * w * k].reshape(
+            h, w, k, self.payload.shape[-1])
+
+    def spill_view(self) -> jnp.ndarray:
+        """[spill_cells, spill_bucket, F+1]: the second level's rows."""
+        return self.payload[self.n_cells * self.bucket + 1:].reshape(
+            self.spill_cells, self.spill_bucket, self.payload.shape[-1])
 
 
 def auto_bucket(
@@ -167,6 +210,35 @@ def _sorted_slots(n_cells: int, skey, rank, bucket: int) -> jnp.ndarray:
     return jnp.where(placed, skey * bucket + rank, dump)
 
 
+def _spill_slots(
+    n_cells: int, skey, rank, bucket: int, cells: int, depth: int
+) -> Tuple[jnp.ndarray, CellStats]:
+    """`_sorted_slots` with a second level, and what the build saw.
+
+    Streaming passes over the sorted keys and ranks, nothing irregular.
+    A cell is over-full when it holds more than `bucket` members; its
+    member of rank `bucket` heads its overflow, and the running count of
+    heads numbers the over-full cells in cell order.  A member of rank
+    `bucket + j` in the `hot`-th over-full cell is placed in the second
+    level (slot `dump + 1 + hot * depth + j`) when `hot < cells` and
+    `j < depth`, and in the dump slot otherwise: so what is dropped is
+    still the highest rows of a cell, whatever level the others sit in.
+    With `cells == 0` the slots are `_sorted_slots`' own."""
+    i32 = jnp.int32
+    valid = skey < n_cells
+    head = valid & (rank == bucket)
+    slots = _sorted_slots(n_cells, skey, rank, bucket)
+    hot_cells = jnp.sum(head, dtype=i32)
+    rows_max = jnp.max(jnp.where(valid, rank + 1, 0))
+    if cells <= 0 or depth <= 0:
+        return slots, CellStats(hot_cells, rows_max, jnp.zeros((), i32))
+    hot = jnp.cumsum(head.astype(i32)) - 1
+    j = rank - bucket
+    placed = valid & (j >= 0) & (hot < cells) & (j < depth)
+    slots = jnp.where(placed, n_cells * bucket + 1 + hot * depth + j, slots)
+    return slots, CellStats(hot_cells, rows_max, jnp.sum(placed, dtype=i32))
+
+
 def _slots_from_ranks(
     n: int, n_cells: int, order, skey, rank, bucket: int
 ) -> jnp.ndarray:
@@ -191,12 +263,15 @@ def sub_chunks(sub_mask: jnp.ndarray, sub_rows: int) -> jnp.ndarray:
 
 
 def _chunked_payload(
-    features, order, flat_sorted, n_chunks, dump: int, sub_rows: int
+    features, order, flat_sorted, n_chunks, dump: int, sub_rows: int,
+    spill_slots: int = 0,
 ) -> jnp.ndarray:
     """Payload table of a COMPACTED sorted list (members first): gather
     and scatter `sub_rows` sorted entries a chunk, `n_chunks` chunks.
     An entry past the members carries the dump slot, so a chunk that
-    reaches beyond them writes nothing that stays.
+    reaches beyond them writes nothing that stays.  `spill_slots` more
+    slots lie behind the dump slot (the second level: a member placed
+    there rides the same chunk, gathered once, scattered once).
 
     The first chunk is a static slice and always goes: it is the whole
     job whenever `sub_rows` was sized for the subset.  Further chunks
@@ -220,7 +295,7 @@ def _chunked_payload(
         return put(payload, order[at], flat_sorted[at])
 
     payload = put(
-        jnp.zeros((dump + 1, f + 1), features.dtype),
+        jnp.zeros((dump + 1 + spill_slots, f + 1), features.dtype),
         order[:sub_rows], flat_sorted[:sub_rows],
     )
     payload = jax.lax.fori_loop(1, n_chunks, one_chunk, payload)
@@ -231,6 +306,7 @@ def _chunked_payload(
 def table_from_slots(
     features, active, slot_of, n_cells: int,
     cell_size: float, width: int, bucket: int, height: int = -1,
+    spill: Tuple[int, int] = (0, 0), stats: Optional[CellStats] = None,
 ) -> CellTable:
     """Materialize a CellTable from a PRECOMPUTED slot assignment: ONE
     deterministic payload scatter (unique slot indices for placed rows),
@@ -239,21 +315,28 @@ def table_from_slots(
     against the cached `slot_of` while skipping the argsort entirely.
     Rows not `active` are forced to the dump slot regardless of their
     cached assignment (a cache is only reused while the active set is
-    unchanged, but a zero-initialized cache must stay harmless)."""
+    unchanged, but a zero-initialized cache must stay harmless).
+
+    `spill = (cells, depth)`: that many second-level slots lie behind
+    the dump slot, and `slot_of` may name them (`_spill_slots`): a row
+    placed there rides the same scatter, at no row more."""
     n = features.shape[0]
     dump = n_cells * bucket
+    spill_cells, spill_bucket = spill
     slot_of = jnp.where(active, slot_of, dump)
     occ = jnp.ones((n, 1), features.dtype)
     feats = jnp.concatenate([features, occ], axis=-1)
     payload = (
-        jnp.zeros((dump + 1, feats.shape[-1]), features.dtype)
+        jnp.zeros((dump + 1 + spill_cells * spill_bucket, feats.shape[-1]),
+                  features.dtype)
         .at[slot_of]
         .set(feats)
     )
     # dump slot may have been written by any loser; force it empty
     payload = payload.at[dump].set(0.0)
     dropped = jnp.sum(active & (slot_of == dump), dtype=jnp.int32)
-    return CellTable(payload, slot_of, dropped, width, cell_size, bucket, height)
+    return CellTable(payload, slot_of, dropped, width, cell_size, bucket,
+                     height, spill_cells, spill_bucket, stats)
 
 
 def build_cell_table(
@@ -294,6 +377,7 @@ def build_cell_table_pair(
     cell: jnp.ndarray | None = None,
     height: int = -1,
     sub_rows: int | None = None,
+    spill: Tuple[int, int, int] = (0, 0, 0),
 ) -> Tuple[CellTable, CellTable]:
     """Build the full table AND a subset table from ONE key pass: two
     sorts (the whole population, then the subset's keys), both tables
@@ -316,6 +400,17 @@ def build_cell_table_pair(
     cell/height: precomputed cell ids over a rectangular [height, width]
     grid (spatial slab shards); default square grid derived from pos.
 
+    `spill = (cells, depth, sub_depth)`: a SECOND LEVEL behind both
+    tables, priced by the over-full cells and not by the grid.  The
+    first `cells` over-full cells of either table (in cell order, each
+    table counting its own) keep `depth` (`sub_depth`) members beyond
+    the bucket, in row order; `_spill_slots` makes their slots from the
+    ranks this build has anyway, and they ride the scatters it makes
+    anyway, into rows behind each table's dump slot.  What fits neither
+    level is dropped and counted as before.  (0, 0, 0) is the one-level
+    build, the same program but for the reductions of `CellTable.stats`
+    (both tables carry them either way).
+
     The one call here that both ranks and builds, and only combat makes
     it, so it opens the device scopes `nf.aoe.rank` (sorts, heads, ranks,
     slots) and `nf.aoe.table` (the victim scatter, the subset's chunk
@@ -330,16 +425,24 @@ def build_cell_table_pair(
             pos, active, cell_size, width, cell=cell,
             n_cells=(n_rows * width if cell is not None else None),
         )
+        spill_cells, spill_bucket, sub_spill_bucket = spill
+        if spill_cells and (spill_bucket <= 0 or sub_spill_bucket <= 0):
+            raise ValueError(f"second level {spill}: a depth of 0")
         order, skey, rank = _key_segments(key)
-        slot_of = _slots_from_ranks(n, n_cells, order, skey, rank, bucket)
+        sorted_slots, stats = _spill_slots(
+            n_cells, skey, rank, bucket, spill_cells, spill_bucket)
+        # un-sort back to row order (one scatter)
+        slot_of = jnp.full((n,), n_cells * bucket, jnp.int32).at[order].set(
+            sorted_slots)
         # the subset, compacted by a second sort: members first, in cell
         # order, rows ascending inside a cell — the order their ranks
         # count in.  Heads, ranks and slots are streaming passes over
         # that list; nothing here gathers or scatters by row.
         sub_key = jnp.where(sub_mask, key, n_cells)
         sub_order, sub_skey, sub_rank = _key_segments(sub_key)
-        sub_sorted_slots = _sorted_slots(
-            n_cells, sub_skey, sub_rank, sub_bucket)
+        sub_sorted_slots, sub_stats = _spill_slots(
+            n_cells, sub_skey, sub_rank, sub_bucket, spill_cells,
+            sub_spill_bucket)
         sub_dump = n_cells * sub_bucket
         sub_dropped = (
             jnp.sum(sub_mask, dtype=jnp.int32)
@@ -349,20 +452,20 @@ def build_cell_table_pair(
         # per-row slots keep their contract for the callers that pull
         # through them (one un-sort scatter); the combat fold does not,
         # and the compiler drops the scatter from the tick program
-        sub_slot_of = _slots_from_ranks(
-            n, n_cells, sub_order, sub_skey, sub_rank, sub_bucket)
+        sub_slot_of = jnp.full((n,), sub_dump, jnp.int32).at[sub_order].set(
+            sub_sorted_slots)
     with jax.named_scope("nf.aoe.table"):
         full = table_from_slots(
             features, active, slot_of, n_cells, cell_size, width, bucket,
-            height,
+            height, (spill_cells, spill_bucket), stats,
         )
         sub_payload = _chunked_payload(
             sub_features, sub_order, sub_sorted_slots, n_chunks, sub_dump,
-            sub_rows,
+            sub_rows, spill_cells * sub_spill_bucket,
         )
     sub = CellTable(
         sub_payload, sub_slot_of, sub_dropped, width, cell_size, sub_bucket,
-        height,
+        height, spill_cells, sub_spill_bucket, sub_stats,
     )
     return full, sub
 
@@ -394,6 +497,7 @@ def stencil_fold(
 def pull_slots(
     slot_of: jnp.ndarray, values: jnp.ndarray,
     fill: float | Tuple[float, ...] = 0.0,
+    spill: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Map per-slot results [H, W, K] or [H, W, K, V] back to rows [N] /
     [N, V] with one gather through a raw slot array; unplaced rows (dump
@@ -407,7 +511,10 @@ def pull_slots(
     fill_row = jnp.broadcast_to(
         jnp.asarray(fill, values.dtype).reshape(-1), (nv,)
     )
-    flat = jnp.concatenate([flat, fill_row[None, :]], axis=0)
+    parts = [flat, fill_row[None, :]]
+    if spill is not None:
+        parts.append(spill.reshape(-1, nv).astype(values.dtype))
+    flat = jnp.concatenate(parts, axis=0)
     out = flat[slot_of]
     return out[..., 0] if squeeze else out
 

@@ -219,13 +219,17 @@ def _kernel(vic_ref, top_ref, mid_ref, bot_ref, out_ref, *, w: int, r2: float):
     out_ref[0, 2] = bestr
 
 
-def combat_fold_pallas(vic_table, att_table, radius: float, interpret: bool = False):
+def combat_fold_pallas(vic_table, att_table, radius: float,
+                       interpret: bool = False, raw: bool = False):
     """Fused 3x3 stencil fold: victims resident, attackers scanned.
 
     vic_table / att_table: ops.stencil.CellTable over the SAME grid
     geometry (vic carries 5 feature cols, att 7 — see module docstring).
     Returns (inc [H, W, Kv] int32, bestr [H, W, Kv] int32), matching the
-    XLA fold's outputs before `pull`."""
+    XLA fold's outputs before `pull`; with `raw`, the accumulators as
+    the fold carries them (inc, besta f32, bestr f32 with _NO_ROW for
+    none), for the second level to fold on into (game/combat.py
+    `combat_fold_spill`)."""
     width = vic_table.width
     assert att_table.width == width and att_table.cell_size == vic_table.cell_size
     vic = _planes(vic_table.payload, width, vic_table.bucket, N_VFEATS,
@@ -253,6 +257,10 @@ def combat_fold_pallas(vic_table, att_table, radius: float, interpret: bool = Fa
         out[:, 0].transpose(0, 2, 1), jnp.int32
     )  # [H, W, Kv]
     bestr_f = out[:, 2].transpose(0, 2, 1)
+    if raw:
+        k = vic_table.bucket
+        besta = out[:, 1].transpose(0, 2, 1)
+        return inc[..., :k], besta[..., :k], bestr_f[..., :k]
     # _NO_ROW (no attacker) -> -1; row ids are exact in f32 (< 2^24)
     bestr = jnp.where(bestr_f >= _NO_ROW, -1.0, bestr_f).astype(jnp.int32)
     if kv > vic_table.bucket:
@@ -263,7 +271,8 @@ def combat_fold_pallas(vic_table, att_table, radius: float, interpret: bool = Fa
 
 def _planes(payload: jnp.ndarray, width: int, bucket: int, n_feats: int,
             pad: bool) -> jnp.ndarray:
-    """CellTable payload [(H*W*K)+1, F+1] -> feature planes.
+    """CellTable payload [(H*W*K)+1(+second level), F+1] -> feature
+    planes of the base level.
 
     pad=True (attacker side) adds the one-cell zero border the shifted
     reads need: [H+2, F, K, W+2]; border slots are all-zero => eff_atk 0
@@ -274,7 +283,7 @@ def _planes(payload: jnp.ndarray, width: int, bucket: int, n_feats: int,
     zero-slot victims never map back through `pull`)."""
     h = w = width
     k = bucket
-    v = payload[:-1, :n_feats].reshape(h, w, k, n_feats)
+    v = payload[: h * w * k, :n_feats].reshape(h, w, k, n_feats)
     planes = v.transpose(0, 3, 2, 1)  # [H, F, K, W]
     k_pad = (-k) % 8
     if pad:

@@ -45,16 +45,33 @@ def test_auto_resize_stops_the_drops():
     w = crowded_world(auto_resize=True)
     c = w.combat
     c.max_bucket_boost = 64  # enough headroom for 32 piled into bucket 1
+    c.SPILL_MIN_OVERDEPTH = 1 << 20  # the doubling alone (no second level)
     for _ in range(20):
         w.tick()
         if c._bucket_boost >= 32:
             break
     assert c.overflow_alerts >= 1
     assert c._bucket_boost >= 32  # grew until the pile-up fits
+    assert c.resolved_spill(64) == (0, 0, 0)
     # the boosted bucket holds all 32 entities: drops actually STOP
     w.tick()
     w.tick()
     assert c.overflow_last == (0, 0)
+
+
+def test_one_deep_cell_gets_the_second_level_and_the_drops_stop():
+    """32 piled into a cell of depth 1 are one cell 32 times over: the
+    breach sizes the second level for it, the grid keeps its depth, and
+    one retrace later nothing is dropped."""
+    w = crowded_world(auto_resize=True)
+    c = w.combat
+    for _ in range(4):
+        w.tick()
+    assert c.overflow_alerts == 1 and c._bucket_boost == 1
+    cells, depth, att_depth = c.resolved_spill(64)
+    assert cells >= 1 and depth >= 31 and att_depth >= 8
+    assert c.overflow_last == (0, 0)
+    assert w.kernel.last_counters["aoe_spill_rows"] >= 31
 
 
 def test_no_overflow_no_alert():

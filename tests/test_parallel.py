@@ -278,7 +278,8 @@ def test_sharded_kernel_drops_traces_on_invalidate(world):
 
 def test_sharded_combat_overflow_resize_takes_effect():
     """End to end under the mesh: everyone piled into one cell with a
-    bucket of 1 overflows; CombatModule doubles the bucket + invalidates,
+    bucket of 1 overflows; CombatModule doubles the bucket + invalidates
+    (the doubling alone: the second level is held off here),
     the generation sync retraces the SHARDED tick, and the drops stop —
     the r05 capture showed grid_overflow_max=374 silently dropped because
     the old mesh kept its stale trace."""
@@ -298,6 +299,7 @@ def test_sharded_combat_overflow_resize_takes_effect():
     c = w.combat
     assert c.auto_resize
     c.max_bucket_boost = 64  # headroom for 32 piled into bucket 1
+    c.SPILL_MIN_OVERDEPTH = 1 << 20
     sk = ShardedKernel(k, n_devices=N_DEV)
     sk.place()
     for _ in range(20):

@@ -299,3 +299,45 @@ def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch):
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+def test_siege_tick_with_its_second_level_compiles(one_chip, monkeypatch):
+    """`kernel.step` of `siege-zipf-1m` as its window runs it (32/12 and
+    a second level of 8,192 hot cells, 512 victims and 32 attackers
+    deep, walkers with homes), traced for the chip: the base fold stays
+    the kernel, the second level's loops are in the program, and it fits
+    the chip beside the world."""
+    from noahgameframe_tpu.game import GameWorld, WorldConfig
+
+    monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
+    extent = float(np.sqrt(1_000_000 / 0.4))
+    w = GameWorld(WorldConfig(npc_capacity=128, extent=extent, seed=0,
+                              middleware=False))
+    w.start()
+    w.scene.create_scene(1, width=extent)
+    k, combat = w.kernel, w.combat
+    w.movement.set_homes(np.zeros((4096, 2), np.float32),
+                         np.zeros(128, np.int32), 64.0)
+    k._ensure_aux()
+    combat._attacker_duty = 1.0 / 30.0
+    combat._bucket_boost = 2
+    combat._spill = (8192, 512, 32)
+    cap = 1 << 20
+    assert combat.resolved_spill(cap) == (8192, 512, 32)
+
+    def described(x):
+        return jax.ShapeDtypeStruct((cap,) + x.shape[1:], x.dtype,
+                                    sharding=one_chip)
+
+    state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
+    state = state.replace(
+        classes={**state.classes,
+                 "NPC": jax.tree.map(described, state.classes["NPC"])},
+        aux={name: jax.tree.map(described, leaf)
+             for name, leaf in state.aux.items()})
+    compiled = jax.jit(k._trace_step, donate_argnums=0).lower(state).compile()
+    text = compiled.as_text()
+    assert combat.engine_baked == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "nf.aoe.spill/while" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
