@@ -772,6 +772,8 @@ class CombatModule(Module):
             ctx.count("grid_rebuilds", rebuilt)
             ctx.count("grid_reuses", 1 - rebuilt)
             ctx.count("grid_cache_age", cache.age)
+            # the replay scatters rows to cached slots and gathers none
+            ctx.count("aoe_victim_slots_built", 0)
             state = state.replace(aux={**state.aux, aux_key: cache})
         else:
             # one key pass feeds both tables (attackers subset of alive);
@@ -797,6 +799,9 @@ class CombatModule(Module):
             chunks = sub_chunks(attacking, att_rows)
             ctx.count("aoe_attacker_chunks", chunks)
             ctx.count("aoe_attacker_rows_sent", chunks * att_rows)
+            # what the victim table is priced by: every slot of both
+            # levels is gathered from the sorted list, no row is sent
+            ctx.count("aoe_victim_slots_built", vic_bin.payload.shape[0] - 1)
         spilling = vic_bin.spill_cells > 0
         with jax.named_scope("nf.aoe.fold"):
             if engine == 1:

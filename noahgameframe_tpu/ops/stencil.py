@@ -18,12 +18,19 @@ candidate:
    slots and ONE payload scatter (unique slot indices, deterministic),
    and remembers each row's slot (`slot_of`).  Entities beyond a cell's
    K slots land in the dump slot and are counted in `dropped` — size K
-   from `auto_bucket` to keep that ~zero.  `build_cell_table_pair` adds
-   a SUBSET table (combat: this tick's attackers) whose irregular passes
-   are priced by the subset, not by the bank: a second sort compacts the
-   members to the front in cell order, their ranks and slots are
-   streaming passes over that list, and only `sub_rows`-sized chunks of
-   it are gathered and scattered.
+   from `auto_bucket` to keep that ~zero.  `build_cell_table_pair`, the
+   build every tick makes, SENDS NO ROW to its full table: a scatter on
+   a v5e costs 85 ns for every row sent (2^20 of them a tick, 89 ms),
+   a gather ~5 ns for every index followed.  After the sort a cell's
+   members are one run of the sorted list, in the order its slots hold
+   them, so the payload is GATHERED slot by slot from the sorted
+   features (`_cell_starts`, `_slot_sources`, `table_from_sorted`) and
+   only `slot_of`, which the pull reads, is still un-sorted by a
+   scatter.  It also adds a SUBSET table (combat: this tick's
+   attackers) whose irregular passes are priced by the subset, not by
+   the bank: a second sort compacts the members to the front in cell
+   order, their ranks and slots are streaming passes over that list,
+   and only `sub_rows`-sized chunks of it are gathered and scattered.
 2. `stencil_fold` walks the 3x3 neighborhood as NINE DENSE SHIFTS of the
    [H, W, K, F] grid view (one pad + nine fused slices — no index math,
    no gathers).  The caller folds candidate blocks against the resident
@@ -37,12 +44,15 @@ A cell far over its K slots (a spawn camp, a city) is not answered by
 a deeper grid: `build_cell_table_pair(..., spill=...)` hangs a SECOND
 LEVEL off the same sort, priced by the over-full cells.  Their rows
 beyond K get slots behind the dump slot (`_spill_slots`: streaming
-passes over the ranks) and ride the same scatters; the caller folds the
-pairs the grid's fold cannot see (game/combat.py `combat_fold_spill`)
-and `pull_slots(..., spill=...)` brings both levels back in one gather.
+passes over the ranks), filled by the same gather (victims) and the
+same chunked scatter (attackers) as the base level's; the caller folds
+the pairs the grid's fold cannot see (game/combat.py
+`combat_fold_spill`) and `pull_slots(..., spill=...)` brings both
+levels back in one gather.
 
 Everything is static-shaped, jit/vmap/shard_map-friendly, and
-deterministic (stable sort + unique-index scatter + fixed fold order).
+deterministic (stable sort, gathers, unique-index scatters, fixed fold
+order).
 
 Reference parity note: this implements the spatial layer behind the
 "AOI" broadcast of NFCSceneAOIModule (the reference's own AOI is
@@ -239,6 +249,132 @@ def _spill_slots(
     return slots, CellStats(hot_cells, rows_max, jnp.sum(placed, dtype=i32))
 
 
+def _cell_starts(n_cells: int, skey: jnp.ndarray) -> jnp.ndarray:
+    """`start[c]`, c in 0..n_cells: the sorted index of cell c's first
+    member, that is the number of sorted keys below c (so `start[c + 1] -
+    start[c]` members, and `start[n_cells]` placed keys in all).
+
+    No pass here is priced by the bank's rows but a sort: the heads of
+    the runs are compacted to the front by a third sort (1 ms at 2^20
+    keys on a v5e, where a scatter of as many single words is 4.85), the
+    first `n_cells` of them are scattered into the table by their cell,
+    and a reverse running minimum gives an empty cell the start of the
+    next cell that has a member."""
+    n = skey.shape[0]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    valid = skey < n_cells
+    n_valid = jnp.sum(valid, dtype=jnp.int32)
+    head = valid & jnp.concatenate(
+        [jnp.ones((1,), bool), skey[1:] != skey[:-1]])
+    # a head's key is its cell and no other head's; everything else
+    # sorts behind them and writes `n_valid` where `n_valid` stands
+    hcell, hidx = jax.lax.sort(
+        (jnp.where(head, skey, n_cells), idx), num_keys=1, is_stable=False)
+    m = min(n, n_cells)
+    hcell, hidx = hcell[:m], hidx[:m]
+    starts = jnp.full((n_cells + 1,), n_valid, jnp.int32).at[hcell].set(
+        jnp.where(hcell < n_cells, hidx, n_valid))
+    return jax.lax.cummin(starts, reverse=True)
+
+
+def _slot_sources(
+    start: jnp.ndarray, n_cells: int, bucket: int, cells: int, depth: int
+) -> list:
+    """Where the slots of the full table find their rows in the sorted
+    list, a level at a time: `(first, count, depth)`, slot `j` of the
+    level's cell `i` holding sorted entry `first[i] + j` while `j <
+    count[i]`, and zeros otherwise.  The members of a cell are one run
+    of the sorted list, rows ascending, which is the order its slots
+    hold them in: so the base level reads `start[c]` onwards, and the
+    second level (`_spill_slots`' numbering: the `hot`-th over-full cell
+    in cell order) reads `start[c] + bucket` onwards of that cell `c`,
+    found by a search over the running count of over-full cells, `cells`
+    queries: nothing here is priced by the bank."""
+    i32 = jnp.int32
+    count = start[1:] - start[:-1]
+    levels = [(start[:-1], count, bucket)]
+    if cells > 0 and depth > 0:
+        hot_no = jnp.cumsum((count > bucket).astype(i32))
+        cell_at = jnp.searchsorted(
+            hot_no, jnp.arange(1, cells + 1, dtype=i32), side="left",
+            method="scan_unrolled").astype(i32)
+        there = cell_at < n_cells
+        cell_at = jnp.minimum(cell_at, n_cells - 1)
+        levels.append((
+            start[cell_at] + bucket,
+            jnp.where(there, count[cell_at] - bucket, 0), depth))
+    return levels
+
+
+# a run of the run table: this many sorted entries, in one lane tile
+RUN_ENTRIES = 16
+RUN_WORDS = 128
+
+
+def run_length(depth: int, n_feats: int) -> int:
+    """Consecutive slots of a cell that `table_from_sorted` fills from
+    one gathered row: `RUN_ENTRIES` where that divides the cell's
+    `depth` and a run of `n_feats`-word entries fits a lane tile, else 1
+    (a slot a gathered row).  Read on the chip, in the tick (PERF.md §6
+    PR 31): at 32 deep runs of 16 take the 1M tick from 94.2 ms to 74.2
+    where runs of 4 and 8 read 90.5 and 99.3 (a run that fills under
+    half a lane tile costs more to lay out than its fewer indices save);
+    at 20 deep under `vmap` runs of 4, 5 and 10 read 63.0, 74.1 and 64.7
+    against 59.6 row by row."""
+    if depth % RUN_ENTRIES == 0 and RUN_ENTRIES * n_feats <= RUN_WORDS:
+        return RUN_ENTRIES
+    return 1
+
+
+def table_from_sorted(
+    features: jnp.ndarray, order: jnp.ndarray, levels: list
+) -> jnp.ndarray:
+    """Payload `[slots, F + 1]` GATHERED from the sorted list, no row
+    sent anywhere.  Bit for bit what `table_from_slots` scatters from
+    the same assignment; the dump slot (behind the first level) is a row
+    of zeros by construction.
+
+    A gather on a v5e costs ~5 ns for every index it follows when the
+    row behind it is five words (16.8 ns when it is one:
+    `scripts/scatter_probe.py`), a scatter 85 ns for every row it sends.
+    So the features are gathered once into sorted order
+    (`features[order]`) and laid out as RUNS: row `i` of the run table
+    holds sorted entries `i .. i + g - 1`, feature by feature, `g` from
+    `run_length`.  A cell's slots are consecutive entries
+    (`_slot_sources`), so one gathered row fills `g` of them and a
+    feature's plane of the table is a slice of lanes: `depth / g`
+    indices a cell where the scatter sent a row a member.  With `g` 1
+    the run table is the sorted features themselves."""
+    n, f = features.shape
+    dtype = features.dtype
+    feats = features[order]
+    runs = {}
+
+    def run_table(g):
+        if g not in runs:
+            padded = jnp.pad(feats, ((0, g - 1), (0, 0)))
+            runs[g] = jnp.stack(
+                [padded[k:k + n, i] for i in range(f) for k in range(g)],
+                axis=-1)
+        return runs[g]
+
+    planes = [[] for _ in range(f + 1)]
+    for level, (first, count, depth) in enumerate(levels):
+        g = run_length(depth, f)
+        heads = first[:, None] + g * jnp.arange(depth // g, dtype=jnp.int32)
+        got = run_table(g)[jnp.minimum(heads, n - 1)]
+        live = jnp.arange(depth, dtype=jnp.int32) < count[:, None]
+        for i in range(f):
+            plane = got[..., i * g:(i + 1) * g].reshape(live.shape)
+            planes[i].append(
+                jnp.where(live, plane, jnp.zeros((), dtype)).reshape(-1))
+        planes[f].append(live.astype(dtype).reshape(-1))
+        if level == 0:  # the dump slot
+            for plane in planes:
+                plane.append(jnp.zeros((1,), dtype))
+    return jnp.stack([jnp.concatenate(p) for p in planes], axis=-1)
+
+
 def _slots_from_ranks(
     n: int, n_cells: int, order, skey, rank, bucket: int
 ) -> jnp.ndarray:
@@ -312,7 +448,10 @@ def table_from_slots(
     deterministic payload scatter (unique slot indices for placed rows),
     dump-slot zeroing, drop count.  This is the sort-free half of the
     build — the Verlet cache (ops/verlet.py) replays it every reuse tick
-    against the cached `slot_of` while skipping the argsort entirely.
+    against the cached `slot_of` while skipping the argsort entirely,
+    and `build_cell_table` (the interest programs, tables 24 times
+    smaller than the 1M tick's) ends in it.  Where the sorted list is at
+    hand, `table_from_sorted` makes the same payload with no row sent.
     Rows not `active` are forced to the dump slot regardless of their
     cached assignment (a cache is only reused while the active set is
     unchanged, but a zero-initialized cache must stay harmless).
@@ -381,14 +520,19 @@ def build_cell_table_pair(
 ) -> Tuple[CellTable, CellTable]:
     """Build the full table AND a subset table from ONE key pass: two
     sorts (the whole population, then the subset's keys), both tables
-    ranked from the sorted keys.
+    ranked from the sorted keys, and a third sort that finds where each
+    cell's run starts.
 
     `sub_mask` must be a subset of `active` (combat: attackers among all
     alive entities).  Placement is bit-identical to two independent
     `build_cell_table` calls — within a cell both tables hold rows in
     ascending order, and the subset ranks are the subset's own ordinal
-    positions.  What differs is the price: the subset's irregular passes
-    go by its members, not by the bank.  The second sort (1 ms at 2^20
+    positions.  What differs is the price.  The full table is priced by
+    its slots and not by the bank's rows: its payload is gathered from
+    the sorted list (`table_from_sorted`; nothing is scattered but
+    `slot_of`, which the pull reads), bit for bit what
+    `table_from_slots` would scatter to the same slots.  The subset's
+    irregular passes go by its members: the second sort (1 ms at 2^20
     rows on a v5e, where one N-row gather or scatter is 5-40) puts the
     members first, so their features are gathered and their payload rows
     scattered `sub_rows` sorted entries at a time, ceil(members /
@@ -405,18 +549,19 @@ def build_cell_table_pair(
     first `cells` over-full cells of either table (in cell order, each
     table counting its own) keep `depth` (`sub_depth`) members beyond
     the bucket, in row order; `_spill_slots` makes their slots from the
-    ranks this build has anyway, and they ride the scatters it makes
-    anyway, into rows behind each table's dump slot.  What fits neither
-    level is dropped and counted as before.  (0, 0, 0) is the one-level
-    build, the same program but for the reductions of `CellTable.stats`
-    (both tables carry them either way).
+    ranks this build has anyway, and the rows behind each table's dump
+    slot are filled by the gather (full table: `_slot_sources` finds the
+    over-full cells' runs) and the chunked scatter (subset) it makes
+    anyway.  What fits neither level is dropped and counted as before.
+    (0, 0, 0) is the one-level build, the same program but for the
+    reductions of `CellTable.stats` (both tables carry them either way).
 
     The one call here that both ranks and builds, and only combat makes
     it, so it opens the device scopes `nf.aoe.rank` (sorts, heads, ranks,
-    slots) and `nf.aoe.table` (the victim scatter, the subset's chunk
-    gather and scatter) itself; every other scope of the neighbour
-    engine is opened by the caller (game/combat.py), because the
-    interest programs share this file."""
+    slots, the cells' starts) and `nf.aoe.table` (the victim gathers, the
+    subset's chunk gather and scatter) itself; every other scope of the
+    neighbour engine is opened by the caller (game/combat.py), because
+    the interest programs share this file."""
     n_rows = height if height > 0 else width
     n = pos.shape[0]
     sub_rows = n if sub_rows is None else max(1, min(sub_rows, n))
@@ -431,9 +576,18 @@ def build_cell_table_pair(
         order, skey, rank = _key_segments(key)
         sorted_slots, stats = _spill_slots(
             n_cells, skey, rank, bucket, spill_cells, spill_bucket)
-        # un-sort back to row order (one scatter)
-        slot_of = jnp.full((n,), n_cells * bucket, jnp.int32).at[order].set(
-            sorted_slots)
+        # un-sort back to row order (one scatter): what the pull reads
+        dump = n_cells * bucket
+        slot_of = jnp.full((n,), dump, jnp.int32).at[order].set(sorted_slots)
+        dropped = (
+            jnp.sum(active, dtype=jnp.int32)
+            - jnp.sum(sorted_slots != dump, dtype=jnp.int32)
+        )
+        # where every slot of the full table finds its row in the sorted
+        # list: a third sort, a scatter of the cells' heads, streaming
+        levels = _slot_sources(
+            _cell_starts(n_cells, skey), n_cells, bucket, spill_cells,
+            spill_bucket)
         # the subset, compacted by a second sort: members first, in cell
         # order, rows ascending inside a cell — the order their ranks
         # count in.  Heads, ranks and slots are streaming passes over
@@ -455,9 +609,10 @@ def build_cell_table_pair(
         sub_slot_of = jnp.full((n,), sub_dump, jnp.int32).at[sub_order].set(
             sub_sorted_slots)
     with jax.named_scope("nf.aoe.table"):
-        full = table_from_slots(
-            features, active, slot_of, n_cells, cell_size, width, bucket,
-            height, (spill_cells, spill_bucket), stats,
+        full = CellTable(
+            table_from_sorted(features, order, levels), slot_of, dropped,
+            width, cell_size, bucket, height, spill_cells, spill_bucket,
+            stats,
         )
         sub_payload = _chunked_payload(
             sub_features, sub_order, sub_sorted_slots, n_chunks, sub_dump,

@@ -7,7 +7,10 @@ slot_of and dropped, including WHICH rows overflow to the dump slot) to
 (stable argsort by key, ordinal in run, `cell * bucket + rank` or the
 dump slot).  The pair build's attacker side is chunked (`sub_rows`), so
 every shape of input runs as one whole-bank chunk, as many small chunks
-and, in the matrix, as chunks sized for the subset.  At the end, the
+and, in the matrix, as chunks sized for the subset.  The pair build's
+full table is gathered from the sorted list (PR 31) and sends no row: it
+is held, both levels, to `table_from_slots` scattering the same rows to
+the same slots, alone, under `vmap` and under `scan`.  At the end, the
 guard rails: what the key pass refuses, and a census of the `NF_*` names
 the package reads from the environment."""
 
@@ -22,6 +25,7 @@ import pytest
 from noahgameframe_tpu.ops.stencil import (
     build_cell_table,
     build_cell_table_pair,
+    table_from_slots,
 )
 from noahgameframe_tpu.ops.verlet import (
     full_table,
@@ -204,6 +208,165 @@ def test_fuzz_overflow_sweep(sub_rows):
                                     sub_rows=sub_rows)
         _assert_pair(got, np_cells(case[0], 4.0, width), case,
                      width * width, bucket, sub_bucket, f"fuzz seed={seed}")
+
+
+# ------------------------- the gathered table against the scattered one
+
+def _one_cell(n=300, cell=4.0):
+    rng = np.random.default_rng(13)
+    feats = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
+    pos = jnp.broadcast_to(jnp.float32([cell * 2.5, cell * 2.5]), (n, 2))
+    return (pos, jnp.ones(n, bool), feats,
+            jnp.asarray(rng.random(n) < 0.4), feats[:, :1])
+
+
+def _packed(n=300, width=8, cell=4.0, share=0.5, at=5.5):
+    rng = np.random.default_rng(13)
+    feats = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
+    pos = jnp.asarray(
+        rng.uniform(0, width * cell, (n, 2)).astype(np.float32)
+    ).at[: int(n * share)].set(jnp.float32([cell * at, cell * at]))
+    return (pos, jnp.ones(n, bool), feats,
+            jnp.asarray(rng.random(n) < 0.4), feats[:, :1])
+
+
+def _fuzz(seed):
+    rng = np.random.default_rng(100 + seed)
+    n = int(rng.integers(16, 400))
+    width = int(rng.integers(2, 10))
+    bucket = int(rng.integers(1, 6))
+    case = _case(seed, n, width, 4.0,
+                 p_active=float(rng.uniform(0.1, 1.0)),
+                 p_sub=float(rng.uniform(0.0, 1.0)))
+    return case, width, bucket
+
+
+# name -> (case, width, bucket, spill); the square grid of 4.0-unit cells
+GATHERED = {
+    "all_inactive": (_case(11, 200, 8, 4.0, p_active=0.0), 8, 8, (0, 0, 0)),
+    "all_active": (_case(11, 200, 8, 4.0, p_active=1.0, p_sub=1.0), 8, 8,
+                   (0, 0, 0)),
+    "sub_empty": (_case(11, 200, 8, 4.0, p_sub=0.0), 8, 8, (0, 0, 0)),
+    "one_cell": (_one_cell(), 8, 8, (0, 0, 0)),
+    "one_overfull_cell": (_packed(), 8, 8, (0, 0, 0)),
+    "fewer_rows_than_cells": (_case(19, 40, 8, 4.0), 8, 4, (0, 0, 0)),
+    **{f"fuzz{seed}": (*_fuzz(seed), (0, 0, 0)) for seed in range(6)},
+    # the second level: 2 slots a cell leave most of the 64 cells
+    # over-full, 3 hold them all
+    "spill_hot_above_cells": (_case(23, 300, 8, 4.0), 8, 2, (5, 3, 2)),
+    "spill_hot_below_cells": (_packed(share=0.3), 8, 12, (16, 8, 4)),
+    "spill_cells_above_grid": (_case(23, 300, 4, 4.0), 4, 2, (40, 4, 2)),
+    "spill_deeper_than_both": (_packed(), 8, 8, (4, 16, 4)),
+    "spill_no_overfull_cell": (_case(29, 100, 8, 4.0), 8, 16, (4, 8, 4)),
+    "spill_one_cell": (_one_cell(), 8, 8, (4, 64, 8)),
+}
+
+
+def np_stats(cell, mask, n_cells, bucket, spill_cells, depth):
+    """(over-full cells, deepest cell, rows the second level holds) from
+    the cells' counts alone."""
+    counts = np.bincount(np.asarray(cell)[np.asarray(mask, bool)],
+                         minlength=n_cells)
+    over = counts[counts > bucket] - bucket
+    return (len(over), int(counts.max(initial=0)),
+            int(np.minimum(over[:spill_cells], depth).sum()))
+
+
+def _assert_gathered(full, feats, active, n_cells, bucket, spill, cell,
+                     label):
+    """The full table of a pair build, gathered from the sorted list,
+    against `table_from_slots` scattering the same rows to the same
+    slots; its drops and counts against the cells' counts."""
+    cells, depth = spill[:2]
+    want = table_from_slots(
+        feats, active, full.slot_of, n_cells, full.cell_size, full.width,
+        bucket, full.height, (cells, depth))
+    assert full.payload.dtype == want.payload.dtype
+    np.testing.assert_array_equal(
+        np.asarray(full.payload).view(np.uint32),
+        np.asarray(want.payload).view(np.uint32), err_msg=f"{label} payload")
+    assert int(full.dropped) == int(want.dropped), f"{label} dropped"
+    got_stats = tuple(int(x) for x in full.stats)
+    assert got_stats == np_stats(cell, active, n_cells, bucket, cells,
+                                 depth), f"{label} stats"
+    assert (full.spill_cells, full.spill_bucket) == (cells, depth)
+    # every active row is placed or counted, and a placed row's slot
+    # holds it
+    slot_of = np.asarray(full.slot_of)
+    placed = slot_of != n_cells * bucket
+    assert int(placed.sum()) + int(full.dropped) == int(
+        np.asarray(active).sum()), f"{label} census"
+    np.testing.assert_array_equal(
+        np.asarray(full.payload)[slot_of[placed], :-1],
+        np.asarray(feats)[placed], err_msg=f"{label} rows")
+
+
+@pytest.mark.parametrize("name", sorted(GATHERED))
+def test_gathered_table_is_the_scattered_table(name):
+    case, width, bucket, spill = GATHERED[name]
+    pos, active, feats = case[:3]
+    full, _sub = build_cell_table_pair(
+        *case, 4.0, width, bucket, max(1, bucket // 2), spill=spill)
+    if name.startswith("spill") and "no_overfull" not in name:
+        assert int(full.stats.hot_cells) > 0, "no second level exercised"
+    _assert_gathered(full, feats, active, width * width, bucket, spill,
+                     np_cells(pos, 4.0, width), name)
+
+
+@pytest.mark.parametrize("spill", [(0, 0, 0), (3, 4, 2)])
+def test_gathered_table_rect_grid_precomputed_cells(spill):
+    h, w, cell, n = 4, 8, 4.0, 220
+    rng = np.random.default_rng(17)
+    pos = jnp.asarray(
+        np.c_[rng.uniform(0, w * cell, n), rng.uniform(0, h * cell, n)]
+        .astype(np.float32))
+    active = jnp.asarray(rng.random(n) < 0.9)
+    sub = jnp.asarray(rng.random(n) < 0.3) & active
+    feats = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
+    cid = np_cells(pos, cell, w, h)
+    full, _sub = build_cell_table_pair(
+        pos, active, feats, sub, feats, cell, w, 6, 4,
+        cell=jnp.asarray(cid, jnp.int32), height=h, spill=spill)
+    assert full.height == h
+    _assert_gathered(full, feats, active, h * w, 6, spill, cid, "rect")
+
+
+@pytest.mark.parametrize("bucket,spill", [
+    (3, (0, 0, 0)), (3, (6, 16, 2)), (16, (0, 0, 0))])
+@pytest.mark.parametrize("under", ["vmap", "scan"])
+def test_gathered_table_under_vmap_and_scan(under, bucket, spill):
+    """A fleet's rooms (`vmap`) and a fused run's ticks (`scan`): every
+    lane and every step builds the table its inputs build alone, a slot
+    a gathered row (3 deep) and in runs of 16 (16 deep)."""
+    import jax
+
+    width, lanes = 6, 4
+    cases = [_case(40 + i, 150, width, 4.0, p_active=0.5 + 0.1 * i)
+             for i in range(lanes)]
+    stacked = tuple(jnp.stack(xs) for xs in zip(*cases))
+
+    def build(case):
+        full, _sub = build_cell_table_pair(
+            *case, 4.0, width, bucket, 2, sub_rows=16, spill=spill)
+        return full.payload, full.slot_of, full.dropped, tuple(full.stats)
+
+    if under == "vmap":
+        outs = jax.jit(jax.vmap(build))(stacked)
+    else:
+        _, outs = jax.lax.scan(lambda c, case: (c, build(case)), 0, stacked)
+    for i, case in enumerate(cases):
+        alone, _sub = build_cell_table_pair(
+            *case, 4.0, width, bucket, 2, sub_rows=16, spill=spill)
+        payload, slot_of, dropped, stats = jax.tree.map(
+            lambda x, i=i: x[i], outs)
+        got = alone._replace(payload=payload, slot_of=slot_of,
+                             dropped=dropped, stats=type(alone.stats)(*stats))
+        np.testing.assert_array_equal(
+            np.asarray(payload).view(np.uint32),
+            np.asarray(alone.payload).view(np.uint32))
+        _assert_gathered(got, case[2], case[1], width * width, bucket,
+                         spill, np_cells(case[0], 4.0, width),
+                         f"{under} lane {i}")
 
 
 # --------------------------------------------------- verlet cache parity

@@ -65,22 +65,31 @@ def _geometry(n):
             m.resolved_att_bucket(cap))
 
 
+def _scatters(text):
+    """(result shape, update shape) of every `scatter` in a compiled
+    program."""
+    import re
+
+    shape_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
+    return [
+        (m.group(1), shape_of[m.group(2)]) for m in re.finditer(
+            r"= (\w+\[[\d,]*\])\S* scatter\(%[\w.\-]+, %[\w.\-]+, "
+            r"(%[\w.\-]+)\)", text)]
+
+
 def _attacker_side(text, table, rank_rows):
     """What PR 27 holds the compiled tick to: (the `gather`s under
     `nf.aoe.rank` whose result is `rank_rows`, the update operand of
     every `scatter` into the `table`-shaped attacker payload)."""
     import re
 
-    shape_of = dict(re.findall(
-        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
     rank_gathers = [
         line for line in text.splitlines()
         if re.search(r"= \w+\[%s\]\S* gather\(" % rank_rows, line)
         and "nf.aoe.rank" in line]
-    updates = [
-        shape_of[m.group(1)] for m in re.finditer(
-            r"= f32\[%s\]\S* scatter\(%%[\w.\-]+, %%[\w.\-]+, "
-            r"(%%[\w.\-]+)\)" % table, text)]
+    updates = [update for result, update in _scatters(text)
+               if result == f"f32[{table}]"]
     return rank_gathers, updates
 
 
@@ -250,6 +259,7 @@ def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip,
 
     compiled = jax.jit(rooms_step, donate_argnums=0).lower(fleet).compile()
     mem = compiled.memory_analysis()
+    # 2.10 GB of banks + 1.20 GB of temporaries (0.59 GB scattered)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < 6 * 1024 ** 3
     text = compiled.as_text()
@@ -263,17 +273,44 @@ def test_fleet_tick_compiles_for_one_chip_at_5k_rooms(one_chip,
     assert rank_gathers == []
     assert updates == ["f32[8192,16,8]"] * 2
     assert "while/body/dynamic_slice" not in text
+    # the victim table is gathered by index vectors, batched over the
+    # rooms, and brought no loop over them: the one loop of the build is
+    # the attacker chunks'
+    _sends_no_victim_row(text, "8192,128", 8192 * 321)
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "nf.aoe" in line]
+    assert len(loops) == 1 and "nf.aoe.table/while\"" in loops[0], loops
     # a room's grid is 4 cells wide, 4 lanes in 128: the XLA fold
     assert room.combat.engine_baked == 0
     assert "tpu_custom_call" not in text
 
 
-def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch):
-    """`kernel.step` of `npc-1m` at the depths its window runs at
-    (boost 2: 32/12), traced for the chip: the fold is the Pallas kernel
-    and it is the program's only custom call.  The world is built small
-    and its NPC bank described at 2^20 rows: every shape of the tick
-    follows from the bank's and from the module's extent."""
+def _sends_no_victim_row(text, bank, slots):
+    """What PR 31 holds a compiled tick to: nothing is scattered into a
+    table of the victims' `slots`, and the one scatter whose updates are
+    the bank's rows (`bank`: the shape's leading dimensions) is the
+    un-sort of `slot_of`, single words."""
+    scatters = _scatters(text)
+    into_table = [s for s in scatters if s[0] in (
+        f"f32[{slots},6]", f"f32[6,{slots}]")]
+    assert into_table == [], "rows are sent to the victim table again"
+    by_bank = [s for s in scatters if s[1].startswith(f"f32[{bank}")]
+    assert by_bank == [], by_bank
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "nf.aoe.rank" in line]
+    assert loops == [], "the build's index passes loop"
+
+
+@pytest.mark.parametrize("boost,depths", [(1, (16, 6)), (2, (32, 12))])
+def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch, boost, depths):
+    """`kernel.step` of `npc-1m` at the depths it is built with and at
+    those its window runs at (boost 2: 32/12), traced for the chip: the
+    fold is the Pallas kernel and it is the program's only custom call,
+    and the victim table is gathered from the sorted list: no scatter
+    sends the bank's 2^20 rows to it (until PR 31 one did, 89 ms of a
+    147 ms tick).  The world is built small and its
+    NPC bank described at 2^20 rows: every shape of the tick follows
+    from the bank's and from the module's extent."""
     from noahgameframe_tpu.game import GameWorld, WorldConfig
 
     monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
@@ -285,10 +322,10 @@ def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch):
     k, combat = w.kernel, w.combat
     k._ensure_aux()
     combat._attacker_duty = 1.0 / 30.0  # arm_all's staggered arming
-    combat._bucket_boost = 2
+    combat._bucket_boost = boost
     cap = 1 << 20
     assert (combat.width, combat.resolved_bucket(cap),
-            combat.resolved_att_bucket(cap)) == (395, 32, 12)
+            combat.resolved_att_bucket(cap)) == (395,) + depths
     state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
     state = state.replace(classes={**state.classes, "NPC": jax.tree.map(
         lambda x: jax.ShapeDtypeStruct((cap,) + x.shape[1:], x.dtype,
@@ -296,8 +333,10 @@ def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch):
         state.classes["NPC"])})
     compiled = jax.jit(k._trace_step, donate_argnums=0).lower(state).compile()
     assert combat.engine_baked == 1
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    _sends_no_victim_row(text, "1048576", 395 * 395 * depths[0] + 1)
+    # 1.09 GB at 32/12, the run table among them (0.70 GB scattered)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
 
 
@@ -340,4 +379,8 @@ def test_siege_tick_with_its_second_level_compiles(one_chip, monkeypatch):
     assert combat.engine_baked == 1
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "nf.aoe.spill/while" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
+    # both levels of the victim table are gathered, 9,187,105 slots
+    _sends_no_victim_row(text, "1048576", 395 * 395 * 32 + 1 + 8192 * 512)
+    # temporaries: 1.69 GB with the run tables of both levels (1.76 GB
+    # when the table was scattered)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 1024 ** 3
