@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..core.datatypes import Guid
 from ..core.store import HANDLE_ROW_BITS, WorldState, with_class
 from ..kernel.module import Module
+from ..ops import stencil
 from ..ops.aoi import cell_of
 from ..ops.stencil import (
     STENCIL,
@@ -487,7 +488,7 @@ class CombatModule(Module):
     # world has had cures the cell at a price that is known (`tick-1m`'s
     # breach at tick 245 reads a deepest cell of 25 or 26 rows at depth
     # 16); the siege world's deepest cell is 8 to 16 times over.
-    SPILL_MIN_OVERDEPTH = 4
+    SPILL_MIN_OVERDEPTH = stencil.SPILL_MIN_OVERDEPTH
     # The most a second level that just holds what was seen may take of
     # the base table's slots.  Its streaming passes (zero fill, depths,
     # results) go by its slots as the base level's go by the grid's, so
@@ -496,7 +497,7 @@ class CombatModule(Module):
     # 9,700 over-full cells, 226 to 246 over: it doubles on every seed)
     # and 0.15 to 0.17 after one doubling (3,500 to 3,900 cells): half
     # lies a factor of 1.6 and of 3 from them.
-    SPILL_MAX_GRID_SHARE = 0.5
+    SPILL_MAX_GRID_SHARE = stencil.SPILL_MAX_GRID_SHARE
     # Headroom over what the breaching tick observed, so that the crowd's
     # drift brings no second retrace.  On the chip, where the dead stand
     # still where the killing is, the over-full cells read 3,568 at tick
@@ -509,8 +510,8 @@ class CombatModule(Module):
     # 4,096 cells took 16,384, and their tick read 4% longer).  A cell's
     # attackers are a 1-in-`interval` draw of its rows, so their depth
     # is sized from the victims' (mean + 5 sigma of a Poisson draw).
-    SPILL_CELLS_HEADROOM = 1.5
-    SPILL_DEPTH_HEADROOM = 1.75
+    SPILL_CELLS_HEADROOM = stencil.SPILL_CELLS_HEADROOM
+    SPILL_DEPTH_HEADROOM = stencil.SPILL_DEPTH_HEADROOM
     SPILL_ATTACKER_SIGMAS = 5.0
 
     def _sized_spill(self, capacity: int, seen: dict):
@@ -521,12 +522,10 @@ class CombatModule(Module):
         import math
 
         kv, ka = self.resolved_bucket(capacity), self.resolved_att_bucket(capacity)
-        hot = max(seen["aoe_hot_cells"], seen["aoe_hot_att_cells"], 1)
-        cells = 1 << math.ceil(math.log2(self.SPILL_CELLS_HEADROOM * hot))
-        over = max(seen["aoe_cell_rows_max"] - kv, 1)
-        # 32 at least: whole blocks
-        depth = 1 << max(5, math.ceil(math.log2(
-            self.SPILL_DEPTH_HEADROOM * over)))
+        cells, depth = stencil.second_level_size(
+            max(seen["aoe_hot_cells"], seen["aoe_hot_att_cells"]),
+            seen["aoe_cell_rows_max"], kv,
+            self.SPILL_CELLS_HEADROOM, self.SPILL_DEPTH_HEADROOM)
         # the attackers of the deepest cell the victims' side now holds
         mean = (kv + depth) * min(self._attacker_duty, 1.0)
         att = mean + self.SPILL_ATTACKER_SIGMAS * math.sqrt(mean) + 2.0
@@ -558,18 +557,20 @@ class CombatModule(Module):
         if self.verlet_skin <= 0.0 and all(k in seen for k in names):
             kv = self.resolved_bucket(capacity)
             ka = self.resolved_att_bucket(capacity)
-            deep = (seen["aoe_cell_rows_max"] > self.SPILL_MIN_OVERDEPTH * kv
-                    or seen["aoe_cell_attackers_max"]
-                    > self.SPILL_MIN_OVERDEPTH * ka)
+            sides = (
+                (seen["aoe_hot_cells"], seen["aoe_cell_rows_max"], kv),
+                (seen["aoe_hot_att_cells"], seen["aoe_cell_attackers_max"],
+                 ka),
+            )
+            deep = any(stencil.deep_cell(most, depth, self.SPILL_MIN_OVERDEPTH)
+                       for _hot, most, depth in sides)
             sized = self._sized_spill(capacity, seen)
             # what was seen, as second-level slots, against the grid's
-            share = self.SPILL_MAX_GRID_SHARE * self.width * self.width
-            few = (
-                seen["aoe_hot_cells"]
-                * max(seen["aoe_cell_rows_max"] - kv, 0) <= share * kv
-                and seen["aoe_hot_att_cells"]
-                * max(seen["aoe_cell_attackers_max"] - ka, 0) <= share * ka
-            )
+            few = all(
+                stencil.few_hot_cells(hot, most, depth,
+                                      self.width * self.width,
+                                      self.SPILL_MAX_GRID_SHARE)
+                for hot, most, depth in sides)
             if deep and (few or not can_double) and sized != self._spill:
                 self._spill = sized
                 return ("second level sized to %d hot cells, %d victims and "

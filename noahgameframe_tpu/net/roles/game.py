@@ -210,6 +210,20 @@ class GameRole(ServerRole):
             float(skin_from_env()) if interest_radius is not None else 0.0
         )
         self._interest_jit: Dict[Tuple[str, int], object] = {}
+        # the interest table's sizes, per class, beyond what the
+        # capacity alone gives (`resolved_interest`): a doubling of the
+        # bucket and the second level's (cells, depth).  Nothing sets
+        # them but `_observe_interest`, from what a frame's own table
+        # build counted (a crowd breaching the budget below)
+        self._interest_boost: Dict[str, int] = {}
+        self._interest_spill: Dict[str, Tuple[int, int]] = {}
+        self.interest_overflow_budget = 1e-4  # dropped/live a frame
+        self.interest_max_boost = 8
+        self.interest_resizes = 0
+        self._interest_log_muted = False
+        # the frame bank: what the newest frame's table build counted,
+        # per class (STAT_NAMES of ops/interest.py + candidates_max)
+        self.interest_last: Dict[str, Dict[str, int]] = {}
         # classes with a create/destroy since the last interest flush
         # (visible sets can change without any Position diff)
         self._interest_dirty: set = set()
@@ -562,6 +576,32 @@ class GameRole(ServerRole):
             "nf_serve_sessions",
             "sessions covered by one batched serve dispatch",
         )
+        # the interest table's crowding (docs/OBSERVABILITY.md): what
+        # each frame's build counted, and the sizes the breach policy
+        # has given the table (`resolved_interest`)
+        by_class = ("cls",)
+        self._interest_dropped = sreg.counter(
+            "nf_interest_dropped_total",
+            "rows no client could be shown because they fit neither "
+            "level of the interest table", by_class)
+        self._interest_gauges = {
+            name: sreg.gauge("nf_interest_" + name, help_, by_class)
+            for name, help_ in (
+                ("hot_cells", "interest cells holding more rows than the "
+                              "table's bucket, newest frame"),
+                ("cell_rows_max", "rows in the fullest interest cell, "
+                                  "newest frame"),
+                ("spill_rows", "rows the interest table's second level "
+                               "placed, newest frame"),
+                ("candidates_max", "the widest visible set any session "
+                                   "was served, newest frame"),
+                ("spill_cells", "over-full cells the interest table's "
+                                "second level holds (0 until a breach "
+                                "sized it)"),
+                ("spill_depth", "rows a hot interest cell keeps beyond "
+                                "the bucket (0 until a breach sized it)"),
+            )
+        }
         # K-tick train accounting (mirrors kernel.train_* — counted
         # here so bare-kernel benches still track their own ints)
         self._train_dispatches = sreg.counter(
@@ -2692,148 +2732,178 @@ class GameRole(ServerRole):
                     )
             self._flush_records(player_idx)
 
-    def _interest_step(self, cname: str, s_pad: int):
-        """Cached per-(class, padded-session-count) jit of the interest
-        pipeline: quantize positions, bin ALL alive in-extent entities into
-        the cell table, read each observer's 3x3 neighborhood, distance+zone
-        mask (ops/interest; the same stencil engine combat runs on).
+    def _interest_build(self, cname: str):
+        """Cached per-class jit of the interest lanes' device half that
+        knows no session: quantize positions and bin rows into the cell
+        table (ops/interest; the same stencil engine combat runs on).
 
-        Visibility runs over the full alive set — not just movers — so the
-        host can diff each session's visible set against what that session
-        last saw: entities that moved while unobserved and then stopped are
-        re-sent the moment an observer walks into range (the reference's
-        enter-view resend, NFCSceneAOIModule OnObjectListEnter)."""
-        key = (cname, s_pad)
+        One program serves both lanes.  The Position lane bins ALL alive
+        in-extent rows (`in_extent_only`), so the host can diff each
+        session's visible set against what that session last saw:
+        entities that moved while unobserved and then stopped are re-sent
+        the moment an observer walks into range (the reference's
+        enter-view resend, NFCSceneAOIModule OnObjectListEnter).  The
+        property lanes bin the frame's changed rows as they are.  The
+        sorts of a million rows are what the chip's compiler takes its
+        time over (~30 s a program), and they are here, once a class and
+        table size, not once a padded session count."""
+        key = ("build", cname)
         fn = self._interest_jit.get(key)
         if fn is not None:
             return fn
-        import jax
         import jax.numpy as jnp
 
         from ...ops.interest import (
+            _interest_feats,
+            interest_table,
             quantize,
-            visible_candidates,
-            visible_candidates_cached,
+            table_seam,
         )
-        from ...ops.stencil import auto_bucket
+        from ...ops.verlet import refresh, sub_table
 
-        k = self.kernel
-        spec = k.store.spec(cname)
-        pspec = k.store.spec("Player")
+        spec = self.kernel.store.spec(cname)
         pos_col = spec.slots["Position"].col
         sc_col, gr_col = spec.slots["SceneID"].col, spec.slots["GroupID"].col
-        p_pos = pspec.slots["Position"].col
-        p_sc, p_gr = pspec.slots["SceneID"].col, pspec.slots["GroupID"].col
         extent = float(self.game_world.config.extent)
-        radius = float(self.interest_radius)
         skin = float(self._interest_skin)
-        # skin > 0 inflates the cell so the 3x3 read still covers the true
-        # radius from anchors up to skin/2 stale (ops/verlet.py)
-        cell = radius + skin if skin > 0.0 else radius
-        width = max(1, int(np.ceil(extent / cell)))
-        cap = k.store.capacity(cname)
-        bucket = auto_bucket(cap, width)
+        cell, width = self._interest_grid()
+        bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
         if skin > 0.0:
-            def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid,
-                     cache):
+            def interest_build(evec, ei32, alive, active, in_extent_only,
+                               cache):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
-                res, cache, _rebuilt = visible_candidates_cached(
-                    cache, pos3, in_extent, alive,
-                    ei32[:, sc_col].astype(jnp.float32),
-                    ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket, skin=skin,
-                )
-                return q, res.rows, res.ok & obs_valid[:, None], cache
+                binned = active & (in_extent | ~in_extent_only)
+                # the cache anchors over the STABLE alive set; a frame's
+                # rows ride a sub-table through its sorted order
+                cache, _rebuilt = refresh(
+                    cache, pos3, alive, cell, width, bucket, skin)
+                feats = _interest_feats(
+                    pos3, ei32[:, sc_col].astype(jnp.float32),
+                    ei32[:, gr_col].astype(jnp.float32))
+                table = sub_table(cache, binned & alive, feats,
+                                  width * width, cell, width, bucket)
+                return (q, *table_seam(table), cache)
         else:
-            def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid):
+            def interest_build(evec, ei32, alive, active, in_extent_only):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
-                res = visible_candidates(
-                    pos3, in_extent,
+                table = interest_table(
+                    pos3, active & (in_extent | ~in_extent_only),
                     ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket,
-                )
-                return q, res.rows, res.ok & obs_valid[:, None]
+                    cell, width, bucket, (spill_cells, spill_depth))
+                return (q, *table_seam(table))
 
         fn = self.kernel.costbook.wrap(
-            f"interest.step/{cname}", step, stage="interest"
-        )
+            f"interest.build/{cname}", interest_build, stage="interest")
         self._interest_jit[key] = fn
         return fn
 
-    def _interest_query(self, cname: str, s_pad: int):
-        """Cached jit of the query-only interest pipeline: caller supplies
-        the changed-row mask (any property's diff), gets per-observer
-        visible candidates.  The Position stream has its own variant with
-        the quantize/delta gate fused in (_interest_step)."""
-        key = ("q", cname, s_pad)
+    def _interest_scan(self, cname: str, s_pad: int):
+        """Cached per-(class, padded-session-count) jit of the other
+        half: read each observer's 3x3 neighbourhood from a built table,
+        both levels, distance+zone mask (ops/interest).  Only the
+        payload and the level's index cross the seam; the geometry is
+        static in both closures."""
+        key = ("scan", cname, s_pad)
         fn = self._interest_jit.get(key)
         if fn is not None:
             return fn
-        import jax
         import jax.numpy as jnp
 
-        from ...ops.interest import visible_candidates, visible_candidates_cached
-        from ...ops.stencil import auto_bucket
+        from ...ops.interest import _scan_observers, seam_table
 
-        k = self.kernel
-        spec = k.store.spec(cname)
-        pspec = k.store.spec("Player")
-        pos_col = spec.slots["Position"].col
-        sc_col, gr_col = spec.slots["SceneID"].col, spec.slots["GroupID"].col
+        pspec = self.kernel.store.spec("Player")
         p_pos = pspec.slots["Position"].col
         p_sc, p_gr = pspec.slots["SceneID"].col, pspec.slots["GroupID"].col
-        extent = float(self.game_world.config.extent)
         radius = float(self.interest_radius)
-        skin = float(self._interest_skin)
-        cell = radius + skin if skin > 0.0 else radius
-        width = max(1, int(np.ceil(extent / cell)))
-        bucket = auto_bucket(k.store.capacity(cname), width)
+        cell, width = self._interest_grid()
+        bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
-        if skin > 0.0:
-            def query(evec, ei32, changed, alive, pvec, pi32, obs_rows,
-                      obs_valid, cache):
-                res, cache, _rebuilt = visible_candidates_cached(
-                    cache, evec[:, pos_col], changed, alive,
-                    ei32[:, sc_col].astype(jnp.float32),
-                    ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket, skin=skin,
-                )
-                return res.rows, res.ok & obs_valid[:, None], cache
-        else:
-            def query(evec, ei32, changed, pvec, pi32, obs_rows, obs_valid):
-                res = visible_candidates(
-                    evec[:, pos_col], changed,
-                    ei32[:, sc_col].astype(jnp.float32),
-                    ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket,
-                )
-                return res.rows, res.ok & obs_valid[:, None]
+        def interest_scan(payload, hot_of, pvec, pi32, obs_rows, obs_valid):
+            res = _scan_observers(
+                seam_table(payload, hot_of, cell, width, bucket,
+                           (spill_cells, spill_depth)),
+                pvec[obs_rows, p_pos][:, :2],
+                pi32[obs_rows, p_sc].astype(jnp.float32),
+                pi32[obs_rows, p_gr].astype(jnp.float32),
+                radius, cell,
+            )
+            ok = res.ok & obs_valid[:, None]
+            # the widest visible set rides the frame's fetch
+            return res.rows, ok, jnp.max(jnp.sum(ok, axis=1, dtype=jnp.int32))
 
         fn = self.kernel.costbook.wrap(
-            f"interest.query/{cname}", query, stage="interest"
-        )
+            f"interest.scan/{cname}", interest_scan, stage="interest")
         self._interest_jit[key] = fn
         return fn
+
+    def _interest_lane(self, position: bool):
+        """`_interest_build`'s `in_extent_only`, kept on the device."""
+        flags = getattr(self, "_interest_lanes", None)
+        if flags is None:
+            import jax.numpy as jnp
+
+            flags = self._interest_lanes = (jnp.asarray(False),
+                                            jnp.asarray(True))
+        return flags[bool(position)]
+
+    def _interest_step(self, cname: str, s_pad: int):
+        """The Position lane's interest pipeline for a padded session
+        count: `_interest_build` over all alive in-extent rows, then
+        `_interest_scan`.  Returns (q, rows, ok, counts[, cache]);
+        `counts` = (the build's stats, the widest visible set), what
+        `_observe_interest` reads."""
+        build = self._interest_build(cname)
+        scan = self._interest_scan(cname, s_pad)
+        lane = self._interest_lane(True)
+
+        if self._interest_skin > 0.0:
+            def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid,
+                     cache):
+                q, payload, hot_of, built, cache = build(
+                    evec, ei32, alive, alive, lane, cache)
+                rows, ok, widest = scan(
+                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
+                return q, rows, ok, (built, widest), cache
+        else:
+            def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid):
+                q, payload, hot_of, built = build(
+                    evec, ei32, alive, alive, lane)
+                rows, ok, widest = scan(
+                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
+                return q, rows, ok, (built, widest)
+        return step
+
+    def _interest_query(self, cname: str, s_pad: int):
+        """The query-only interest pipeline: caller supplies the
+        changed-row mask (any property's diff), gets per-observer visible
+        candidates, through the Position lane's own two programs.  The
+        changed rows of a cell are among its alive rows, so the sizes
+        that hold the whole class hold any frame's diff."""
+        build = self._interest_build(cname)
+        scan = self._interest_scan(cname, s_pad)
+        lane = self._interest_lane(False)
+
+        if self._interest_skin > 0.0:
+            def query(evec, ei32, changed, alive, pvec, pi32, obs_rows,
+                      obs_valid, cache):
+                _q, payload, hot_of, _built, cache = build(
+                    evec, ei32, alive, changed, lane, cache)
+                rows, ok, _widest = scan(
+                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
+                return rows, ok, cache
+        else:
+            def query(evec, ei32, changed, pvec, pi32, obs_rows, obs_valid):
+                # (this lane binned changed rows alive or not: keep it)
+                _q, payload, hot_of, _built = build(
+                    evec, ei32, changed, changed, lane)
+                rows, ok, _widest = scan(
+                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
+                return rows, ok
+        return query
 
     def _interest_cache_for(self, cname: str):
         """The class's interest Verlet cache, carried in WorldState.aux
@@ -2903,6 +2973,7 @@ class GameRole(ServerRole):
         from OnObjectListEnter, without any global last-synced table (and
         hence no stale-row hazard when rows are recycled: the guid is part
         of the match)."""
+        import jax
         import jax.numpy as jnp
 
         from ...ops.interest import QMAX
@@ -2921,20 +2992,24 @@ class GameRole(ServerRole):
         fn = self._interest_step(cname, len(obs_rows))
         if self._interest_skin > 0.0:
             ckey, cache = self._interest_cache_for(cname)
-            q, rows, ok, cache = fn(
+            q, rows, ok, stats, cache = fn(
                 cs.vec, cs.i32, cs.alive,
                 pcs.vec, pcs.i32,
                 jnp.asarray(obs_rows), jnp.asarray(obs_valid), cache,
             )
             self._interest_cache_store(ckey, cache)
         else:
-            q, rows, ok = fn(
+            q, rows, ok, stats = fn(
                 cs.vec, cs.i32, cs.alive,
                 pcs.vec, pcs.i32,
                 jnp.asarray(obs_rows), jnp.asarray(obs_valid),
             )
-        q_np = np.asarray(q).astype(np.uint16)
-        rows_np, ok_np = np.asarray(rows), np.asarray(ok)
+        # the frame's one read: the answer, and beside it what the
+        # table's build counted (the breach policy's input)
+        q_np, rows_np, ok_np, (built, widest) = jax.device_get(
+            (q, rows, ok, stats))
+        q_np = q_np.astype(np.uint16)
+        self._observe_interest(cname, list(built) + [widest])
         host = k.store._hosts[cname]
         scale = float(self.game_world.config.extent) / QMAX
         # nf-lint: disable=serve-loop -- the legacy per-session engine
@@ -3005,28 +3080,142 @@ class GameRole(ServerRole):
     # and the host's only per-session work is slicing precomputed byte
     # buffers into packets (net/serving.py).
 
+    def _interest_grid(self) -> Tuple[float, int]:
+        """(cell size, width) of every class's interest grid.  A Verlet
+        skin inflates the cell so the 3x3 read still covers the true
+        radius from anchors up to skin/2 stale (ops/verlet.py)."""
+        extent = float(self.game_world.config.extent)
+        radius = float(self.interest_radius)
+        skin = float(self._interest_skin)
+        cell = radius + skin if skin > 0.0 else radius
+        return cell, max(1, int(np.ceil(extent / cell)))
+
+    def resolved_interest(self, cname: str) -> Tuple[int, int, int]:
+        """(bucket, spill_cells, spill_depth) of the class's interest
+        table in a program traced now, as `CombatModule.resolved_bucket`
+        / `resolved_spill` state the neighbour engine's: a cell keeps
+        `bucket` rows (`auto_bucket` of the capacity, doubled by every
+        breach a deeper grid answered) and the first `spill_cells`
+        over-full cells in cell order `spill_depth` more; (auto_bucket,
+        0, 0) until a frame's drops breached the budget.  The Verlet
+        path runs no second level."""
+        from ...ops.stencil import auto_bucket
+
+        _cell, width = self._interest_grid()
+        cap = self.kernel.store.capacity(cname)
+        bucket = min(auto_bucket(cap, width)
+                     * self._interest_boost.get(cname, 1), max(cap, 1))
+        if self._interest_skin > 0.0:
+            return bucket, 0, 0
+        cells, depth = self._interest_spill.get(cname, (0, 0))
+        return bucket, min(cells, width * width), depth
+
+    def _observe_interest(self, cname: str, stats) -> None:
+        """Host side of a frame's interest build: publish what it
+        counted (`stats`: ops/interest.STAT_NAMES, then the widest
+        visible set) and hold its drops against the budget.  A breach is
+        answered as combat's (`CombatModule._on_overflow`): a retrace
+        with a larger table, announced, compiled by the next frame."""
+        import logging
+
+        from ...ops.interest import STAT_NAMES
+
+        last = dict(zip(STAT_NAMES + ("candidates_max",),
+                        (int(v) for v in stats)))
+        self.interest_last[cname] = last
+        self._interest_dropped.inc(last["dropped"], cls=cname)
+        for name in ("hot_cells", "cell_rows_max", "spill_rows",
+                     "candidates_max"):
+            self._interest_gauges[name].set(last[name], cls=cname)
+        _bucket, cells, depth = self.resolved_interest(cname)
+        self._interest_gauges["spill_cells"].set(cells, cls=cname)
+        self._interest_gauges["spill_depth"].set(depth, cls=cname)
+        live = int(self.kernel.store.live_count(cname))
+        dropped = last["dropped"]
+        if live <= 0 or dropped <= self.interest_overflow_budget * live:
+            return
+        log = logging.getLogger("nf.interest")
+        answer = self._answer_interest_breach(cname, last)
+        if answer is not None:
+            self._interest_resize(cname)
+            log.warning(
+                "interest table overflow (%s): %d/%d rows in no client's "
+                "view (budget %.4f%%) — %s, interest programs retracing",
+                cname, dropped, live, self.interest_overflow_budget * 100,
+                answer)
+        elif not self._interest_log_muted:
+            self._interest_log_muted = True
+            log.warning(
+                "interest table overflow (%s): %d/%d rows in no client's "
+                "view (budget %.4f%%) — resize exhausted; further "
+                "breaches are counted (nf_interest_dropped_total) but "
+                "not logged", cname, dropped, live,
+                self.interest_overflow_budget * 100)
+
+    def _answer_interest_breach(self, cname: str, seen: Dict[str, int]
+                                ) -> Optional[str]:
+        """What a budget breach changes, from what the breaching frame's
+        build counted, or None when nothing is left to change: the rule
+        of `CombatModule._answer_breach` (ops/stencil.py has it).  A few
+        cells far over the bucket get the second level, sized with
+        headroom and never smaller than it was; a class over-full
+        everywhere, or over-full in so many cells that the level would
+        be priced like the grid, gets its bucket doubled; with the
+        doubling used up the level is what is left."""
+        from ...ops import stencil
+
+        bucket, cells, depth = self.resolved_interest(cname)
+        _cell, width = self._interest_grid()
+        boost = self._interest_boost.get(cname, 1)
+        can_double = boost < self.interest_max_boost
+        hot, most = seen["hot_cells"], seen["cell_rows_max"]
+        if self._interest_skin <= 0.0:
+            sized = stencil.second_level_size(hot, most, bucket)
+            sized = (max(sized[0], cells), max(sized[1], depth))
+            few = stencil.few_hot_cells(hot, most, bucket, width * width)
+            if (stencil.deep_cell(most, bucket) and (few or not can_double)
+                    and sized != (cells, depth)):
+                self._interest_spill[cname] = sized
+                return ("second level sized to %d hot cells, %d rows deep"
+                        % sized)
+        if can_double:
+            self._interest_boost[cname] = boost * 2
+            return "bucket boosted x%d" % (boost * 2)
+        return None
+
+    def _interest_resize(self, cname: str) -> None:
+        """Make the class's interest programs retrace at the sizes
+        `resolved_interest` now states.  Announced like a bucket boost
+        of the tick (a CostBook generation bump), so the compiles that
+        follow are the program's own and no hazard; the tick itself is
+        not retraced.  Nobody is resent the world: the per-session
+        engine's seen-state is by row and knows no width, the batched
+        engine's `SeenTable` is widened in place (`seen_for`)."""
+        with span("interest.resize", cls=cname):
+            self.interest_resizes += 1
+            self.kernel.costbook.generation_bump(f"interest_resize:{cname}")
+            for jits in (self._interest_jit, self._serve_jit):
+                for key in [key for key in jits if cname in key]:
+                    del jits[key]
+            getattr(self, "_serve_geom", {}).pop(cname, None)
+
     def _serve_geometry(self, cname: str):
         """(cell, width, bucket, m): grid geometry shared with the legacy
         jits — identical candidate sets are the parity precondition.  `m`
-        is the seen-table width: 9*bucket covers every candidate slot
-        exactly; NF_SERVE_SLOTS can cap it (memory at huge session
-        counts) at the cost of dropping the farthest-slot candidates of
-        overfull views for a frame."""
+        is the seen-table width: 9 * (bucket + spill depth) covers every
+        candidate slot of both levels exactly, so it follows the crowd
+        with `resolved_interest`; NF_SERVE_SLOTS can cap it (memory at
+        huge session counts) at the cost of dropping the farthest-slot
+        candidates of overfull views for a frame."""
         geom = getattr(self, "_serve_geom", None)
         if geom is None:
             geom = self._serve_geom = {}
         g = geom.get(cname)
         if g is not None:
             return g
-        from ...ops.stencil import auto_bucket
-
-        extent = float(self.game_world.config.extent)
-        radius = float(self.interest_radius)
-        skin = float(self._interest_skin)
-        cell = radius + skin if skin > 0.0 else radius
-        width = max(1, int(np.ceil(extent / cell)))
-        bucket = auto_bucket(self.kernel.store.capacity(cname), width)
-        m = 9 * bucket
+        cell, width = self._interest_grid()
+        bucket, _cells, depth = self.resolved_interest(cname)
+        m = 9 * (bucket + depth)
         cap_m = _env_int("NF_SERVE_SLOTS", 0)
         if cap_m > 0:
             m = min(m, cap_m)
@@ -3060,9 +3249,13 @@ class GameRole(ServerRole):
         import jax
         import jax.numpy as jnp
 
-        from ...ops.interest import _interest_feats, quantize
+        from ...ops.interest import (
+            _interest_feats,
+            interest_table,
+            quantize,
+            table_seam,
+        )
         from ...ops.serving import bump_qver
-        from ...ops.stencil import build_cell_table
         from ...ops.verlet import refresh, sub_table
 
         spec = self.kernel.store.spec(cname)
@@ -3071,6 +3264,7 @@ class GameRole(ServerRole):
         extent = float(self.game_world.config.extent)
         skin = float(self._interest_skin)
         cell, width, bucket, _m = self._serve_geometry(cname)
+        _bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
         if skin > 0.0:
             def prep(evec, ei32, alive, qver, prev_q, cache):
@@ -3089,21 +3283,19 @@ class GameRole(ServerRole):
                     cache, in_extent & alive, feats, width * width,
                     cell, width, bucket,
                 )
-                return q, qver2, prev2, table.payload, cache
+                return (q, qver2, prev2, *table_seam(table), cache)
         else:
             def prep(evec, ei32, alive, qver, prev_q):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
                 qver2, prev2 = bump_qver(q, prev_q, qver)
-                feats = _interest_feats(
-                    pos3,
+                table = interest_table(
+                    pos3, in_extent,
                     ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32),
+                    cell, width, bucket, (spill_cells, spill_depth),
                 )
-                table = build_cell_table(
-                    pos3, in_extent, feats, cell, width, bucket
-                )
-                return q, qver2, prev2, table.payload
+                return (q, qver2, prev2, *table_seam(table))
 
         fn = self.kernel.costbook.wrap(
             f"serve.prepare/{cname}", prep, stage="interest"
@@ -3123,25 +3315,22 @@ class GameRole(ServerRole):
         import jax
         import jax.numpy as jnp
 
-        from ...ops.interest import _scan_observers
+        from ...ops.interest import _scan_observers, seam_table
         from ...ops.serving import SeenTable, interest_delta, slot_compact
-        from ...ops.stencil import CellTable
 
         pspec = self.kernel.store.spec("Player")
         p_pos = pspec.slots["Position"].col
         p_sc, p_gr = pspec.slots["SceneID"].col, pspec.slots["GroupID"].col
         radius = float(self.interest_radius)
         cell, width, bucket, m = self._serve_geometry(cname)
-        k9 = 9 * bucket
+        _bucket, spill_cells, spill_depth = self.resolved_interest(cname)
+        k9 = 9 * (bucket + spill_depth)
 
-        def scan(payload, pvec, pi32, obs_rows, valid, alloc_ok, gen,
-                 qver, seen_rows, seen_gen, seen_qver):
-            table = CellTable(
-                payload, jnp.zeros((1,), jnp.int32),
-                jnp.zeros((), jnp.int32), width, cell, bucket,
-            )
+        def scan(payload, hot_of, pvec, pi32, obs_rows, valid, alloc_ok,
+                 gen, qver, seen_rows, seen_gen, seen_qver):
             res = _scan_observers(
-                table,
+                seam_table(payload, hot_of, cell, width, bucket,
+                           (spill_cells, spill_depth)),
                 pvec[obs_rows, p_pos][:, :2],
                 pi32[obs_rows, p_sc].astype(jnp.float32),
                 pi32[obs_rows, p_gr].astype(jnp.float32),
@@ -3155,10 +3344,12 @@ class GameRole(ServerRole):
                 rows, counts = slot_compact(rows, ok)
                 rows = rows[:, :m]
                 ok = jnp.arange(m, dtype=jnp.int32)[None, :] < counts[:, None]
-            return interest_delta(
+            delta = interest_delta(
                 rows, ok, gen, qver,
                 SeenTable(seen_rows, seen_gen, seen_qver),
             )
+            # the chunk's widest visible set rides its fetch
+            return delta, jnp.max(jnp.sum(ok, axis=1, dtype=jnp.int32))
 
         fn = self.kernel.costbook.wrap(
             f"serve.scan/{cname}", scan, stage="interest"
@@ -3196,12 +3387,12 @@ class GameRole(ServerRole):
         prep = self._serve_prepare(cname)
         if self._interest_skin > 0.0:
             ckey, cache = self._interest_cache_for(cname)
-            q, qver, prev_q, payload, cache = prep(
+            q, qver, prev_q, payload, hot_of, built, cache = prep(
                 cs.vec, cs.i32, cs.alive, qver, prev_q, cache
             )
             self._interest_cache_store(ckey, cache)
         else:
-            q, qver, prev_q, payload = prep(
+            q, qver, prev_q, payload, hot_of, built = prep(
                 cs.vec, cs.i32, cs.alive, qver, prev_q
             )
         self._serve_qver[cname] = (qver, prev_q)
@@ -3220,14 +3411,16 @@ class GameRole(ServerRole):
         for c0 in range(0, s_total, chunk):
             c1 = c0 + chunk
             fn = self._serve_scan(cname, chunk)
-            delta = fn(
-                payload, pcs.vec, pcs.i32,
+            delta, widest = fn(
+                payload, hot_of, pcs.vec, pcs.i32,
                 obs_rows[c0:c1], valid[c0:c1], alloc_ok, gen, qver,
                 seen.rows[c0:c1], seen.gen[c0:c1], seen.qver[c0:c1],
             )
             self._serve_dispatches.inc()
+            # the build's counts ride the chunk's own fetch
             parts.append(jax.device_get(
-                (delta.vis, delta.send, delta.gone, delta.gone_rows)
+                (delta.vis, delta.send, delta.gone, delta.gone_rows,
+                 widest, built)
             ))
             seen = type(seen)(
                 rows=seen.rows.at[c0:c1].set(delta.seen.rows),
@@ -3236,6 +3429,8 @@ class GameRole(ServerRole):
             )
         st.store_seen(cname, seen)
         self._serve_sessions_hist.observe(int(st.valid.sum()))
+        self._observe_interest(
+            cname, list(parts[0][5]) + [max(int(p[4]) for p in parts)])
 
         # gone lists carry guids AS LAST SERVED — freed rows have their
         # live guid zeroed, so gather from the previous run's mirrors
@@ -3343,6 +3538,7 @@ class GameRole(ServerRole):
         radius = float(self.interest_radius)
         skin = float(self._interest_skin)
         cell, width, bucket, _m = self._serve_geometry(cname)
+        _bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
         if skin > 0.0:
             def query(evec, ei32, changed, alive, pvec, pi32, obs_rows,
@@ -3371,7 +3567,7 @@ class GameRole(ServerRole):
                     pi32[obs_rows, p_sc].astype(jnp.float32),
                     pi32[obs_rows, p_gr].astype(jnp.float32),
                     radius=radius, cell_size=cell, width=width,
-                    bucket=bucket,
+                    bucket=bucket, spill=(spill_cells, spill_depth),
                 )
                 ok = res.ok & valid[:, None] & alloc_ok[res.rows]
                 rows, counts = slot_compact(res.rows, ok)

@@ -171,11 +171,24 @@ class SessionTable:
     # ------------------------------------------------------- device state
     def seen_for(self, cname: str, m: int) -> SeenTable:
         """[capacity, m] seen-state for a class, created empty on first
-        use.  `m` is static per class (9 * stencil bucket, possibly
-        capped by NF_SERVE_SLOTS) — a changed m means a changed kernel
-        geometry, so the table resets (full resend, same as a fresh
-        compile of the legacy path after capacity growth)."""
+        use.  `m` is the candidate width of the class's serve kernel
+        (9 * (bucket + second-level depth), possibly capped by
+        NF_SERVE_SLOTS).  It GROWS when the game role deepens a crowded
+        interest table (`GameRole._interest_resize`): the table is then
+        widened in place, empty slots behind every session's sorted
+        rows, so nobody is resent what they already mirror.  A smaller
+        m (a changed cap) resets the table: a full resend, as a fresh
+        compile of the legacy path after capacity growth."""
         tbl = self.seen.get(cname)
+        if (tbl is not None and tbl.rows.shape[0] == self.capacity
+                and self._seen_m.get(cname, m) < m):
+            import jax.numpy as jnp
+
+            ext = init_seen(self.capacity, m - self._seen_m[cname])
+            tbl = SeenTable(*(jnp.concatenate([a, b], axis=1)
+                              for a, b in zip(tbl, ext)))
+            self.seen[cname] = tbl
+            self._seen_m[cname] = m
         if tbl is None or self._seen_m.get(cname) != m or (
             tbl.rows.shape[0] != self.capacity
         ):

@@ -17,7 +17,11 @@ position sync at 100k entities / 500 sessions).  This module computes
    engine's cell table (ops/stencil.build_cell_table, one argsort) and,
    for every observer position, read the 3x3 neighborhood's K slots and
    distance-mask them: [S, 9K] candidate rows in ONE dispatch, no host
-   loops.
+   loops.  Where the table has a SECOND LEVEL (a crowd: cells far over
+   K, sized by the game role's breach policy and not by an option), the
+   rows an over-full cell keeps beyond K are read too, for those of the
+   nine cells that are over-full: [S, 9K + 9D] candidates, D the
+   level's depth.
 
 Both are static-shaped and jit-compiled by the caller (the game role
 caches per-shape jits).  The host then slices each session's visible
@@ -26,7 +30,7 @@ rows and packs one compact message per session (net/roles/game.py).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +41,17 @@ from .verlet import VerletCache, refresh, sub_table
 QMAX = 65535  # u16 quantization range
 
 
+# what a build of the interest table reports, in `InterestResult.stats`
+STAT_NAMES = ("dropped", "hot_cells", "cell_rows_max", "spill_rows")
+
+
 class InterestResult(NamedTuple):
-    rows: jnp.ndarray  # [S, 9K] int32 entity row ids (garbage where ~ok)
-    ok: jnp.ndarray  # [S, 9K] bool — occupied slot AND within radius
+    rows: jnp.ndarray  # [S, 9K + 9D] int32 row ids (garbage where ~ok)
+    ok: jnp.ndarray  # [S, 9K + 9D] bool — occupied slot AND within radius
+    # [4] int32, `STAT_NAMES`: rows that fit neither level of the table
+    # this answer was read from, its over-full cells, its fullest cell,
+    # rows its second level placed (None: a cached table counts none)
+    stats: Optional[jnp.ndarray] = None
 
 
 def quantize(
@@ -79,22 +91,70 @@ def visible_candidates(
     cell_size: float,
     width: int,
     bucket: int,
+    spill: Tuple[int, int] = (0, 0),
 ) -> InterestResult:
-    """For each observer, the moved entities within `radius` AND visible
+    """For each observer, the `moved` entities within `radius` AND visible
     under the reference's broadcast scoping (NFCSceneAOIModule): same
     scene, and either the same group or the entity carries GroupID 0
     (scene-wide).  Scenes share one coordinate space, so proximity alone
     would leak entities across scene/clone-group boundaries.
 
     cell_size must be >= radius so the 3x3 stencil covers the disc.
-    Entities beyond a cell's `bucket` slots are dropped for the frame
-    (they re-qualify next time they move; size via ops.stencil.auto_bucket
-    to keep that ~zero)."""
-    feats = _interest_feats(pos, scene, group)
-    table = build_cell_table(pos, moved, feats, cell_size, width, bucket)
+    `spill = (cells, depth)` is the table's second level
+    (ops/stencil.build_cell_table): the first `cells` over-full cells in
+    cell order keep `depth` rows beyond `bucket`.  A row that fits
+    neither level (the highest rows of its cell) is in no observer's
+    answer for as long as it stands that deep in that cell: it is
+    counted in `stats` (`dropped`), which the game role publishes as
+    `nf_interest_dropped_total` and holds against its budget
+    (net/roles/game.py `_answer_interest_breach` deepens the table)."""
+    table = interest_table(
+        pos, moved, scene, group, cell_size, width, bucket, spill)
     return _scan_observers(
         table, obs_pos, obs_scene, obs_group, radius, cell_size
-    )
+    )._replace(stats=table_stats(table))
+
+
+def interest_table(
+    pos, active, scene, group, cell_size: float, width: int, bucket: int,
+    spill: Tuple[int, int] = (0, 0),
+) -> CellTable:
+    """The interest programs' one table build (device scope
+    `nf.interest.bin`): `active` rows binned with `_interest_feats`."""
+    with jax.named_scope("nf.interest.bin"):
+        return build_cell_table(
+            pos, active, _interest_feats(pos, scene, group), cell_size,
+            width, bucket, spill_cells=spill[0], spill_bucket=spill[1])
+
+
+def table_stats(table: CellTable) -> jnp.ndarray:
+    """`InterestResult.stats` of a table built here (zeros from a table
+    that counts none: the Verlet-cached sub-table)."""
+    st = table.stats
+    if st is None:
+        return jnp.zeros((len(STAT_NAMES),), jnp.int32)
+    return jnp.stack([table.dropped, st.hot_cells, st.rows_max,
+                      st.spill_rows]).astype(jnp.int32)
+
+
+def table_seam(table: CellTable):
+    """What of a built table crosses from the program that builds it to
+    the program that scans it (the game role keeps them apart: the build
+    knows no session, the scan no row): (payload, hot_of, stats), the
+    level's index one word where there is no level."""
+    hot_of = (jnp.zeros((1,), jnp.int32) if table.hot_of is None
+              else table.hot_of)
+    return table.payload, hot_of, table_stats(table)
+
+
+def seam_table(payload, hot_of, cell_size: float, width: int, bucket: int,
+               spill: Tuple[int, int] = (0, 0)) -> CellTable:
+    """`table_seam`'s other side: the table `_scan_observers` reads,
+    from the payload and the level's index (geometry static)."""
+    return CellTable(
+        payload, jnp.zeros((1,), jnp.int32), jnp.zeros((), jnp.int32),
+        width, cell_size, bucket, spill_cells=spill[0],
+        spill_bucket=spill[1], hot_of=hot_of if spill[0] else None)
 
 
 def scope_mask(cand_scene, cand_group, obs_scene, obs_group) -> jnp.ndarray:
@@ -137,31 +197,48 @@ def _scan_observers(
     TRUE radius on the current positions carried in the payload — which
     is what keeps cached (anchor-binned) tables bit-identical, provided
     cell_size >= radius + skin/2 covers the staleness."""
-    grid = table.grid_view()  # [H, W, K, F+1]
-    h, w, k, f = grid.shape
+    h = table.height if table.height > 0 else table.width
+    w = table.width
     inv = 1.0 / cell_size
     ox = jnp.floor(obs_pos[:, 0] * inv).astype(jnp.int32)
     oy = jnp.floor(obs_pos[:, 1] * inv).astype(jnp.int32)
-    cand_list = []
-    ok_list = []
-    for dy, dx in STENCIL:
-        yy, xx = oy + dy, ox + dx
-        in_grid = (yy >= 0) & (yy < h) & (xx >= 0) & (xx < w)
-        cells = grid[jnp.clip(yy, 0, h - 1), jnp.clip(xx, 0, w - 1)]
-        # cells: [S, K, F+1]; occupancy rides the last column
-        occ = (cells[..., -1] > 0) & in_grid[:, None]
+    r2 = radius * radius
+
+    def read(cells, present):
+        """One neighbour cell's slots for every observer: cells
+        [S, depth, F+1], occupancy in the last column."""
+        occ = (cells[..., -1] > 0) & present[:, None]
         dxv = cells[..., 1] - obs_pos[:, None, 0]
         dyv = cells[..., 2] - obs_pos[:, None, 1]
-        within = (dxv * dxv + dyv * dyv) <= radius * radius
+        within = (dxv * dxv + dyv * dyv) <= r2
         scoped = scope_mask(
             cells[..., 3], cells[..., 4],
             obs_scene[:, None], obs_group[:, None],
         )
-        cand_list.append(cells[..., 0].astype(jnp.int32))
-        ok_list.append(occ & within & scoped)
+        return cells[..., 0].astype(jnp.int32), occ & within & scoped
+
+    def nine():
+        for dy, dx in STENCIL:
+            yy, xx = oy + dy, ox + dx
+            in_grid = (yy >= 0) & (yy < h) & (xx >= 0) & (xx < w)
+            yield jnp.clip(yy, 0, h - 1), jnp.clip(xx, 0, w - 1), in_grid
+
+    with jax.named_scope("nf.interest.scan"):
+        grid = table.grid_view()  # [H, W, K, F+1]
+        got = [read(grid[yy, xx], in_grid) for yy, xx, in_grid in nine()]
+    if table.spill_cells > 0:
+        # the rows an over-full cell keeps beyond its K slots, for those
+        # of the nine that are over-full: the same tests, `depth` wide
+        with jax.named_scope("nf.interest.spill"):
+            level = table.spill_view()  # [cells, depth, F+1]
+            hot_of = table.hot_of.reshape(h, w)
+            for yy, xx, in_grid in nine():
+                hot = hot_of[yy, xx]
+                got.append(read(level[jnp.maximum(hot, 0)],
+                                in_grid & (hot >= 0)))
     return InterestResult(
-        rows=jnp.concatenate(cand_list, axis=1),
-        ok=jnp.concatenate(ok_list, axis=1),
+        rows=jnp.concatenate([rows for rows, _ in got], axis=1),
+        ok=jnp.concatenate([ok for _, ok in got], axis=1),
     )
 
 

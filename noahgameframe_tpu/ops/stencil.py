@@ -14,19 +14,18 @@ candidate:
 1. `build_cell_table` sorts `(cell id, row)` pairs (one cheap sort that
    hands back the sorted keys with the order, so nothing is gathered to
    rank them), packs caller-chosen per-entity features into a dense
-   `[n_cells*K + 1, F+1]` payload table with ONE un-sort scatter of the
-   slots and ONE payload scatter (unique slot indices, deterministic),
-   and remembers each row's slot (`slot_of`).  Entities beyond a cell's
-   K slots land in the dump slot and are counted in `dropped` — size K
-   from `auto_bucket` to keep that ~zero.  `build_cell_table_pair`, the
-   build every tick makes, SENDS NO ROW to its full table: a scatter on
-   a v5e costs 85 ns for every row sent (2^20 of them a tick, 89 ms),
-   a gather ~5 ns for every index followed.  After the sort a cell's
-   members are one run of the sorted list, in the order its slots hold
-   them, so the payload is GATHERED slot by slot from the sorted
-   features (`_cell_starts`, `_slot_sources`, `table_from_sorted`) and
-   only `slot_of`, which the pull reads, is still un-sorted by a
-   scatter.  It also adds a SUBSET table (combat: this tick's
+   `[n_cells*K + 1, F+1]` payload table and remembers each row's slot
+   (`slot_of`, ONE un-sort scatter).  Entities beyond a cell's K slots
+   land in the dump slot and are counted in `dropped` — size K from
+   `auto_bucket` to keep that ~zero.  The build SENDS NO ROW to the
+   table: a scatter on a v5e costs 85 ns for every row sent (2^20 of
+   them a tick, 89 ms), a gather ~5 ns for every index followed.  After
+   the sort a cell's members are one run of the sorted list, in the
+   order its slots hold them, so the payload is GATHERED slot by slot
+   from the sorted features (`_cell_starts`, `_slot_sources`,
+   `table_from_sorted`) and only `slot_of`, which the pull reads, is
+   still un-sorted by a scatter.  `build_cell_table_pair`, the build
+   every tick makes, adds a SUBSET table (combat: this tick's
    attackers) whose irregular passes are priced by the subset, not by
    the bank: a second sort compacts the members to the front in cell
    order, their ranks and slots are streaming passes over that list,
@@ -41,14 +40,20 @@ candidate:
    identity element).
 
 A cell far over its K slots (a spawn camp, a city) is not answered by
-a deeper grid: `build_cell_table_pair(..., spill=...)` hangs a SECOND
-LEVEL off the same sort, priced by the over-full cells.  Their rows
+a deeper grid: both builds hang a SECOND LEVEL off the same sort
+(`build_cell_table_pair(..., spill=...)`, `build_cell_table(...,
+spill_cells, spill_bucket)`), priced by the over-full cells.  Their rows
 beyond K get slots behind the dump slot (`_spill_slots`: streaming
 passes over the ranks), filled by the same gather (victims) and the
 same chunked scatter (attackers) as the base level's; the caller folds
 the pairs the grid's fold cannot see (game/combat.py
 `combat_fold_spill`) and `pull_slots(..., spill=...)` brings both
-levels back in one gather.
+levels back in one gather; the interest scan reads, beside an
+observer's nine cells, the second-level rows of those of the nine that
+are over-full (`CellTable.hot_of`, ops/interest.py).  When a breach of
+a caller's overflow budget is answered by the level and how large it
+is made is one rule for both callers (`deep_cell`, `few_hot_cells`,
+`second_level_size`).
 
 Everything is static-shaped, jit/vmap/shard_map-friendly, and
 deterministic (stable sort, gathers, unique-index scatters, fixed fold
@@ -105,8 +110,7 @@ class CellTable(NamedTuple):
     dropped: scalar int32 — active entities that overflowed their cell.
     width, cell_size, bucket: static grid geometry.
 
-    A table built with a SECOND LEVEL (`build_cell_table_pair(...,
-    spill=...)`) carries, behind the dump slot, `spill_cells` rows of
+    A table built with a SECOND LEVEL carries, behind the dump slot, `spill_cells` rows of
     `spill_bucket` slots: the rows the first `spill_cells` over-full
     cells (in cell order) hold beyond `bucket`, in row order.  A member
     placed there has `slot_of = n_cells*K + 1 + hot*spill_bucket + j`;
@@ -126,6 +130,10 @@ class CellTable(NamedTuple):
     spill_cells: int = 0
     spill_bucket: int = 0
     stats: Optional[CellStats] = None
+    # [n_cells] int32: a cell's row of the second level, -1 for a cell
+    # that has none.  Carried where a caller reads the level by cell
+    # (`build_cell_table`: the interest scan); combat reads it by rank.
+    hot_of: Optional[jnp.ndarray] = None
 
     @property
     def n_cells(self) -> int:
@@ -167,6 +175,48 @@ def auto_bucket(
     k = int(math.ceil(lam + 2.5 * math.sqrt(max(lam, 1.0)) + 2.0))
     k = max(lo, min(hi, k))
     return -(-k // align) * align
+
+
+# When a budget breach is answered by the second level, and how large
+# the level is made: one rule behind combat's tables (game/combat.py
+# `_answer_breach`, which has the counts behind each constant) and the
+# interest table (net/roles/game.py `_answer_interest_breach`).
+SPILL_MIN_OVERDEPTH = 4  # times over the base depth before it is thought of
+SPILL_MAX_GRID_SHARE = 0.5  # of the base table's slots, what was seen
+SPILL_CELLS_HEADROOM = 1.5
+SPILL_DEPTH_HEADROOM = 1.75
+
+
+def deep_cell(rows_max: int, bucket: int,
+              min_overdepth: float = SPILL_MIN_OVERDEPTH) -> bool:
+    """The fullest cell is so far over the base depth that doubling the
+    depth of every cell is the wrong answer to it."""
+    return rows_max > min_overdepth * bucket
+
+
+def few_hot_cells(hot_cells: int, rows_max: int, bucket: int, n_cells: int,
+                  max_grid_share: float = SPILL_MAX_GRID_SHARE) -> bool:
+    """A second level that just holds what was seen (over-full cells x
+    the deepest one's excess) takes at most `max_grid_share` of the base
+    table's slots: its streaming passes go by its slots as the base
+    level's go by the grid's."""
+    return (hot_cells * max(rows_max - bucket, 0)
+            <= max_grid_share * n_cells * bucket)
+
+
+def second_level_size(
+    hot_cells: int, rows_max: int, bucket: int,
+    cells_headroom: float = SPILL_CELLS_HEADROOM,
+    depth_headroom: float = SPILL_DEPTH_HEADROOM,
+) -> Tuple[int, int]:
+    """(cells, depth) that hold what a build observed, with headroom so
+    that the crowd's drift brings no second retrace, each rounded up to
+    a power of two so that worlds that differ by a seed trace the same
+    program; the depth 32 at least (whole blocks)."""
+    cells = 1 << math.ceil(math.log2(cells_headroom * max(hot_cells, 1)))
+    over = max(rows_max - bucket, 1)
+    depth = 1 << max(5, math.ceil(math.log2(depth_headroom * over)))
+    return cells, depth
 
 
 def _cell_keys(pos, active, cell_size: float, width: int,
@@ -294,7 +344,7 @@ def _slot_sources(
     count = start[1:] - start[:-1]
     levels = [(start[:-1], count, bucket)]
     if cells > 0 and depth > 0:
-        hot_no = jnp.cumsum((count > bucket).astype(i32))
+        hot_no = _hot_numbers(count, bucket)
         cell_at = jnp.searchsorted(
             hot_no, jnp.arange(1, cells + 1, dtype=i32), side="left",
             method="scan_unrolled").astype(i32)
@@ -304,6 +354,20 @@ def _slot_sources(
             start[cell_at] + bucket,
             jnp.where(there, count[cell_at] - bucket, 0), depth))
     return levels
+
+
+def _hot_numbers(count: jnp.ndarray, bucket: int) -> jnp.ndarray:
+    """Running count of over-full cells, in cell order: an over-full
+    cell `c` is the `hot_no[c] - 1`-th (`_spill_slots`' numbering)."""
+    return jnp.cumsum((count > bucket).astype(jnp.int32))
+
+
+def hot_index(start: jnp.ndarray, bucket: int, cells: int) -> jnp.ndarray:
+    """`CellTable.hot_of` from the cells' starts: the second-level row
+    of every over-full cell among the first `cells`, -1 elsewhere."""
+    count = start[1:] - start[:-1]
+    hot = _hot_numbers(count, bucket) - 1
+    return jnp.where((count > bucket) & (hot < cells), hot, -1)
 
 
 # a run of the run table: this many sorted entries, in one lane tile
@@ -362,7 +426,8 @@ def table_from_sorted(
     for level, (first, count, depth) in enumerate(levels):
         g = run_length(depth, f)
         heads = first[:, None] + g * jnp.arange(depth // g, dtype=jnp.int32)
-        got = run_table(g)[jnp.minimum(heads, n - 1)]
+        # (a table of occupancy alone gathers nothing)
+        got = run_table(g)[jnp.minimum(heads, n - 1)] if f else None
         live = jnp.arange(depth, dtype=jnp.int32) < count[:, None]
         for i in range(f):
             plane = got[..., i * g:(i + 1) * g].reshape(live.shape)
@@ -448,10 +513,9 @@ def table_from_slots(
     deterministic payload scatter (unique slot indices for placed rows),
     dump-slot zeroing, drop count.  This is the sort-free half of the
     build — the Verlet cache (ops/verlet.py) replays it every reuse tick
-    against the cached `slot_of` while skipping the argsort entirely,
-    and `build_cell_table` (the interest programs, tables 24 times
-    smaller than the 1M tick's) ends in it.  Where the sorted list is at
-    hand, `table_from_sorted` makes the same payload with no row sent.
+    against the cached `slot_of` while skipping the argsort entirely.
+    Where the sorted list is at hand (both builds of this file),
+    `table_from_sorted` makes the same payload with no row sent.
     Rows not `active` are forced to the dump slot regardless of their
     cached assignment (a cache is only reused while the active set is
     unchanged, but a zero-initialized cache must stay harmless).
@@ -478,6 +542,31 @@ def table_from_slots(
                      height, spill_cells, spill_bucket, stats)
 
 
+def _rank_full(
+    n_cells: int, key, active, bucket: int, spill_cells: int,
+    spill_bucket: int,
+):
+    """What a full table is ranked from, nothing here priced by a row
+    but the sorts and the un-sort of `slot_of`: (order, slot_of,
+    dropped, stats, start).  One sort of the keys, the slots of both
+    levels from the ranks (`_spill_slots`), their un-sort back to row
+    order (one scatter: what a pull reads; a caller that reads no
+    `slot_of` has it dropped by the compiler), the count of what fits
+    neither level, and every cell's start in the sorted list (a third
+    sort, a scatter of the cells' heads, streaming)."""
+    n = key.shape[0]
+    order, skey, rank = _key_segments(key)
+    sorted_slots, stats = _spill_slots(
+        n_cells, skey, rank, bucket, spill_cells, spill_bucket)
+    dump = n_cells * bucket
+    slot_of = jnp.full((n,), dump, jnp.int32).at[order].set(sorted_slots)
+    dropped = (
+        jnp.sum(active, dtype=jnp.int32)
+        - jnp.sum(sorted_slots != dump, dtype=jnp.int32)
+    )
+    return order, slot_of, dropped, stats, _cell_starts(n_cells, skey)
+
+
 def build_cell_table(
     pos: jnp.ndarray,
     active: jnp.ndarray,
@@ -485,21 +574,39 @@ def build_cell_table(
     cell_size: float,
     width: int,
     bucket: int,
+    spill_cells: int = 0,
+    spill_bucket: int = 0,
 ) -> CellTable:
-    """Bin `active` entities into the uniform grid, carrying `features`.
+    """Bin `active` entities into the uniform grid, carrying `features`:
+    the one-table case of `build_cell_table_pair`.
 
     pos: [N, >=2] positions; active: [N] bool; features: [N, F] float32.
-    One sort, one un-sort scatter of the slots (a sorted-gather + scatter
-    would be two N-sized irregular ops, ~1 ms per 131k rows each on a
-    v5e) and one payload scatter.  All slot indices are unique so the
-    payload scatter is deterministic.
-    """
+    Two sorts and a scatter of the cells' heads (`_rank_full`), the
+    payload gathered from the sorted list (`table_from_sorted`: no row
+    is sent; bit for bit what `table_from_slots` scatters to the same
+    slots), and `slot_of` un-sorted by one scatter for the callers that
+    pull through it.
+
+    `spill_cells`, `spill_bucket`: the SECOND LEVEL, as the pair build's
+    (`_spill_slots`): the first `spill_cells` over-full cells in cell
+    order keep `spill_bucket` rows beyond `bucket`, `dropped` counts
+    what fits neither level, `stats` what the build saw of its cells,
+    and `hot_of` names each cell's row of the level for a caller that
+    reads it by cell (ops/interest.py).  0 and 0: one level, and
+    nothing of the second is traced."""
     n_cells, key = _cell_keys(pos, active, cell_size, width)
-    order, skey, rank = _key_segments(key)
-    slot_of = _slots_from_ranks(
-        features.shape[0], n_cells, order, skey, rank, bucket)
-    return table_from_slots(
-        features, active, slot_of, n_cells, cell_size, width, bucket
+    if spill_cells and spill_bucket <= 0:
+        raise ValueError(
+            f"second level ({spill_cells}, {spill_bucket}): a depth of 0")
+    if spill_cells <= 0:
+        spill_cells = spill_bucket = 0
+    order, slot_of, dropped, stats, start = _rank_full(
+        n_cells, key, active, bucket, spill_cells, spill_bucket)
+    levels = _slot_sources(start, n_cells, bucket, spill_cells, spill_bucket)
+    hot_of = hot_index(start, bucket, spill_cells) if spill_cells else None
+    return CellTable(
+        table_from_sorted(features, order, levels), slot_of, dropped, width,
+        cell_size, bucket, -1, spill_cells, spill_bucket, stats, hot_of,
     )
 
 
@@ -573,21 +680,13 @@ def build_cell_table_pair(
         spill_cells, spill_bucket, sub_spill_bucket = spill
         if spill_cells and (spill_bucket <= 0 or sub_spill_bucket <= 0):
             raise ValueError(f"second level {spill}: a depth of 0")
-        order, skey, rank = _key_segments(key)
-        sorted_slots, stats = _spill_slots(
-            n_cells, skey, rank, bucket, spill_cells, spill_bucket)
-        # un-sort back to row order (one scatter): what the pull reads
-        dump = n_cells * bucket
-        slot_of = jnp.full((n,), dump, jnp.int32).at[order].set(sorted_slots)
-        dropped = (
-            jnp.sum(active, dtype=jnp.int32)
-            - jnp.sum(sorted_slots != dump, dtype=jnp.int32)
-        )
-        # where every slot of the full table finds its row in the sorted
-        # list: a third sort, a scatter of the cells' heads, streaming
+        # ranks, slots, the un-sort of `slot_of` (what the pull reads)
+        # and where every slot of the full table finds its row in the
+        # sorted list: a third sort, a scatter of the cells' heads
+        order, slot_of, dropped, stats, start = _rank_full(
+            n_cells, key, active, bucket, spill_cells, spill_bucket)
         levels = _slot_sources(
-            _cell_starts(n_cells, skey), n_cells, bucket, spill_cells,
-            spill_bucket)
+            start, n_cells, bucket, spill_cells, spill_bucket)
         # the subset, compacted by a second sort: members first, in cell
         # order, rows ascending inside a cell — the order their ranks
         # count in.  Heads, ranks and slots are streaming passes over

@@ -35,7 +35,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 #: entries the served game role must have compiled after the drive
-EXPECTED_GAME_ENTRIES = ("kernel.step", "interest.step/Player")
+EXPECTED_GAME_ENTRIES = ("kernel.step", "interest.build/Player")
 
 
 def _scrape(cluster, port: int, path: str):

@@ -6,8 +6,9 @@ PR at no chip time: the Pallas fold at the real 100k and 1M geometries
 and every depth a bucket boost can take them to (held against what
 `fold_engine` answers there), the program that seeds a 1M world, the
 default tick, the 1M tick with the kernel in it, one sharded tick over
-the described 2x2 mesh, and the clone-scene fleet's `rooms.step` at its
-benchmarked size.  A compile that passes is not a chip run: nothing
+the described 2x2 mesh, the clone-scene fleet's `rooms.step` at its
+benchmarked size, and the served siege's interest table build and scan
+at 2^20 rows with and without its second level.  A compile that passes is not a chip run: nothing
 executes, so no result or time is checked here.
 
 This is the only file that describes a topology.  The description
@@ -384,3 +385,41 @@ def test_siege_tick_with_its_second_level_compiles(one_chip, monkeypatch):
     # temporaries: 1.69 GB with the run tables of both levels (1.76 GB
     # when the table was scattered)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 1024 ** 3
+
+
+@pytest.mark.parametrize("sizes", [(44, 0, 0), (88, 4096, 2048)])
+def test_siege_interest_step_compiles_for_one_chip(one_chip, sizes):
+    """The served siege's visibility answer at its real size: 2^20 rows
+    binned into 198 x 198 interest cells, 32 observers, at the depth the
+    capacity alone sizes and at the sizes the role's breach policy gives
+    the crowd (a doubling, then 4,096 hot cells 2,048 deep).  The table
+    is built with no row sent: the only scatter is of the cells' heads
+    (`slot_of` is read by nobody here and dropped).  The level's scope
+    is in the program exactly when the level is."""
+    from noahgameframe_tpu.ops.interest import quantize, visible_candidates
+
+    n, sessions, extent, radius = 1 << 20, 32, 1581.1388, 8.0
+    width = int(np.ceil(extent / radius))
+    bucket, cells, depth = sizes
+
+    def step(pos, alive, scene, group, obs, obs_scene, obs_group):
+        q, inside = quantize(pos, alive, extent)
+        res = visible_candidates(
+            pos, inside, scene, group, obs, obs_scene, obs_group,
+            radius=radius, cell_size=radius, width=width, bucket=bucket,
+            spill=(cells, depth))
+        return q, res.rows, res.ok, res.stats
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(step).lower(
+        arg((n, 3)), arg((n,), jnp.bool_), arg((n,)), arg((n,)),
+        arg((sessions, 2)), arg((sessions,)), arg((sessions,))).compile()
+    text = compiled.as_text()
+    assert [s for s in _scatters(text) if "1048576" in s[1]] == []
+    assert f"s32[{sessions},{9 * (bucket + depth)}]" in text
+    assert ("nf.interest.spill" in text) == bool(cells)
+    assert "nf.interest.bin" in text and "nf.interest.scan" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 4 << 30
