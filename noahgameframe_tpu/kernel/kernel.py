@@ -64,6 +64,9 @@ REC_NONE, REC_ADDED, REC_REMOVED, REC_UPDATED = 0, 1, 2, 3
 # mod 2^32 and single-bit flips diffuse instead of cancelling)
 _DIGEST_MULT = 1000003
 
+# rows a word of a diff's bit planes holds (pack_diff_planes)
+DIFF_WORD = 32
+
 # Every per-tick output lane of _trace_step that host code consumes must
 # match one of these patterns (or appear in TRAIN_EXCLUDED with a
 # reason): the K-tick train stacks exactly these lanes into [K, ...]
@@ -105,6 +108,39 @@ def _assert_train_lanes(out: Dict[str, object]) -> None:
             "TRAIN_LANE_SPEC drift: "
             f"unlisted out lanes {unlisted}, stale patterns {stale}"
         )
+
+
+def pack_diff_planes(m: jnp.ndarray) -> jnp.ndarray:
+    """A diff mask `bool[capacity, cols]` as bit planes by column,
+    `uint32[cols, W]` with `W = ceil(capacity / 32)`: bit `b` of word `w`
+    of plane `col` is `m[b * W + w, col]` (rows past the capacity read
+    0).  `unpack_diff_plane` is the inverse.
+
+    The bit order follows how the chip keeps a bank: column-major, a
+    column's rows on the lanes (`s32[1048576,47]{0,1}`).  A word's 32
+    rows lie `W` rows apart, so row block `b` lands on bit `b` of the
+    words lane for lane and the fold is 32 elementwise passes, no
+    reduction across lanes (which 32 adjacent rows to a word would be:
+    PERF.md section 6, PR 34).  The mask stays a `bool` up to the fold
+    (a select of the bit's weight, not a widened mask shifted): what
+    the fold reads is a byte a cell."""
+    cap, cols = m.shape
+    words = -(-cap // DIFF_WORD)
+    if words * DIFF_WORD != cap:
+        m = jnp.pad(m, ((0, words * DIFF_WORD - cap), (0, 0)))
+    weight = np.uint32(1) << np.arange(DIFF_WORD, dtype=np.uint32)
+    bits = jnp.where(m.T.reshape(cols, DIFF_WORD, words),
+                     weight[None, :, None], np.uint32(0))
+    return jnp.sum(bits, axis=1, dtype=jnp.uint32)
+
+
+def unpack_diff_plane(plane: np.ndarray) -> np.ndarray:
+    """The rows one fetched plane of `pack_diff_planes` names, ascending
+    (`intp[n]`): the words' bits laid out bit-major as `bool[32, W]`,
+    whose flat index is the row, and scanned once (numpy scans a flat
+    `bool` array fastest)."""
+    bit = np.uint32(1) << np.arange(DIFF_WORD, dtype=np.uint32)
+    return np.flatnonzero((np.asarray(plane)[None, :] & bit[:, None]) != 0)
 
 
 def _digest_u32(x: jnp.ndarray) -> jnp.ndarray:
@@ -229,7 +265,9 @@ class TickOutputs:
     """Device-resident tick results; host fetches lazily."""
 
     fired: Dict[str, jnp.ndarray]  # class -> [C, T] bool
-    diff: Dict[str, Dict[str, jnp.ndarray]]  # class -> bank -> [C, ncols] bool
+    # class -> bank -> uint32[ncols, ceil(C / 32)]: the changed cells as
+    # bit planes by column (pack_diff_planes / unpack_diff_plane)
+    diff: Dict[str, Dict[str, jnp.ndarray]]
     diff_count: Dict[str, jnp.ndarray]  # class -> scalar changed-cell count
     died: Dict[str, jnp.ndarray]  # class -> [C] bool
     died_count: Dict[str, jnp.ndarray]  # class -> scalar
@@ -245,6 +283,11 @@ class TickOutputs:
     # (events fired, diff cells, deaths, combat hits, AOI overflow drops
     # + anything phases ctx.count()ed) — already on host, free to read
     counters: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # class -> bank -> int[ncols] changed cells a column, decoded from
+    # the summary fetch like the counters (their sum is diff_count)
+    diff_cols: Dict[str, Dict[str, np.ndarray]] = dataclasses.field(
+        default_factory=dict
+    )
 
 
 class Kernel(Module):
@@ -299,11 +342,13 @@ class Kernel(Module):
         self.train_dispatches = 0
         self.train_ticks = 0
         self.train_fetch_bytes = 0
-        # property fan-out accounting (nf_fanout_mask_*_total): diff masks
-        # read whole, one per (class, bank) with a subscribed column, on
-        # the ticks whose summary says the class changed
+        # property fan-out accounting (nf_fanout_mask_*_total): the
+        # (class, bank) diffs read as bit planes, one each on the ticks
+        # whose summary says a subscribed column of the bank changed,
+        # their bytes, and the columns unpacked to row lists
         self.fanout_mask_fetches = 0
         self.fanout_mask_bytes = 0
+        self.fanout_mask_columns = 0
         # monotonically bumped whenever the compiled tick is dropped
         # (invalidate / set_phases) so WRAPPING compilers — ShardedKernel
         # keeps its own jitted variants of _trace_step — can notice and
@@ -412,6 +457,7 @@ class Kernel(Module):
                 state = phase.fn(state, ctx)
 
         diff: Dict[str, Dict[str, jnp.ndarray]] = {}
+        diff_cols: Dict[str, Dict[str, jnp.ndarray]] = {}
         diff_count: Dict[str, jnp.ndarray] = {}
         rec_diff: Dict[str, Dict[str, jnp.ndarray]] = {}
         rec_diff_count: Dict[str, jnp.ndarray] = {}
@@ -421,37 +467,36 @@ class Kernel(Module):
             for cname in self.store.class_order:
                 spec = self.store.spec(cname)
                 oc, nc = old.classes[cname], state.classes[cname]
-                masks: Dict[str, jnp.ndarray] = {}
-                total = jnp.zeros((), jnp.int32)
-                flag_union = {}
-                for bank, nm in ((Bank.I32, "i32"), (Bank.F32, "f32"), (Bank.VEC, "vec")):
-                    fm = np.zeros(spec.bank_size(bank), bool)
-                    for fl in self._diff_flags:
-                        fm |= spec.mask(bank, fl)
-                    for pname in self._forced_diff.get(cname, ()):
-                        slot = spec.slot(pname)
-                        if slot.bank == bank:
-                            fm[slot.col] = True
-                    flag_union[nm] = fm
-                if flag_union["i32"].any():
-                    m = (oc.i32 != nc.i32) & nc.alive[:, None] & flag_union["i32"][None, :]
-                    masks["i32"] = m
-                    total = total + jnp.sum(m, dtype=jnp.int32)
-                if flag_union["f32"].any():
-                    m = (oc.f32 != nc.f32) & nc.alive[:, None] & flag_union["f32"][None, :]
-                    masks["f32"] = m
-                    total = total + jnp.sum(m, dtype=jnp.int32)
-                if flag_union["vec"].any():
-                    m = (
-                        jnp.any(oc.vec != nc.vec, axis=-1)
-                        & nc.alive[:, None]
-                        & flag_union["vec"][None, :]
-                    )
-                    masks["vec"] = m
-                    total = total + jnp.sum(m, dtype=jnp.int32)
-                if masks:
-                    diff[cname] = masks
-                    diff_count[cname] = total
+                planes: Dict[str, jnp.ndarray] = {}
+                cols: Dict[str, jnp.ndarray] = {}
+                totals: List[jnp.ndarray] = []
+                for bank in (Bank.I32, Bank.F32, Bank.VEC):
+                    fm = self.diff_columns(cname, bank)
+                    if not fm.any():
+                        continue
+                    nm = bank.value
+                    ne = getattr(oc, nm) != getattr(nc, nm)
+                    if bank == Bank.VEC:
+                        ne = jnp.any(ne, axis=-1)
+                    m = ne & nc.alive[:, None] & fm[None, :]
+                    if self.room_batch is not None:
+                        # no room reads a diff: a fleet's tick keeps the
+                        # changed cells' count and makes no planes
+                        totals.append(jnp.sum(m, dtype=jnp.int32))
+                        continue
+                    # the mask leaves the device as bit planes by column
+                    # and changed cells a column, counted off the planes
+                    # so the banks are compared, and read, once
+                    planes[nm] = pack_diff_planes(m)
+                    cols[nm] = jnp.sum(
+                        jax.lax.population_count(planes[nm]),
+                        axis=1, dtype=jnp.int32)
+                    totals.append(jnp.sum(cols[nm]))
+                if planes:
+                    diff[cname] = planes
+                    diff_cols[cname] = cols
+                if totals:
+                    diff_count[cname] = sum(totals, jnp.zeros((), jnp.int32))
                 # record-row diffs: add/remove/update codes per (entity, row),
                 # only for subscribed records (device phases mutate records —
                 # buff expiry, stat groups — and those changes must reach the
@@ -542,6 +587,14 @@ class Kernel(Module):
                     jnp.stack(ev_counts)
                     if ctx.emitted
                     else jnp.zeros((0,), jnp.int32),
+                    # changed cells a column, by (class, bank) in sorted
+                    # order: which planes the fan-out fetches, learnt
+                    # with no read of its own
+                    *(
+                        diff_cols[c][b]
+                        for c in sorted(diff_cols)
+                        for b in sorted(diff_cols[c])
+                    ),
                     jnp.stack([counters[k] for k in self._counter_names]),
                 ]
             )
@@ -557,6 +610,19 @@ class Kernel(Module):
             "summary": summary,
         }
         return state, out
+
+    def diff_columns(self, class_name: str, bank: Bank) -> np.ndarray:
+        """`bool[ncols]`: the columns of a class's bank the tick extracts
+        a diff for (a diff flag, or opted in by force_diff_property)."""
+        spec = self.store.spec(class_name)
+        fm = np.zeros(spec.bank_size(bank), bool)
+        for fl in self._diff_flags:
+            fm |= spec.mask(bank, fl)
+        for pname in self._forced_diff.get(class_name, ()):
+            slot = spec.slot(pname)
+            if slot.bank == bank:
+                fm[slot.col] = True
+        return fm
 
     def compile(self) -> None:
         if self._jit_step is None:
@@ -860,15 +926,21 @@ class Kernel(Module):
                    exact_deaths: bool = False) -> None:
         n_cls = len(self.store.class_order)
         died_counts = summary[:n_cls]
-        diff_keys = sorted(out.diff_count)
-        diff_counts = dict(zip(diff_keys, summary[n_cls : n_cls + len(diff_keys)]))
-        off = n_cls + len(diff_keys)
+        off = n_cls + len(out.diff_count)
         rec_keys = sorted(out.rec_diff_count)
         rec_counts = dict(zip(rec_keys, summary[off : off + len(rec_keys)]))
         off2 = off + len(rec_keys)
-        # bounded slice: the on-device counter bank rides AFTER the event
-        # counts, so an open-ended slice would absorb it
+        # bounded slice: the per-column diff counts and the on-device
+        # counter bank ride AFTER the event counts, so an open-ended
+        # slice would absorb them
         event_counts = summary[off2 : off2 + len(out.events)]
+        off3 = off2 + len(out.events)
+        for cname in sorted(out.diff):
+            out.diff_cols[cname] = {}
+            for bank_name in sorted(out.diff[cname]):
+                n = out.diff[cname][bank_name].shape[0]
+                out.diff_cols[cname][bank_name] = summary[off3 : off3 + n]
+                off3 += n
         # device-emitted events FIRST — entities that died this tick must
         # still deliver their events (the reference fires events before
         # destroy), so guid identities are intact here
@@ -897,7 +969,7 @@ class Kernel(Module):
                     self._fire_class_event(g, cname, ObjectEvent.DESTROY)
         # property-change host subscribers (batch granularity)
         with span("fanout.props"):
-            self._fanout_props(out, diff_counts)
+            self._fanout_props(out)
         # record-diff subscribers (device-path record mutations)
         with span("fanout.records"):
             for (cname, rname), fns in self._rec_event_subs.items():
@@ -911,38 +983,41 @@ class Kernel(Module):
                     for fn in fns:
                         fn(cname, rname, codes)
 
-    def _fanout_props(self, out: TickOutputs, diff_counts: Dict[str, int]) -> None:
+    def _fanout_props(self, out: TickOutputs) -> None:
         """Call the property subscribers with the rows this frame's diff
-        masks name.  Each (class, bank) mask that carries a subscribed
-        column of a class the summary says changed is read ONCE, whole,
-        and columns are taken on the host: indexing the device array per
-        property costs a dispatch and a blocking read each (~1.65 ms on a
-        v5e, 41 of them a served frame), the bulk read one transfer."""
+        names.  The summary's per-column counts say which subscribed
+        columns changed; the bit planes of the (class, bank) pairs that
+        hold one come over in ONE transfer, an eighth of a byte a cell,
+        and each such column's plane is unpacked to its row list.  No
+        device array is indexed: a column taken on the device costs a
+        dispatch and a blocking read each (~1.65 ms on a v5e, 41 of them
+        a served frame)."""
+        span = self.tracer.span
         cols: Dict[Tuple[str, str], Tuple[str, int]] = {}
         need: Dict[Tuple[str, str], Any] = {}
         for cname, pname in self._prop_event_subs:
-            masks = out.diff.get(cname)
-            if not masks or int(diff_counts[cname]) == 0:
-                continue
             slot = self.store.spec(cname).slot(pname)
             bank_name = slot.bank.value
-            m = masks.get(bank_name)
-            if m is None:
+            counts = out.diff_cols.get(cname, {}).get(bank_name)
+            if counts is None or not counts[slot.col]:
                 continue
             cols[cname, pname] = (bank_name, slot.col)
-            need[cname, bank_name] = m
+            need[cname, bank_name] = out.diff[cname][bank_name]
         if not need:
             return
-        host = jax.device_get(need)
+        with span("fanout.props.fetch"):
+            host = jax.device_get(need)
         self.fanout_mask_fetches += len(host)
-        self.fanout_mask_bytes += sum(m.nbytes for m in host.values())
-        changed = {key: m.any(axis=0) for key, m in host.items()}
-        for (cname, pname), (bank_name, col) in cols.items():
-            if not changed[cname, bank_name][col]:
-                continue
-            rows = np.flatnonzero(host[cname, bank_name][:, col])
+        self.fanout_mask_bytes += sum(p.nbytes for p in host.values())
+        self.fanout_mask_columns += len(cols)
+        with span("fanout.props.unpack"):
+            rows = [
+                unpack_diff_plane(host[cname, bank_name][col])
+                for (cname, _), (bank_name, col) in cols.items()
+            ]
+        for (cname, pname), changed in zip(cols, rows):
             for fn in self._prop_event_subs[cname, pname]:
-                fn(cname, pname, rows)
+                fn(cname, pname, changed)
 
     # -- object lifecycle (host control plane) ------------------------------
 

@@ -10,10 +10,10 @@ sync messages sent via the proxy with explicit client lists
 (`OnPropertyEnter` `:271-400` and the §3.3 data-flow spine).
 
 TPU inversion: instead of per-write callbacks, the role pulls each tick's
-flag-masked diff masks off the device (already reduced by the jit'd step)
-and fans the changed cells out as grouped property-sync messages to every
-player in the broadcast set — one device fetch per bank per tick instead
-of one callback per write.
+flag-masked diff off the device as bit planes by column (already counted
+per column by the jit'd step) and fans the changed cells out as grouped
+property-sync messages to every player in the broadcast set — one device
+fetch per changed bank per tick instead of one callback per write.
 """
 
 from __future__ import annotations
@@ -398,7 +398,7 @@ class GameRole(ServerRole):
         self.kernel.register_class_event(self._on_npc_event, "NPC")
         # subscribe every public OR private property of the synced classes;
         # the kernel fires these for host writes synchronously AND from the
-        # device diff masks after each tick — one mechanism for the whole
+        # device diff planes after each tick — one mechanism for the whole
         # spine.  Public changes broadcast to the (scene, group); private-
         # only changes go to the owner's client (GetBroadCastObject
         # semantics, NFCSceneAOIModule.cpp:531-593).
@@ -619,13 +619,18 @@ class GameRole(ServerRole):
         # property fan-out accounting (mirrors kernel.fanout_mask_*)
         self._fanout_mask_fetches = sreg.counter(
             "nf_fanout_mask_fetches_total",
-            "diff masks read whole for the property fan-out, one per "
-            "(class, bank) with a subscribed column on a tick that "
-            "changed the class",
+            "diffs read as bit planes for the property fan-out, one per "
+            "(class, bank) on a tick that changed a subscribed column "
+            "of the bank",
         )
         self._fanout_mask_bytes = sreg.counter(
             "nf_fanout_mask_bytes_total",
-            "bytes of diff mask the property fan-out read from the device",
+            "bytes of bit planes the property fan-out read from the device",
+        )
+        self._fanout_mask_columns = sreg.counter(
+            "nf_fanout_mask_columns_total",
+            "subscribed, changed columns the property fan-out unpacked "
+            "to row lists",
         )
         self._stage_timing = stage_timing_enabled()
         self.kernel.stage_timing = self._stage_timing
@@ -1970,8 +1975,9 @@ class GameRole(ServerRole):
             with sc.stage("tick"):
                 t0 = _time.perf_counter()
                 pm.execute_modules()
-                f0, fb0 = (self.kernel.fanout_mask_fetches,
-                           self.kernel.fanout_mask_bytes)
+                f0, fb0, fc0 = (self.kernel.fanout_mask_fetches,
+                                self.kernel.fanout_mask_bytes,
+                                self.kernel.fanout_mask_columns)
                 if pend_classes:
                     # double-buffered serve: fetch the deferred lanes'
                     # deltas from the pre-tick state (the donated buffers
@@ -2009,6 +2015,8 @@ class GameRole(ServerRole):
                     self.kernel.fanout_mask_fetches - f0)
                 self._fanout_mask_bytes.inc(
                     self.kernel.fanout_mask_bytes - fb0)
+                self._fanout_mask_columns.inc(
+                    self.kernel.fanout_mask_columns - fc0)
                 if self.rooms is not None:
                     # the attached fleet keeps the world's clock: one
                     # vmapped frame for every room slot per world tick

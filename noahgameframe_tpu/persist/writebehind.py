@@ -4,7 +4,7 @@ without ever blocking the tick.
 The reference dedicates a whole async role to exactly this —
 NFCAsyMysqlModule pushes player saves onto an actor queue so MySQL
 round-trips never stall the main loop.  Here the kernel already computes
-exactly what changed per tick (the device diff masks the GameRole drains
+exactly what changed per tick (the device diff planes the GameRole drains
 for sync), so durability is a *tap* on that spine: the role snapshots
 each dirty entity's Save-flagged pack (persist.codec) and hands
 ``{key: blob}`` to this pipeline; a background flusher owns every store

@@ -6,6 +6,7 @@ plugin-manager lifecycle (reference Tutorial/Tutorial3/HelloWorld3Module).
 """
 
 import contextlib
+import functools
 import logging
 
 import jax
@@ -14,12 +15,18 @@ import numpy as np
 import pytest
 
 from noahgameframe_tpu.core import StoreConfig
+from noahgameframe_tpu.core.datatypes import Bank
 from noahgameframe_tpu.kernel import (
     Kernel,
     Module,
     ObjectEvent,
     Plugin,
     PluginManager,
+)
+from noahgameframe_tpu.kernel.kernel import (
+    DIFF_WORD,
+    pack_diff_planes,
+    unpack_diff_plane,
 )
 
 from fixtures import base_registry
@@ -384,21 +391,49 @@ def build_churn(subscribe=True, cap=64):
     return kernel, calls
 
 
-def calls_from_raw_masks(kernel, outs):
-    """The fan-out worked out with plain numpy from each tick's raw
-    `out.diff` masks: per tick, per subscribed (class, property) in order
-    of first registration, the changed rows, once per subscriber."""
+@functools.lru_cache(maxsize=None)
+def reference_diffs(ticks):
+    """Plain numpy over the banks of a twin world ticked a frame at a
+    time, nobody subscribed: per tick, class -> bank -> `bool[capacity,
+    cols]`, the extracted cells of alive rows that changed."""
+    twin, _ = build_churn(subscribe=False)
+    twin.force_diff_property("NPC", "ATK_VALUE")
+    frames = []
+    for _ in range(ticks):
+        before = jax.device_get(twin.state.classes)
+        twin.tick()
+        after = jax.device_get(twin.state.classes)
+        frame = {}
+        for cname in twin.store.class_order:
+            for bank in Bank:
+                fm = twin.diff_columns(cname, bank)
+                if not fm.any():
+                    continue
+                ne = (getattr(before[cname], bank.value)
+                      != getattr(after[cname], bank.value))
+                if bank == Bank.VEC:
+                    ne = ne.any(axis=-1)
+                frame.setdefault(cname, {})[bank.value] = (
+                    ne & after[cname].alive[:, None] & fm[None, :])
+        frames.append(frame)
+    return frames
+
+
+def calls_from_reference(kernel, frames):
+    """The fan-out worked out from `reference_diffs`: per tick, per
+    subscribed (class, property) in order of first registration, the
+    changed rows, once per subscriber."""
     order = {}
     for cname, pname, tag in FANOUT_SUBS:
         order.setdefault((cname, pname), []).append(tag)
     want = []
-    for out in outs:
+    for frame in frames:
         for (cname, pname), tags in order.items():
             slot = kernel.store.spec(cname).slot(pname)
-            m = out.diff.get(cname, {}).get(slot.bank.value)
+            m = frame.get(cname, {}).get(slot.bank.value)
             if m is None:
                 continue
-            rows = np.flatnonzero(np.asarray(m)[:, slot.col])
+            rows = np.flatnonzero(m[:, slot.col])
             if rows.size:
                 want += [(tag, cname, pname, rows.tolist()) for tag in tags]
     return want
@@ -418,12 +453,12 @@ def _drive(kernel, path, ticks):
 
 
 @pytest.mark.parametrize("path", ["tick", "train", "sharded"])
-def test_property_fanout_equals_raw_diff_masks(path):
+def test_property_fanout_equals_reference_diff(path):
     kernel, calls = build_churn()
     outs = _drive(kernel, path, 8)
     assert len(outs) == 8
     got = [(tag, c, p, rows.tolist()) for tag, c, p, rows in calls]
-    assert got == calls_from_raw_masks(kernel, outs)
+    assert got == calls_from_reference(kernel, reference_diffs(8))
     for _, _, _, rows in calls:
         assert rows.dtype == np.intp and rows.ndim == 1 and rows.size
         assert (np.diff(rows) > 0).all()
@@ -437,16 +472,69 @@ def test_property_fanout_equals_raw_diff_masks(path):
                        ("NPC", "MoveSpeed"), ("NPC", "TargetPos")}
 
 
-class _HostOnly:
-    """Stands where a diff mask stands: it can be read whole, and
-    counts the reads; indexing it (a device program per call) fails."""
+@pytest.mark.parametrize("path", ["tick", "train", "sharded"])
+def test_diff_planes_and_column_counts_equal_reference_diff(path):
+    """What a tick hands over in place of the masks: every plane unpacks
+    to its column of the reference diff, the summary's per-column counts
+    are that column's cells, and their sum is the class's `diff_count`."""
+    kernel, _ = build_churn()
+    outs = _drive(kernel, path, 8)
+    frames = reference_diffs(8)
+    assert any(m.any() for f in frames for d in f.values() for m in d.values())
+    for out, frame in zip(outs, frames):
+        assert {c: set(d) for c, d in out.diff.items()} == {
+            c: set(d) for c, d in frame.items()}
+        cells = 0
+        for cname, banks in frame.items():
+            cap = kernel.store.capacity(cname)
+            for bank_name, m in banks.items():
+                planes = np.asarray(out.diff[cname][bank_name])
+                assert planes.dtype == np.uint32
+                assert planes.shape == (m.shape[1], -(-cap // DIFF_WORD))
+                for col in range(m.shape[1]):
+                    assert unpack_diff_plane(planes[col]).tolist() == \
+                        np.flatnonzero(m[:, col]).tolist()
+                assert out.diff_cols[cname][bank_name].tolist() == \
+                    m.sum(axis=0).tolist()
+            per_class = sum(int(m.sum()) for m in banks.values())
+            assert int(out.diff_count[cname]) == per_class == sum(
+                int(c.sum()) for c in out.diff_cols[cname].values())
+            cells += per_class
+        assert out.counters["diff_cells"] == cells
 
-    def __init__(self, mask, reads):
-        self._mask, self._reads = mask, reads
+
+@pytest.mark.parametrize("fill", ["sparse", "half", "empty", "full"])
+@pytest.mark.parametrize("n", [8, 33, 128, 1 << 17])
+def test_diff_planes_round_trip(n, fill):
+    """`unpack_diff_plane` inverts `pack_diff_planes` at capacities that
+    are and are not a multiple of 32; the rows come out ascending."""
+    rng = np.random.default_rng(n)
+    p = {"sparse": 0.01, "half": 0.5, "empty": 0.0, "full": 1.0}[fill]
+    m = rng.random((n, 5)) < p
+    m[:, 4] = False  # an empty column beside the others
+    planes = np.asarray(jax.jit(pack_diff_planes)(jnp.asarray(m)))
+    assert planes.dtype == np.uint32
+    assert planes.shape == (5, -(-n // DIFF_WORD))
+    for col in range(5):
+        rows = unpack_diff_plane(planes[col])
+        assert rows.dtype == np.intp
+        assert rows.tolist() == np.flatnonzero(m[:, col]).tolist()
+    assert [int(c) for c in np.unpackbits(
+        planes.view(np.uint8), axis=1).sum(axis=1)] == m.sum(axis=0).tolist()
+
+
+class _HostOnly:
+    """Stands where a bank's diff planes stand: it can be read whole,
+    and counts the reads; indexing it (a device program per call)
+    fails."""
+
+    def __init__(self, planes, reads, key=None):
+        self._planes, self._reads, self._key = planes, reads, key
+        self.shape = planes.shape
 
     def __array__(self, *a, **kw):
-        self._reads.append(self._mask.shape)
-        return np.asarray(self._mask)
+        self._reads.append((self._key, self._planes.shape))
+        return np.asarray(self._planes)
 
     def __getitem__(self, idx):
         raise AssertionError("the fan-out indexed a device array")
@@ -471,40 +559,61 @@ class _SpanProbe:
 @pytest.mark.parametrize("path", ["tick", "train", "sharded"])
 def test_fanout_mask_counters_and_round_trips(path):
     """More than three subscribed properties a class cost at most one
-    read per (class, bank); a tick that changed nothing costs none."""
+    read per (class, bank), and only of a bank a subscribed column of
+    which changed; a tick that changed nothing costs none."""
     kernel, calls = build_churn()
     pairs = {(c, kernel.store.spec(c).slot(p).bank.value)
              for c, p, _ in FANOUT_SUBS}
     assert len({p for c, p, _ in FANOUT_SUBS if c == "NPC"}) > 3
-    reads, per_tick, masked = [], [], set()
+    reads, per_tick, extracted = [], [], set()
     post = kernel._post_tick
 
     def guarded(out, summary, **kw):
-        masked.update((c, b) for c, d in out.diff.items() for b in d)
-        out.diff = {c: {b: _HostOnly(m, reads) for b, m in d.items()}
+        extracted.update((c, b) for c, d in out.diff.items() for b in d)
+        out.diff = {c: {b: _HostOnly(m, reads, (c, b)) for b, m in d.items()}
                     for c, d in out.diff.items()}
         was = (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
-               len(reads), len(calls))
+               kernel.fanout_mask_columns, len(reads), len(calls))
+        n0 = len(calls)
         post(out, summary, **kw)
         now = (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
-               len(reads), len(calls))
-        per_tick.append(tuple(b - a for a, b in zip(was, now)))
+               kernel.fanout_mask_columns, len(reads), len(calls))
+        per_tick.append(tuple(b - a for a, b in zip(was, now))
+                        + (len({(c, p) for _, c, p, _ in calls[n0:]}),))
 
     kernel._post_tick = guarded
     _drive(kernel, path, 8)
     assert len(per_tick) == 8
-    # NPC has no flagged f32 column, so no f32 mask: five pairs of six
-    pairs &= masked
+    # NPC has no flagged f32 column, so no f32 planes: five pairs of six
+    pairs &= extracted
     assert len(pairs) == 5
-    for fetches, nbytes, n_reads, n_calls in per_tick:
+    for fetches, nbytes, columns, n_reads, n_calls, props in per_tick:
         assert fetches == n_reads <= len(pairs)
         assert (fetches > 0) == (nbytes > 0) == (n_calls > 0)
+        assert fetches <= columns == props  # a column unpacked a property
     # state.tick 3 and 7 (the fourth and eighth ticks) change nothing
-    assert [f for f, *_ in per_tick][3::4] == [0, 0]
-    assert max(f for f, *_ in per_tick) == len(pairs)
+    assert [t[:3] for t in per_tick][3::4] == [(0, 0, 0)] * 2
+    # Player's vec bank has a subscriber (Position) and never changes:
+    # its planes are never read
+    assert {key for key, _ in reads} == pairs - {("Player", "vec")}
     assert kernel.fanout_mask_fetches == len(reads)
-    assert kernel.fanout_mask_bytes == sum(
-        int(np.prod(shape)) for shape in reads)
+    assert kernel.fanout_mask_bytes == 4 * sum(
+        int(np.prod(shape)) for _, shape in reads)
+
+
+def test_unsubscribed_change_reads_no_plane():
+    """A frame that changes cells, none in a subscribed column: the
+    summary's column counts say so and no plane is fetched."""
+    kernel, _ = build_churn(subscribe=False)
+    calls = []
+    for pname in ("MAXHP", "TargetPos"):  # flagged columns nobody writes
+        kernel.register_property_event(
+            "NPC", pname, lambda c, p, rows: calls.append((c, p)))
+    outs = [kernel.tick() for _ in range(4)]
+    assert any(int(o.diff_count["NPC"]) for o in outs)
+    assert calls == []
+    assert (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
+            kernel.fanout_mask_columns) == (0, 0, 0)
 
 
 def test_fanout_counters_stay_zero_without_subscribers():
@@ -512,7 +621,8 @@ def test_fanout_counters_stay_zero_without_subscribers():
     kernel, _ = build_churn(subscribe=False)
     outs = [kernel.tick() for _ in range(4)]
     assert any(int(v) for o in outs for v in o.diff_count.values())
-    assert (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes) == (0, 0)
+    assert (kernel.fanout_mask_fetches, kernel.fanout_mask_bytes,
+            kernel.fanout_mask_columns) == (0, 0, 0)
 
 
 def test_fanout_props_compiles_nothing(caplog):

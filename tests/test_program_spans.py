@@ -44,6 +44,8 @@ HOST = {
     "nf.fanout.events": "nf.kernel.fanout",
     "nf.fanout.deaths": "nf.kernel.fanout",
     "nf.fanout.props": "nf.kernel.fanout",
+    "nf.fanout.props.fetch": "nf.fanout.props",
+    "nf.fanout.props.unpack": "nf.fanout.props",
     "nf.fanout.records": "nf.kernel.fanout",
     "nf.trace.emit": "nf.role.game",
     "nf.trace.relay": "nf.role.proxy",

@@ -144,10 +144,10 @@ def test_counter_bank_matches_host_recount():
         out = k.tick()
         deaths = sum(int(np.asarray(m).sum()) for m in out.died.values())
         events = sum(int(np.asarray(ev.mask).sum()) for ev in out.events)
-        diff_cells = sum(
-            int(np.asarray(m).sum())
-            for masks in out.diff.values()
-            for m in masks.values()
+        diff_cells = sum(  # the bits set in the diff's planes
+            int(np.unpackbits(np.asarray(p).view(np.uint8)).sum())
+            for planes in out.diff.values()
+            for p in planes.values()
         )
         rec_cells = sum(
             int((np.asarray(code) != 0).sum())

@@ -302,43 +302,81 @@ def _sends_no_victim_row(text, bank, slots):
     assert loops == [], "the build's index passes loop"
 
 
+_TICKS_1M = {}
+
+
+def _compile_1m_tick(one_chip, boost, depths):
+    """`kernel.step` of `npc-1m` traced for the chip, once a boost for
+    the tests that read it.  The world is built small and its NPC bank
+    described at 2^20 rows: every shape of the tick follows from the
+    bank's and from the module's extent."""
+    if boost in _TICKS_1M:
+        return _TICKS_1M[boost]
+    from noahgameframe_tpu.game import GameWorld, WorldConfig
+
+    was, sp.trace_platform = sp.trace_platform, lambda: "tpu"
+    try:
+        extent = float(np.sqrt(1_000_000 / 0.4))
+        w = GameWorld(WorldConfig(npc_capacity=128, extent=extent, seed=0,
+                                  middleware=False))
+        w.start()
+        w.scene.create_scene(1, width=extent)
+        k, combat = w.kernel, w.combat
+        k._ensure_aux()
+        combat._attacker_duty = 1.0 / 30.0  # arm_all's staggered arming
+        combat._bucket_boost = boost
+        cap = 1 << 20
+        assert (combat.width, combat.resolved_bucket(cap),
+                combat.resolved_att_bucket(cap)) == (395,) + depths
+        state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
+        state = state.replace(classes={**state.classes, "NPC": jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((cap,) + x.shape[1:], x.dtype,
+                                           sharding=one_chip),
+            state.classes["NPC"])})
+        compiled = jax.jit(k._trace_step,
+                           donate_argnums=0).lower(state).compile()
+    finally:
+        sp.trace_platform = was
+    _TICKS_1M[boost] = (compiled, combat.engine_baked)
+    return _TICKS_1M[boost]
+
+
 @pytest.mark.parametrize("boost,depths", [(1, (16, 6)), (2, (32, 12))])
-def test_1m_tick_bakes_one_kernel(one_chip, monkeypatch, boost, depths):
+def test_1m_tick_bakes_one_kernel(one_chip, boost, depths):
     """`kernel.step` of `npc-1m` at the depths it is built with and at
     those its window runs at (boost 2: 32/12), traced for the chip: the
     fold is the Pallas kernel and it is the program's only custom call,
     and the victim table is gathered from the sorted list: no scatter
     sends the bank's 2^20 rows to it (until PR 31 one did, 89 ms of a
-    147 ms tick).  The world is built small and its
-    NPC bank described at 2^20 rows: every shape of the tick follows
-    from the bank's and from the module's extent."""
-    from noahgameframe_tpu.game import GameWorld, WorldConfig
-
-    monkeypatch.setattr(sp, "trace_platform", lambda: "tpu")
-    extent = float(np.sqrt(1_000_000 / 0.4))
-    w = GameWorld(WorldConfig(npc_capacity=128, extent=extent, seed=0,
-                              middleware=False))
-    w.start()
-    w.scene.create_scene(1, width=extent)
-    k, combat = w.kernel, w.combat
-    k._ensure_aux()
-    combat._attacker_duty = 1.0 / 30.0  # arm_all's staggered arming
-    combat._bucket_boost = boost
-    cap = 1 << 20
-    assert (combat.width, combat.resolved_bucket(cap),
-            combat.resolved_att_bucket(cap)) == (395,) + depths
-    state = _shapes(k.state, jax.tree.map(lambda _: one_chip, k.state))
-    state = state.replace(classes={**state.classes, "NPC": jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct((cap,) + x.shape[1:], x.dtype,
-                                       sharding=one_chip),
-        state.classes["NPC"])})
-    compiled = jax.jit(k._trace_step, donate_argnums=0).lower(state).compile()
-    assert combat.engine_baked == 1
+    147 ms tick)."""
+    compiled, engine_baked = _compile_1m_tick(one_chip, boost, depths)
+    assert engine_baked == 1
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     _sends_no_victim_row(text, "1048576", 395 * 395 * depths[0] + 1)
     # 1.09 GB at 32/12, the run table among them (0.70 GB scattered)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+def test_1m_tick_hands_over_planes_not_masks(one_chip):
+    """The 1M tick's diff leaves the program as bit planes by column,
+    128 KB a column, and no `pred[2^20, C]` mask is among its outputs
+    (until PR 34 two were, 51 MB a frame for the serving host to fetch).
+    The per-column counts ride the summary: no output of their own."""
+    compiled, _ = _compile_1m_tick(one_chip, 2, (32, 12))
+    _, out = compiled.out_info
+    diff = {(c, b): (i.shape, str(i.dtype))
+            for c, banks in out["diff"].items() for b, i in banks.items()}
+    assert {dt for _, dt in diff.values()} == {"uint32"}
+    assert diff["NPC", "i32"][0] == (47, (1 << 20) // 32)
+    assert diff["NPC", "vec"][0] == (2, (1 << 20) // 32)
+    wide = [i.shape for i in jax.tree.leaves(compiled.out_info)
+            if str(i.dtype) == "bool" and i.shape == (1 << 20, 47)]
+    assert wide == []
+    assert "diff_cols" not in out
+    # the compare's mask is a temporary, folded where it is made: the
+    # program text has no widened copy of it
+    assert "u32[1048576,47]" not in compiled.as_text()
 
 
 def test_siege_tick_with_its_second_level_compiles(one_chip, monkeypatch):

@@ -384,6 +384,9 @@ def test_role_mirrors_fanout_mask_counters(mode):
         assert k.fanout_mask_fetches > 0  # NPCs walk every tick
         assert reg.value("nf_fanout_mask_fetches_total") == k.fanout_mask_fetches
         assert reg.value("nf_fanout_mask_bytes_total") == k.fanout_mask_bytes
+        assert k.fanout_mask_columns >= k.fanout_mask_fetches
+        assert reg.value("nf_fanout_mask_columns_total") == \
+            k.fanout_mask_columns
     finally:
         role.shut()
 
