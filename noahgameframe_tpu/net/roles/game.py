@@ -227,6 +227,9 @@ class GameRole(ServerRole):
         # (visible sets can change without any Position diff)
         self._interest_dirty: set = set()
         self._last_obs_sig: Optional[tuple] = None
+        # this flush's answer per class: each session's visible rows,
+        # ascending (`_visible_sets`)
+        self._frame_vis: Dict[str, List[np.ndarray]] = {}
         # --- batched serving edge (ISSUE 13) -------------------------
         # NF_SERVE_BATCH=1 swaps the per-session Python serve loops for
         # one vmap-over-sessions device kernel (ops/serving.py) plus
@@ -563,6 +566,11 @@ class GameRole(ServerRole):
             "nf_interest_builds_total",
             "interest-table builds dispatched, each with the scans that "
             "read it (one per nf.interest.dispatch span)", ("cls", "lane"),
+        )
+        self._interest_shared = sreg.counter(
+            "nf_interest_shared_total",
+            "property and record lanes answered from the flush's visible "
+            "sets without a build", ("cls", "lane"),
         )
         self._serve_packets = sreg.counter(
             "nf_serve_packets_total",
@@ -2526,54 +2534,33 @@ class GameRole(ServerRole):
 
     def _interest_targets(self, cname: str, rows: np.ndarray,
                           lane: str) -> Dict[int, List[Guid]]:
-        """Per-row visible OBSERVERS for the per-entity sync lanes: one
-        device interest query over the changed rows, inverted into
-        row -> [observer avatar guid].  With a radius set, "Public"
-        means public to whoever can SEE you — not to the whole group
-        (round-4 verdict item 4; reference broadcast scope is the
-        coarse (scene, group), NFCSceneAOIModule.cpp:531-593)."""
-        import jax.numpy as jnp
-
+        """Per-row visible OBSERVERS for the per-entity sync lanes: the
+        flush's visible sets (`_visible_sets`) filtered to the changed
+        rows and inverted into row -> [observer avatar guid], observers
+        in session order.  With a radius set, "Public" means public to
+        whoever can SEE you — not to the whole group (round-4 verdict
+        item 4; reference broadcast scope is the coarse (scene, group),
+        NFCSceneAOIModule.cpp:531-593)."""
         out: Dict[int, List[Guid]] = {}
-        if rows.size == 0:
+        if rows.size == 0 or not self._has_observers():
             return out
-        obs, obs_rows, obs_valid = self._observer_arrays()
-        if not obs:
-            return out
-        k = self.kernel
-        cs = k.state.classes[cname]
-        with self._interest_dispatch(cname, lane):
-            changed = np.zeros(k.store.capacity(cname), bool)
-            changed[rows] = True
-            fn = self._interest_query(cname, len(obs_rows))
-            if self._interest_skin > 0.0:
-                ckey, cache = self._interest_cache_for(cname)
-                vrows, vok, cache = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed), cs.alive,
-                    k.state.classes["Player"].vec,
-                    k.state.classes["Player"].i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid), cache,
-                )
-                self._interest_cache_store(ckey, cache)
-            else:
-                vrows, vok = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed),
-                    k.state.classes["Player"].vec,
-                    k.state.classes["Player"].i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid),
-                )
-        with span("interest.fetch", cls=cname, lane=lane):
-            vrows, vok = np.asarray(vrows), np.asarray(vok)
-        with span("interest.views", cls=cname, lane=lane):
+        vis = self._visible_among(cname, lane, rows)
+        if self.serve_batch:
+            slot_of = self._session_table.slot_of
             # nf-lint: disable=serve-loop -- per-entity property lane
             # shared by both engines; diffs here are < batch_sync_min
             # rows, so the loop is small-N (batching it is ROADMAP debt,
             # not serve-path)
-            for i, sess in enumerate(obs):
+            order = [(s, slot_of.get(key))
+                     for key, s in self.sessions.items()]
+        else:
+            order = [(s, i) for i, s in enumerate(self._observer_arrays()[0])]
+        with span("interest.views", cls=cname, lane=lane):
+            for sess, i in order:
                 g = sess.guid
-                if g is None:
+                if g is None or i is None:
                     continue
-                for r in vrows[i][vok[i]].tolist():
+                for r in vis[i].tolist():
                     out.setdefault(int(r), []).append(g)
         return out
 
@@ -2621,6 +2608,7 @@ class GameRole(ServerRole):
         # (Player Position), an observer set change, or a create/destroy
         # in the class (the dirty marks) — so an idle world pays nothing.
         self._obs_cache = None  # one _observer_arrays() per flush
+        self._frame_vis = {}  # one interest answer per class per flush
         if self.interest_radius is not None:
             with sc.stage("interest"):
 
@@ -2749,13 +2737,12 @@ class GameRole(ServerRole):
         knows no session: quantize positions and bin rows into the cell
         table (ops/interest; the same stencil engine combat runs on).
 
-        One program serves both lanes.  The Position lane bins ALL alive
-        in-extent rows (`in_extent_only`), so the host can diff each
+        It bins ALL alive in-extent rows, so the host can diff each
         session's visible set against what that session last saw:
         entities that moved while unobserved and then stopped are re-sent
         the moment an observer walks into range (the reference's
-        enter-view resend, NFCSceneAOIModule OnObjectListEnter).  The
-        property lanes bin the frame's changed rows as they are.  The
+        enter-view resend, NFCSceneAOIModule OnObjectListEnter); the
+        property lanes filter that same answer (`_visible_sets`).  The
         sorts of a million rows are what the chip's compiler takes its
         time over (~30 s a program), and they are here, once a class and
         table size, not once a padded session count."""
@@ -2782,11 +2769,9 @@ class GameRole(ServerRole):
         bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
         if skin > 0.0:
-            def interest_build(evec, ei32, alive, active, in_extent_only,
-                               cache):
+            def interest_build(evec, ei32, alive, cache):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
-                binned = active & (in_extent | ~in_extent_only)
                 # the cache anchors over the STABLE alive set; a frame's
                 # rows ride a sub-table through its sorted order
                 cache, _rebuilt = refresh(
@@ -2794,15 +2779,15 @@ class GameRole(ServerRole):
                 feats = _interest_feats(
                     pos3, ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32))
-                table = sub_table(cache, binned & alive, feats,
+                table = sub_table(cache, in_extent, feats,
                                   width * width, cell, width, bucket)
                 return (q, *table_seam(table), cache)
         else:
-            def interest_build(evec, ei32, alive, active, in_extent_only):
+            def interest_build(evec, ei32, alive):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
                 table = interest_table(
-                    pos3, active & (in_extent | ~in_extent_only),
+                    pos3, in_extent,
                     ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32),
                     cell, width, bucket, (spill_cells, spill_depth))
@@ -2861,16 +2846,6 @@ class GameRole(ServerRole):
         self._interest_builds.inc(cls=cname, lane=lane)
         return span("interest.dispatch", cls=cname, lane=lane)
 
-    def _interest_lane(self, position: bool):
-        """`_interest_build`'s `in_extent_only`, kept on the device."""
-        flags = getattr(self, "_interest_lanes", None)
-        if flags is None:
-            import jax.numpy as jnp
-
-            flags = self._interest_lanes = (jnp.asarray(False),
-                                            jnp.asarray(True))
-        return flags[bool(position)]
-
     def _interest_step(self, cname: str, s_pad: int):
         """The Position lane's interest pipeline for a padded session
         count: `_interest_build` over all alive in-extent rows, then
@@ -2879,52 +2854,95 @@ class GameRole(ServerRole):
         `_observe_interest` reads."""
         build = self._interest_build(cname)
         scan = self._interest_scan(cname, s_pad)
-        lane = self._interest_lane(True)
 
         if self._interest_skin > 0.0:
             def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid,
                      cache):
                 q, payload, hot_of, built, cache = build(
-                    evec, ei32, alive, alive, lane, cache)
+                    evec, ei32, alive, cache)
                 rows, ok, widest = scan(
                     payload, hot_of, pvec, pi32, obs_rows, obs_valid)
                 return q, rows, ok, (built, widest), cache
         else:
             def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid):
-                q, payload, hot_of, built = build(
-                    evec, ei32, alive, alive, lane)
+                q, payload, hot_of, built = build(evec, ei32, alive)
                 rows, ok, widest = scan(
                     payload, hot_of, pvec, pi32, obs_rows, obs_valid)
                 return q, rows, ok, (built, widest)
         return step
 
-    def _interest_query(self, cname: str, s_pad: int):
-        """The query-only interest pipeline: caller supplies the
-        changed-row mask (any property's diff), gets per-observer visible
-        candidates, through the Position lane's own two programs.  The
-        changed rows of a cell are among its alive rows, so the sizes
-        that hold the whole class hold any frame's diff."""
-        build = self._interest_build(cname)
-        scan = self._interest_scan(cname, s_pad)
-        lane = self._interest_lane(False)
+    def _interest_answer(self, cname: str, lane: str, obs_rows, obs_valid,
+                         with_q: bool):
+        """Run `_interest_step` for the observers `obs_rows` (valid
+        where `obs_valid`) on the current state: the dispatch, the one
+        read, `_observe_interest`.  Keeps each observer's visible rows,
+        ascending, as the flush's answer for the class (`_frame_vis`)
+        and returns (q or None, them).  Touches no seen-state."""
+        import jax
+        import jax.numpy as jnp
 
-        if self._interest_skin > 0.0:
-            def query(evec, ei32, changed, alive, pvec, pi32, obs_rows,
-                      obs_valid, cache):
-                _q, payload, hot_of, _built, cache = build(
-                    evec, ei32, alive, changed, lane, cache)
-                rows, ok, _widest = scan(
-                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
-                return rows, ok, cache
+        k = self.kernel
+        cs = k.state.classes[cname]
+        pcs = k.state.classes["Player"]
+        with self._interest_dispatch(cname, lane):
+            fn = self._interest_step(cname, len(obs_rows))
+            args = (cs.vec, cs.i32, cs.alive, pcs.vec, pcs.i32,
+                    jnp.asarray(obs_rows), jnp.asarray(obs_valid))
+            if self._interest_skin > 0.0:
+                ckey, cache = self._interest_cache_for(cname)
+                q, rows, ok, stats, cache = fn(*args, cache)
+                self._interest_cache_store(ckey, cache)
+            else:
+                q, rows, ok, stats = fn(*args)
+        # the frame's one read: the answer, and beside it what the
+        # table's build counted (the breach policy's input)
+        with span("interest.fetch", cls=cname, lane=lane):
+            q_np, rows, ok, (built, widest) = jax.device_get(
+                (q if with_q else None, rows, ok, stats))
+        self._observe_interest(cname, list(built) + [widest])
+        alloc = k.store._hosts[cname].alloc_mask
+        vis = []
+        for r, o in zip(rows, ok):
+            v = r[o]
+            vis.append(np.sort(v[alloc[v]]))  # drop just-died rows
+        self._frame_vis[cname] = vis
+        return q_np, vis
+
+    def _has_observers(self) -> bool:
+        """Whether any session has a live avatar, in the engine's terms."""
+        if self.serve_batch:
+            st = self._session_table
+            return st.capacity > 0 and bool(st.valid.any())
+        return bool(self._observer_arrays()[0])
+
+    def _visible_sets(self, cname: str, lane: str) -> List[np.ndarray]:
+        """Each observer's visible rows of the class in this flush,
+        ascending, by the per-session engine's observer index
+        (`_observer_arrays`) or the batched engine's session slot: the
+        answer the class's position lane fetched
+        (`nf_interest_shared_total` counts the lane), or, where that
+        lane did not run in this flush (no position, zone or observer
+        change; deferred by `serve_overlap`), its build and scan run
+        once here for every lane that asks after."""
+        vis = self._frame_vis.get(cname)
+        if vis is not None:
+            self._interest_shared.inc(cls=cname, lane=lane)
+            return vis
+        if self.serve_batch:
+            st = self._session_table
+            obs_rows, obs_valid = st.avatar_row, st.valid
         else:
-            def query(evec, ei32, changed, pvec, pi32, obs_rows, obs_valid):
-                # (this lane binned changed rows alive or not: keep it)
-                _q, payload, hot_of, _built = build(
-                    evec, ei32, changed, changed, lane)
-                rows, ok, _widest = scan(
-                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
-                return rows, ok
-        return query
+            _obs, obs_rows, obs_valid = self._observer_arrays()
+        return self._interest_answer(cname, lane, obs_rows, obs_valid,
+                                     with_q=False)[1]
+
+    def _visible_among(self, cname: str, lane: str,
+                       rows: np.ndarray) -> List[np.ndarray]:
+        """`_visible_sets` cut to `rows` (a diff's): each observer's
+        recipients of a property or record lane, ascending."""
+        changed = np.zeros(self.kernel.store.capacity(cname), bool)
+        changed[rows] = True
+        return [v[changed[v]] for v in self._visible_sets(cname, lane)]
 
     def _interest_cache_for(self, cname: str):
         """The class's interest Verlet cache, carried in WorldState.aux
@@ -2994,9 +3012,6 @@ class GameRole(ServerRole):
         from OnObjectListEnter, without any global last-synced table (and
         hence no stale-row hazard when rows are recycled: the guid is part
         of the match)."""
-        import jax
-        import jax.numpy as jnp
-
         from ...ops.interest import QMAX
         from ..wire import InterestPosSync
 
@@ -3007,32 +3022,9 @@ class GameRole(ServerRole):
         obs, obs_rows, obs_valid = self._observer_arrays()
         if not obs:
             return
-
-        cs = k.state.classes[cname]
-        pcs = k.state.classes["Player"]
-        with self._interest_dispatch(cname, "pos"):
-            fn = self._interest_step(cname, len(obs_rows))
-            if self._interest_skin > 0.0:
-                ckey, cache = self._interest_cache_for(cname)
-                q, rows, ok, stats, cache = fn(
-                    cs.vec, cs.i32, cs.alive,
-                    pcs.vec, pcs.i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid), cache,
-                )
-                self._interest_cache_store(ckey, cache)
-            else:
-                q, rows, ok, stats = fn(
-                    cs.vec, cs.i32, cs.alive,
-                    pcs.vec, pcs.i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid),
-                )
-        # the frame's one read: the answer, and beside it what the
-        # table's build counted (the breach policy's input)
-        with span("interest.fetch", cls=cname, lane="pos"):
-            q_np, rows_np, ok_np, (built, widest) = jax.device_get(
-                (q, rows, ok, stats))
+        q_np, vis_all = self._interest_answer(cname, "pos", obs_rows,
+                                              obs_valid, with_q=True)
         q_np = q_np.astype(np.uint16)
-        self._observe_interest(cname, list(built) + [widest])
         host = k.store._hosts[cname]
         scale = float(self.game_world.config.extent) / QMAX
         with span("interest.views", cls=cname, lane="pos"):
@@ -3041,12 +3033,10 @@ class GameRole(ServerRole):
             # oracle for tests/test_serve_batch.py, never the production
             # hot path
             for i, sess in enumerate(obs):
-                vis = rows_np[i][ok_np[i]]
-                vis = vis[host.alloc_mask[vis]]  # drop just-died rows
+                vis = vis_all[i]
                 seen = getattr(sess, "_interest_seen", None)
                 if seen is None:
                     seen = self.reset_view(sess)
-                vis = np.sort(vis)
                 heads = host.guid_head[vis]
                 datas = host.guid_data[vis]
                 qv = q_np[vis]  # [n, 3]
@@ -3391,6 +3381,8 @@ class GameRole(ServerRole):
         import jax
         import jax.numpy as jnp
 
+        from ...ops.serving import SENTINEL
+
         k = self.kernel
         st = self._session_table
         if st.capacity == 0 or not st.valid.any():
@@ -3468,10 +3460,13 @@ class GameRole(ServerRole):
         )
         cat = (lambda i: np.concatenate([p[i] for p in parts])
                if len(parts) > 1 else parts[0][i])
+        vis = cat(0)
+        # every slot's visible rows, ascending: the flush's answer
+        self._frame_vis[cname] = [v[v < SENTINEL] for v in vis]
         return {
             "cname": cname,
             "q": q_np.astype(np.uint16),
-            "vis": cat(0), "send": cat(1),
+            "vis": vis, "send": cat(1),
             "gone": cat(2), "gone_rows": cat(3),
             "prev_h": prev_h, "prev_d": prev_d,
         }
@@ -3538,132 +3533,31 @@ class GameRole(ServerRole):
         with self.stage_clock.stage("assemble"):
             self._serve_pos_emit(data)
 
-    def _serve_query(self, cname: str, s_pad: int):
-        """Batched interest-scoped BatchPropertySync query jit: legacy
-        `_interest_query` + device alloc filter + stable slot-order
-        compaction (the lane's wire order is candidate slot order)."""
-        key = ("bscan", cname, s_pad)
-        fn = self._serve_jit.get(key)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-
-        from ...ops.interest import (
-            visible_candidates,
-            visible_candidates_cached,
-        )
-        from ...ops.serving import slot_compact
-
-        k = self.kernel
-        spec = k.store.spec(cname)
-        pspec = k.store.spec("Player")
-        pos_col = spec.slots["Position"].col
-        sc_col, gr_col = spec.slots["SceneID"].col, spec.slots["GroupID"].col
-        p_pos = pspec.slots["Position"].col
-        p_sc, p_gr = pspec.slots["SceneID"].col, pspec.slots["GroupID"].col
-        radius = float(self.interest_radius)
-        skin = float(self._interest_skin)
-        cell, width, bucket, _m = self._serve_geometry(cname)
-        _bucket, spill_cells, spill_depth = self.resolved_interest(cname)
-
-        if skin > 0.0:
-            def query(evec, ei32, changed, alive, pvec, pi32, obs_rows,
-                      valid, alloc_ok, cache):
-                res, cache, _rebuilt = visible_candidates_cached(
-                    cache, evec[:, pos_col], changed, alive,
-                    ei32[:, sc_col].astype(jnp.float32),
-                    ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket, skin=skin,
-                )
-                ok = res.ok & valid[:, None] & alloc_ok[res.rows]
-                rows, counts = slot_compact(res.rows, ok)
-                return rows, counts, cache
-        else:
-            def query(evec, ei32, changed, pvec, pi32, obs_rows, valid,
-                      alloc_ok):
-                res = visible_candidates(
-                    evec[:, pos_col], changed,
-                    ei32[:, sc_col].astype(jnp.float32),
-                    ei32[:, gr_col].astype(jnp.float32),
-                    pvec[obs_rows, p_pos][:, :2],
-                    pi32[obs_rows, p_sc].astype(jnp.float32),
-                    pi32[obs_rows, p_gr].astype(jnp.float32),
-                    radius=radius, cell_size=cell, width=width,
-                    bucket=bucket, spill=(spill_cells, spill_depth),
-                )
-                ok = res.ok & valid[:, None] & alloc_ok[res.rows]
-                rows, counts = slot_compact(res.rows, ok)
-                return rows, counts
-
-        fn = self.kernel.costbook.wrap(
-            f"serve.query/{cname}", query, stage="interest"
-        )
-        self._serve_jit[key] = fn
-        return fn
-
     def _send_batch_property_interest_batched(
         self, cname: str, pname: str, rows: np.ndarray
     ) -> None:
-        """Batched interest-scoped columnar sync: one device query for
-        all sessions, one value gather, per-session byte slices."""
-        import jax
-        import jax.numpy as jnp
-
+        """Batched interest-scoped columnar sync: the flush's visible
+        sets filtered to the diff's rows for every session at once, one
+        value gather, per-session byte slices."""
         from ..serving import segments
         from ..wire import BatchPropertySync
 
         k = self.kernel
         st = self._session_table
         host = k.store._hosts[cname]
-        spec = k.store.spec(cname)
-        slot = spec.slot(pname)
+        slot = k.store.spec(cname).slot(pname)
         rows = rows[host.alloc_mask[rows]]
-        if rows.size == 0 or st.capacity == 0 or not st.valid.any():
+        if rows.size == 0 or not self._has_observers():
             return
-        cap = k.store.capacity(cname)
-        cs = k.state.classes[cname]
-        pcs = k.state.classes["Player"]
-        with self._interest_dispatch(cname, pname):
-            changed = np.zeros(cap, bool)
-            changed[rows] = True
-            fn = self._serve_query(cname, st.capacity)
-            obs_rows = jnp.asarray(st.avatar_row)
-            valid = jnp.asarray(st.valid)
-            alloc_ok = jnp.asarray(host.alloc_mask)
-            if self._interest_skin > 0.0:
-                ckey, cache = self._interest_cache_for(cname)
-                vrows, counts, cache = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed), cs.alive,
-                    pcs.vec, pcs.i32, obs_rows, valid, alloc_ok, cache,
-                )
-                self._interest_cache_store(ckey, cache)
-            else:
-                vrows, counts = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed),
-                    pcs.vec, pcs.i32, obs_rows, valid, alloc_ok,
-                )
-        with span("interest.fetch", cls=cname, lane=pname):
-            vrows, counts = jax.device_get((vrows, counts))
-
-        if slot.bank == Bank.VEC:
-            vals = gather_rows(cs.vec, rows, cols=slot.col)[:, 0]
-        elif slot.bank == Bank.F32:
-            vals = gather_rows(cs.f32, rows, cols=slot.col)[:, 0]
-        else:
-            vals = gather_rows(cs.i32, rows, cols=slot.col)[:, 0]
-        vals = np.asarray(vals)
-        pos_of = np.full(cap, -1, np.int64)
+        vis = self._visible_among(cname, pname, rows)
+        vals = self._prop_values(cname, slot, rows)
+        pos_of = np.full(k.store.capacity(cname), -1, np.int64)
         pos_of[rows] = np.arange(rows.size)
 
         with self.stage_clock.stage("assemble"), \
                 span("interest.views", cls=cname, lane=pname):
-            mask = np.arange(vrows.shape[1])[None, :] < counts[:, None]
-            flat = vrows[mask]  # session-major, slot order per session
+            counts = np.array([v.size for v in vis])
+            flat = np.concatenate(vis)  # session-major, ascending
             heads_b = host.guid_head[flat].tobytes()
             datas_b = host.guid_data[flat].tobytes()
             vals_flat = np.ascontiguousarray(vals[pos_of[flat]])
@@ -3703,55 +3597,22 @@ class GameRole(ServerRole):
         BatchPropertySync with only the changed entities inside its
         interest radius (same message type as the broadcast lane, so
         clients are agnostic to the fan-out mode)."""
-        import jax
-        import jax.numpy as jnp
-
         from ..wire import BatchPropertySync
 
         k = self.kernel
         host = k.store._hosts[cname]
-        spec = k.store.spec(cname)
-        slot = spec.slot(pname)
+        slot = k.store.spec(cname).slot(pname)
         rows = rows[host.alloc_mask[rows]]
         if rows.size == 0:
             return
-        obs, obs_rows, obs_valid = self._observer_arrays()
+        obs, _obs_rows, _obs_valid = self._observer_arrays()
         if not obs:
             return
-        cap = k.store.capacity(cname)
-        cs = k.state.classes[cname]
-        with self._interest_dispatch(cname, pname):
-            changed = np.zeros(cap, bool)
-            changed[rows] = True
-            fn = self._interest_query(cname, len(obs_rows))
-            if self._interest_skin > 0.0:
-                ckey, cache = self._interest_cache_for(cname)
-                vrows, vok, cache = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed), cs.alive,
-                    k.state.classes["Player"].vec,
-                    k.state.classes["Player"].i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid), cache,
-                )
-                self._interest_cache_store(ckey, cache)
-            else:
-                vrows, vok = fn(
-                    cs.vec, cs.i32, jnp.asarray(changed),
-                    k.state.classes["Player"].vec,
-                    k.state.classes["Player"].i32,
-                    jnp.asarray(obs_rows), jnp.asarray(obs_valid),
-                )
-        with span("interest.fetch", cls=cname, lane=pname):
-            vrows, vok = jax.device_get((vrows, vok))
+        vis_all = self._visible_among(cname, pname, rows)
         # one value gather for the changed set; per-session subsets map
         # through pos_of (changed row -> position in `rows`)
-        if slot.bank == Bank.VEC:
-            vals = gather_rows(cs.vec, rows, cols=slot.col)[:, 0]
-        elif slot.bank == Bank.F32:
-            vals = gather_rows(cs.f32, rows, cols=slot.col)[:, 0]
-        else:
-            vals = gather_rows(cs.i32, rows, cols=slot.col)[:, 0]
-        vals = np.asarray(vals)
-        pos_of = np.full(cap, -1, np.int64)
+        vals = self._prop_values(cname, slot, rows)
+        pos_of = np.full(k.store.capacity(cname), -1, np.int64)
         pos_of[rows] = np.arange(rows.size)
         name_b, cls_b = pname.encode(), cname.encode()
         ptype = int(slot.prop.type)
@@ -3760,8 +3621,7 @@ class GameRole(ServerRole):
             # (NF_SERVE_BATCH=0), the parity oracle for the batched
             # _send_batch_property_interest_batched above
             for i, sess in enumerate(obs):
-                vis = vrows[i][vok[i]]
-                vis = vis[host.alloc_mask[vis]]
+                vis = vis_all[i]
                 if vis.size == 0:
                     continue
                 idx = pos_of[vis]
@@ -3775,6 +3635,12 @@ class GameRole(ServerRole):
                     data=np.ascontiguousarray(vals[idx]).tobytes(),
                 )
                 self._send_to_session(sess, MsgID.ACK_BATCH_PROPERTY, msg)
+
+    def _prop_values(self, cname: str, slot, rows: np.ndarray) -> np.ndarray:
+        """One property's values at `rows`, one device gather."""
+        cs = self.kernel.state.classes[cname]
+        bank = {Bank.VEC: cs.vec, Bank.F32: cs.f32}.get(slot.bank, cs.i32)
+        return np.asarray(gather_rows(bank, rows, cols=slot.col)[:, 0])
 
     def _send_batch_property(self, cname: str, pname: str, rows: np.ndarray,
                              player_idx) -> None:
@@ -3799,13 +3665,7 @@ class GameRole(ServerRole):
         if rows.size == 0:
             return
         cells = self._rows_cells(cname, rows)  # [n, 2]
-        cs = k.state.classes[cname]
-        if slot.bank == Bank.VEC:
-            vals = gather_rows(cs.vec, rows, cols=slot.col)[:, 0]  # [n, 3]
-        elif slot.bank == Bank.F32:
-            vals = gather_rows(cs.f32, rows, cols=slot.col)[:, 0]
-        else:
-            vals = gather_rows(cs.i32, rows, cols=slot.col)[:, 0]
+        vals = self._prop_values(cname, slot, rows)
         heads = host.guid_head[rows]
         datas = host.guid_data[rows]
         cell_ids = cells[:, 0].astype(np.int64) * MAX_GROUPS_PER_SCENE + cells[:, 1]

@@ -7,7 +7,9 @@ have compiled every program.  Both engines must open the same three
 `nf.interest.*` spans, each with its `cls` and `lane`, and
 `nf_interest_builds_total` must move by exactly the number of
 `nf.interest.dispatch` spans, lane by lane: the counter is incremented
-where the span opens.
+where the span opens.  The HP diff's lane reads the position lane's
+answer of the same flush: it opens `nf.interest.views` and no dispatch,
+and `nf_interest_shared_total` counts it.
 """
 
 import glob
@@ -21,9 +23,9 @@ SPANS = ("nf.interest.dispatch", "nf.interest.fetch", "nf.interest.views")
 WARM, TRACED = 12, 12  # frames 12-23 hold every lane (HP at 17)
 
 
-def _builds(role) -> Counter:
+def _builds(role, counter="_interest_builds") -> Counter:
     return Counter({(labels["cls"], labels["lane"]): value
-                    for _s, labels, value in role._interest_builds.samples()})
+                    for _s, labels, value in getattr(role, counter).samples()})
 
 
 @pytest.fixture(scope="module", params=[False, True],
@@ -35,6 +37,7 @@ def traced(request, tmp_path_factory):
     driver = Driver(role, world)
     driver.run(WARM)
     before = _builds(role)
+    shared_before = _builds(role, "_interest_shared")
     tmp = tmp_path_factory.mktemp("interest_spans")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -54,8 +57,10 @@ def traced(request, tmp_path_factory):
               for line in plane.lines for e in line.events
               if e.name.startswith("nf.")]
     delta = _builds(role) - before
+    shared = _builds(role, "_interest_shared") - shared_before
     role.shut()
-    return {"batched": request.param, "events": events, "delta": delta}
+    return {"batched": request.param, "events": events, "delta": delta,
+            "shared": shared}
 
 
 def test_both_engines_open_the_same_interest_spans(traced):
@@ -80,3 +85,11 @@ def test_builds_counter_moves_with_the_dispatch_spans(traced):
     fetches = Counter((a["cls"], a["lane"]) for n, a in traced["events"]
                       if n == "nf.interest.fetch")
     assert fetches == spans
+
+
+def test_the_hp_lane_shares_the_position_answer(traced):
+    dispatches = {(a["cls"], a["lane"]) for n, a in traced["events"]
+                  if n == "nf.interest.dispatch"}
+    assert ("NPC", "pos") in dispatches
+    assert ("NPC", "HP") not in dispatches, dispatches
+    assert traced["shared"][("NPC", "HP")] >= 1, traced["shared"]
