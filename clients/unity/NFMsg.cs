@@ -5931,6 +5931,189 @@ namespace NFMsg
         }
     }
 
+    public class BatchPropertyColumns
+    {
+        public byte[] class_name = Nf.Empty;
+        public bool HasClassName = false;
+        public List<byte[]> names = new List<byte[]>();
+        public int ptype = 0;
+        public bool HasPtype = false;
+        public byte[] counts = Nf.Empty;
+        public bool HasCounts = false;
+        public byte[] svrid = Nf.Empty;
+        public bool HasSvrid = false;
+        public byte[] index = Nf.Empty;
+        public bool HasIndex = false;
+        public byte[] data = Nf.Empty;
+        public bool HasData = false;
+        public void Encode(MemoryStream nf__o)
+        {
+            if (HasClassName)
+            {
+                Nf.PutTag(nf__o, 1, 2);
+                Nf.PutBytes(nf__o, class_name);
+            }
+            foreach (var nf__it in names)
+            {
+                Nf.PutTag(nf__o, 2, 2);
+                Nf.PutBytes(nf__o, nf__it);
+            }
+            if (HasPtype)
+            {
+                Nf.PutTag(nf__o, 3, 0);
+                Nf.PutI64(nf__o, (long)ptype);
+            }
+            if (HasCounts)
+            {
+                Nf.PutTag(nf__o, 4, 2);
+                Nf.PutBytes(nf__o, counts);
+            }
+            if (HasSvrid)
+            {
+                Nf.PutTag(nf__o, 5, 2);
+                Nf.PutBytes(nf__o, svrid);
+            }
+            if (HasIndex)
+            {
+                Nf.PutTag(nf__o, 6, 2);
+                Nf.PutBytes(nf__o, index);
+            }
+            if (HasData)
+            {
+                Nf.PutTag(nf__o, 7, 2);
+                Nf.PutBytes(nf__o, data);
+            }
+        }
+        public byte[] Encode()
+        {
+            var nf__o = new MemoryStream(); Encode(nf__o); return nf__o.ToArray();
+        }
+        public void Clear()
+        {
+            class_name = Nf.Empty;
+            HasClassName = false;
+            names.Clear();
+            ptype = 0;
+            HasPtype = false;
+            counts = Nf.Empty;
+            HasCounts = false;
+            svrid = Nf.Empty;
+            HasSvrid = false;
+            index = Nf.Empty;
+            HasIndex = false;
+            data = Nf.Empty;
+            HasData = false;
+        }
+        public bool Decode(byte[] nf__data, int nf__off, int nf__len)
+        {
+            Clear();
+            var nf__r = new NfReader(nf__data, nf__off, nf__len);
+            while (!nf__r.Done())
+            {
+                ulong nf__key = nf__r.Varint();
+                if (!nf__r.Ok) return false;
+                switch ((uint)(nf__key >> 3))
+                {
+                    case 1:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        class_name = nf__r.Bytes();
+                        if (!nf__r.Ok) return false;
+                        HasClassName = true;
+                        break;
+                    }
+                    case 2:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        names.Add(nf__r.Bytes());
+                        if (!nf__r.Ok) return false;
+                        break;
+                    }
+                    case 3:
+                    {
+                        if ((uint)(nf__key & 7) != 0)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        ptype = (int)nf__r.Varint();
+                        if (!nf__r.Ok) return false;
+                        HasPtype = true;
+                        break;
+                    }
+                    case 4:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        counts = nf__r.Bytes();
+                        if (!nf__r.Ok) return false;
+                        HasCounts = true;
+                        break;
+                    }
+                    case 5:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        svrid = nf__r.Bytes();
+                        if (!nf__r.Ok) return false;
+                        HasSvrid = true;
+                        break;
+                    }
+                    case 6:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        index = nf__r.Bytes();
+                        if (!nf__r.Ok) return false;
+                        HasIndex = true;
+                        break;
+                    }
+                    case 7:
+                    {
+                        if ((uint)(nf__key & 7) != 2)
+                        {
+                            nf__r.Skip((uint)(nf__key & 7));
+                            if (!nf__r.Ok) return false;
+                            break;
+                        }
+                        data = nf__r.Bytes();
+                        if (!nf__r.Ok) return false;
+                        HasData = true;
+                        break;
+                    }
+                    default:
+                        nf__r.Skip((uint)(nf__key & 7));
+                        if (!nf__r.Ok) return false;
+                        break;
+                }
+            }
+            return nf__r.Ok;
+        }
+    }
+
     public class InterestPosSync
     {
         public float scale = 0f;

@@ -65,6 +65,7 @@ public static class NFMsgGoldenTest
             case "ReqDeleteRole": { var m = new NFMsg.ReqDeleteRole(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
             case "ServerHeartBeat": { var m = new NFMsg.ServerHeartBeat(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
             case "BatchPropertySync": { var m = new NFMsg.BatchPropertySync(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
+            case "BatchPropertyColumns": { var m = new NFMsg.BatchPropertyColumns(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
             case "InterestPosSync": { var m = new NFMsg.InterestPosSync(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
             case "ReqSwitchServer": { var m = new NFMsg.ReqSwitchServer(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }
             case "AckSwitchServer": { var m = new NFMsg.AckSwitchServer(); if (!m.Decode(raw, 0, raw.Length)) return null; return m.Encode(); }

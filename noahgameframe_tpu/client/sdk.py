@@ -59,6 +59,7 @@ from ..net.wire import (
     ReqAckOprTeamMember,
     ReqAckPlayerChat,
     ReqAckPlayerMove,
+    ReqAckSwapScene,
     ReqAckUseItem,
     ReqAckUseSkill,
     ReqCompeleteTask,
@@ -115,6 +116,8 @@ class GameClient:
         self.player_guid: Optional[Ident] = None  # game-side avatar guid
         self.entered = False
         self.last_enter_code: Optional[int] = None  # refusal visibility
+        self.scene_id = 0  # the scene the avatar stands in (0: not known)
+        self.swaps: List[ReqAckSwapScene] = []  # scene swaps acknowledged
         # the world mirror
         self.objects: Dict[_IdentKey, MirrorObject] = {}
         self.chat_log: List[Tuple[str, str]] = []
@@ -165,8 +168,11 @@ class GameClient:
         h[int(MsgID.ACK_RECORD_OBJECT)] = self._on_record_object
         h[int(MsgID.ACK_RECORD_VECTOR3)] = self._on_record_vector3
         h[int(MsgID.ACK_BATCH_PROPERTY)] = self._on_batch_property
+        h[int(MsgID.ACK_BATCH_PROPERTY_COLUMNS)] = \
+            self._on_batch_property_columns
         h[int(MsgID.ACK_INTEREST_POS)] = self._on_interest_pos
         h[int(MsgID.ACK_MOVE)] = self._on_move
+        h[int(MsgID.ACK_SWAP_SCENE)] = self._on_swap_scene
         h[int(MsgID.ACK_CHAT)] = self._on_chat
         h[int(MsgID.ACK_SKILL_OBJECTX)] = self._on_skill
         h[int(MsgID.FRAME_TRACE)] = self._on_frame_trace
@@ -377,7 +383,10 @@ class GameClient:
         return self.objects[k]
 
     def _on_object_entry(self, base: MsgBase) -> None:
+        mine = _key(self.player_guid) if self.player_guid else None
         for e in AckPlayerEntryList.decode(base.msg_data).object_list:
+            if _key(e.object_guid) == mine:
+                self.scene_id = int(e.scene_id)
             o = self._obj(e.object_guid)
             o.class_id = e.class_id.decode("utf-8", "replace")
             o.config_id = e.config_id.decode("utf-8", "replace")
@@ -571,6 +580,31 @@ class GameClient:
             if name == "Position":
                 o.position = v if len(v) == 3 else (*v, 0.0)
 
+    def _on_batch_property_columns(self, base: MsgBase) -> None:
+        """Several integer columns of one class in one message: column
+        `names[j]` holds the next `counts[j]` (entity, value) pairs."""
+        import numpy as np
+
+        from ..net.wire import BatchPropertyColumns
+
+        msg = BatchPropertyColumns.decode(base.msg_data)
+        counts = np.frombuffer(msg.counts, np.int32).tolist()
+        names = [n.decode() for n in msg.names]
+        heads = np.frombuffer(msg.svrid, np.int64).tolist()
+        datas = np.frombuffer(msg.index, np.int64).tolist()
+        vals = np.frombuffer(msg.data, np.int32).tolist()
+        objs = {}
+        at = 0
+        for name, n in zip(names, counts):
+            for h_, d_, v in zip(heads[at:at + n], datas[at:at + n],
+                                 vals[at:at + n]):
+                o = objs.get((h_, d_))
+                if o is None:
+                    o = objs[(h_, d_)] = self._obj(Ident(svrid=h_,
+                                                         index=d_))
+                o.properties[name] = v
+            at += n
+
     def _on_interest_pos(self, base: MsgBase) -> None:
         """Per-session interest stream: u16-quantized positions of the
         entities near this client's avatar; scale rides the message."""
@@ -608,6 +642,23 @@ class GameClient:
 
     def _on_move(self, base: MsgBase) -> None:
         self.moves.append(ReqAckPlayerMove.decode(base.msg_data))
+
+    def swap_scene(self, scene_id: int) -> None:
+        """Ask to enter another scene (REQ_SWAP_SCENE); a CLONE scene
+        gives the avatar an instance of its own."""
+        self._send(MsgID.REQ_SWAP_SCENE, ReqAckSwapScene(scene_id=scene_id))
+
+    def _on_swap_scene(self, base: MsgBase) -> None:
+        """The swap is done: a new scene unloads the old one, so the
+        mirror keeps only the client's own avatar, and what the new
+        scene holds arrives behind this acknowledgement."""
+        ack = ReqAckSwapScene.decode(base.msg_data)
+        if int(ack.scene_id) != self.scene_id:
+            mine = _key(self.player_guid) if self.player_guid else None
+            self.objects = {k: o for k, o in self.objects.items()
+                            if k == mine}
+        self.scene_id = int(ack.scene_id)
+        self.swaps.append(ack)
 
     def use_item(self, config_id: str, target_row: int | None = None) -> None:
         """EGMI_REQ_ITEM_OBJECT — family targets (hero/equip row) ride

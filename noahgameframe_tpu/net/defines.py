@@ -166,6 +166,10 @@ class MsgID(enum.IntEnum):
     # target game -> world: staged switch-in refused (capacity / torn
     # blob) — the reference AckSwitchServer has no failure leg
     ACK_SWITCH_REFUSED = 8008
+    # several columns of one class in one message (BatchPropertyColumns):
+    # a clone room's rows that come into a client's view carry every
+    # public column, one message a class a frame
+    ACK_BATCH_PROPERTY_COLUMNS = 8009
 
     # in-game actions
     REQ_MOVE = 1230

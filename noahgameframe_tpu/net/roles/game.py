@@ -1265,7 +1265,7 @@ class GameRole(ServerRole):
         sess = self.sessions.get(_ident_key(base.player_id))
         if sess is None or sess.guid is None:
             return
-        self._enter_scene(sess.guid, req.scene_id)
+        self._swap_scene(sess, _ident_key(base.player_id), req.scene_id)
         self._send_to_session(sess, MsgID.ACK_SWAP_SCENE, req)
 
     def _on_move(self, conn_id: int, _msg_id: int, body: bytes) -> None:
@@ -2087,6 +2087,10 @@ class GameRole(ServerRole):
                     self._changed.clear()
                     self._rec_changed.clear()
                     self._interest_dirty.clear()
+        if (tick_due and self._room_serve is not None
+                and self._room_serve.seats):
+            self._room_serve.flush()
+            flushed = True
         if framed:
             sc.frame_end()
             if flushed and self._trace_sample > 0:
@@ -2139,13 +2143,35 @@ class GameRole(ServerRole):
         el.begin_drain(int(device_index))
 
     # ------------------------------------------------------- many worlds
-    def attach_rooms(self, directory) -> None:
+    # the clone enters into the attached directory's rooms and the lanes
+    # that serve them (room_serve.RoomServe); None without rooms or an
+    # interest radius.  Every room hook of the world's paths lies below
+    # the pump: a Pallas kernel's compiled body carries the source lines
+    # of the frames that traced it, and so does the tick's cache key
+    _room_serve = None
+
+    def _swap_scene(self, sess: Session, key, scene_id: int) -> None:
+        """A swap request's enter: a CLONE scene seats the session in a
+        room when rooms are served, any other scene is entered as ever."""
+        if self._room_serve is None or not self._room_serve.swap(
+                sess, key, scene_id):
+            self._enter_scene(sess.guid, scene_id)
+
+    def attach_rooms(self, directory, seed: int = 0) -> None:
         """Host a many-worlds RoomDirectory (parallel/rooms.py) beside
         the single world: the pump ticks it once per world tick, inside
         the tick stage (so nobody else may), room status rides the
         heartbeat ext and the room churn verbs below become
-        drill-addressable."""
+        drill-addressable.  With an interest radius, a swap into a
+        CLONE scene seats the session in a room of its own made from
+        `seed` and its account, served from then on
+        (`room_serve.RoomServe`)."""
         self.rooms = directory
+        if (self.interest_radius is not None
+                and hasattr(directory.template_world, "scene")):
+            from .room_serve import RoomServe
+
+            self._room_serve = RoomServe(self, directory, seed)
 
     def _rooms_or_raise(self):
         if self.rooms is None:
@@ -2164,6 +2190,8 @@ class GameRole(ServerRole):
         (same reset discipline as a completed reshard: the seen-state
         wipe is lazy, the routing column clears now)."""
         d = self._rooms_or_raise()
+        if self._room_serve is not None:
+            self._room_serve.release_room(room_id)
         slot = d.destroy_room(room_id)
         table = self._session_table
         if table is not None:
@@ -2985,6 +3013,9 @@ class GameRole(ServerRole):
             s for s in self.sessions.values()
             if s.guid is not None and s.guid in k.store.guid_map
         ]
+        if self._room_serve is not None and self._room_serve.seats:
+            seated = self._room_serve.world_guids()  # served their rooms
+            obs = [s for s in obs if s.guid not in seated]
         if not obs:
             self._obs_cache = ([], None, None)
             return self._obs_cache
@@ -3251,6 +3282,9 @@ class GameRole(ServerRole):
             if s.guid is not None and s.guid in k.store.guid_map:
                 st.ensure(key, s.conn_id, k.store.row_of(s.guid)[1])
             else:
+                st.invalidate(key)
+        if self._room_serve is not None:
+            for key in self._room_serve.seats:  # served their rooms
                 st.invalidate(key)
 
     def _serve_prepare(self, cname: str):
@@ -3793,6 +3827,10 @@ class GameRole(ServerRole):
             if sess is not None:
                 sess.guid = None
                 self.reset_view(sess)
+        elif ev == ObjectEvent.DESTROY and self._room_serve is not None:
+            # a seated avatar left the world (despawn, leave, lost link):
+            # its room goes with it
+            self._room_serve.avatar_gone(guid)
 
     def _on_npc_event(self, guid: Guid, _cname: str, ev: ObjectEvent) -> None:
         if ev == ObjectEvent.DESTROY and self.sessions:

@@ -140,12 +140,12 @@ class SessionTable:
         return -1 if slot is None else int(self.room[slot])
 
     def sessions_in_room(self, room_id: int) -> List[Hashable]:
-        """Keys of every live session routed to `room_id` — the set a
-        room destroy/re-home must release or reset."""
+        """Keys of every session routed to `room_id` — the set a room
+        destroy/re-home must release or reset.  A session served a room
+        is no observer of the single world (`valid` is False)."""
         rid = int(room_id)
-        return [self._key_of[s] for s in np.flatnonzero(
-            (self.room == rid) & self.valid)
-            if self._key_of[s] is not None]
+        return [self._key_of[s] for s in np.flatnonzero(self.room == rid)
+                if self._key_of[s] is not None]
 
     def reset_view(self, key: Hashable) -> None:
         """Wipe the session's device seen-state NOW (the batched half of

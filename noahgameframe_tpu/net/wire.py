@@ -236,6 +236,26 @@ def ident_key(i: Optional["Ident"]) -> Tuple[int, int]:
     return (i.svrid, i.index) if i is not None else (0, 0)
 
 
+#: Ident.svrid of a row of a clone room (parallel/rooms.py): the tag,
+#: then the room id and the row's class code; Ident.index is the row.
+#: Row 7 of one room and row 7 of another are two objects, and so two
+#: mirror keys.  A single world's guid heads stay below the tag.
+ROOM_IDENT_TAG = 1 << 48
+ROOM_CLASS_CODES = 8
+
+
+def room_ident_head(room_id: int, class_code: int) -> int:
+    """Ident.svrid of the rows of one class of one room."""
+    return ROOM_IDENT_TAG + int(room_id) * ROOM_CLASS_CODES + int(class_code)
+
+
+def ident_room(i: Optional["Ident"]) -> int:
+    """The room an Ident's object lives in; -1 for the single world."""
+    if i is None or i.svrid < ROOM_IDENT_TAG:
+        return -1
+    return (i.svrid - ROOM_IDENT_TAG) // ROOM_CLASS_CODES
+
+
 class Vector2(Message):
     FIELDS = [(1, "x", "float", 0.0), (2, "y", "float", 0.0)]
 
@@ -670,6 +690,26 @@ class BatchPropertySync(Message):
         (2, "property_name", "bytes", b""),
         (3, "ptype", "int32", 0),
         (4, "count", "int32", 0),
+        (5, "svrid", "bytes", b""),  # i64le[n]
+        (6, "index", "bytes", b""),  # i64le[n]
+        (7, "data", "bytes", b""),
+    ]
+
+
+class BatchPropertyColumns(Message):
+    """TPU-native columnar sync of several properties of one class at
+    once (msg id ACK_BATCH_PROPERTY_COLUMNS): the `BatchPropertySync`s
+    of one frame for one client, one after another in one message.
+    Column `names[j]` covers the next `counts[j]` entries of `svrid`,
+    `index` and `data`; every column has the type `ptype`.  Where a
+    row that comes into view is sent every public column, this is one
+    message where the per-column form is one a column."""
+
+    FIELDS = [
+        (1, "class_name", "bytes", b""),
+        (2, "names", R("bytes"), None),
+        (3, "ptype", "int32", 0),
+        (4, "counts", "bytes", b""),  # i32le[k]
         (5, "svrid", "bytes", b""),  # i64le[n]
         (6, "index", "bytes", b""),  # i64le[n]
         (7, "data", "bytes", b""),

@@ -941,7 +941,8 @@ class RoomDirectory:
     # ----------------------------------------------------------- churn
     def create_rooms(self, seeds: Sequence[int],
                      room_ids: Optional[Sequence[int]] = None,
-                     control: bool = False) -> List[int]:
+                     control: bool = False,
+                     rows: Optional[Dict[str, Any]] = None) -> List[int]:
         """Make the rooms of ``seeds`` and admit them all at once, each
         into the least-loaded free slot: the one way a room comes to be.
         A recipe with ``seeded_rows`` has its rooms made on the host for
@@ -950,7 +951,10 @@ class RoomDirectory:
         the worlds' states are stacked.  With ``control=True`` the
         rooms are built as worlds either way, the worlds stay alive and
         ``tick``/``run`` advance them in lockstep: the independent
-        oracle for isolation/parity gates."""
+        oracle for isolation/parity gates.  ``rows`` (``[R, ...]``
+        leaves by ``ROOM_PACK_SPEC`` path) replaces what the seeds made
+        of those leaves, in the same scatter: a player seated in a
+        fresh room (``net/roles/room_serve.py``)."""
         seeds = [int(s) for s in seeds]
         if room_ids is None:
             room_ids = range(self._next_room_id,
@@ -974,16 +978,17 @@ class RoomDirectory:
                     k = self._kernel_of(world)
                     k._ensure_aux()
                     items.append(world_room_leaf_items(k.state, order))
-                rows = {path: jnp.stack([room[i][1] for room in items])
+                made = {path: jnp.stack([room[i][1] for room in items])
                         for i, (path, _) in enumerate(items[0])}
                 proto = None
             else:
                 worlds = []
-                rows = seeded(kernel, seeds)
+                made = seeded(kernel, seeds)
                 proto = self._proto
+            made.update(rows or {})
             loads = np.zeros(len(seeds))
             for cname in order:
-                alive = rows.get(f"classes.{cname}.alive")
+                alive = made.get(f"classes.{cname}.alive")
                 loads += (np.asarray(alive).sum(axis=1) if alive is not None
                           else float(np.asarray(
                               self._proto.classes[cname].alive).sum()))
@@ -991,7 +996,7 @@ class RoomDirectory:
             try:
                 for load in loads:
                     slots.append(self.packer.alloc(load=float(load)))
-                self.batch.admit_rooms(slots, rows, proto)
+                self.batch.admit_rooms(slots, made, proto)
             except Exception:
                 for slot in slots:
                     self.packer.free(slot)
@@ -1009,6 +1014,14 @@ class RoomDirectory:
             self._metrics["admitted_rows"].inc(int(loads.sum()))
         self._publish()
         return room_ids
+
+    def proto_leaf(self, path: str) -> np.ndarray:
+        """The template room's packed leaf at ``path`` (no room axis),
+        on the host: what a room's leaf holds before its seed or an
+        admit's ``rows`` change it."""
+        items = dict(world_room_leaf_items(
+            self._proto, self.batch.kernel.store.class_order))
+        return np.asarray(items[path])
 
     def create_room(self, seed: Optional[int] = None,
                     room_id: Optional[int] = None,
