@@ -2762,8 +2762,9 @@ class GameRole(ServerRole):
 
     def _interest_build(self, cname: str):
         """Cached per-class jit of the interest lanes' device half that
-        knows no session: quantize positions and bin rows into the cell
-        table (ops/interest; the same stencil engine combat runs on).
+        knows no session: quantize positions and sort rows by interest
+        cell (ops/interest `interest_sorted`: the stencil engine's key
+        pass and ranks, no table laid out).
 
         It bins ALL alive in-extent rows, so the host can diff each
         session's visible set against what that session last saw:
@@ -2782,7 +2783,7 @@ class GameRole(ServerRole):
 
         from ...ops.interest import (
             _interest_feats,
-            interest_table,
+            interest_sorted,
             quantize,
             table_seam,
         )
@@ -2809,17 +2810,16 @@ class GameRole(ServerRole):
                     ei32[:, gr_col].astype(jnp.float32))
                 table = sub_table(cache, in_extent, feats,
                                   width * width, cell, width, bucket)
-                return (q, *table_seam(table), cache)
+                return q, table_seam(table), cache
         else:
             def interest_build(evec, ei32, alive):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
-                table = interest_table(
+                return q, interest_sorted(
                     pos3, in_extent,
                     ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32),
                     cell, width, bucket, (spill_cells, spill_depth))
-                return (q, *table_seam(table))
 
         fn = self.kernel.costbook.wrap(
             f"interest.build/{cname}", interest_build, stage="interest")
@@ -2828,17 +2828,17 @@ class GameRole(ServerRole):
 
     def _interest_scan(self, cname: str, s_pad: int):
         """Cached per-(class, padded-session-count) jit of the other
-        half: read each observer's 3x3 neighbourhood from a built table,
-        both levels, distance+zone mask (ops/interest).  Only the
-        payload and the level's index cross the seam; the geometry is
-        static in both closures."""
+        half: read each observer's 3x3 neighbourhood, both levels, from
+        the sorted list, distance+zone mask (ops/interest `scan_sorted`).
+        Only the seam (`InterestSeam`) crosses; the geometry is static
+        in both closures."""
         key = ("scan", cname, s_pad)
         fn = self._interest_jit.get(key)
         if fn is not None:
             return fn
         import jax.numpy as jnp
 
-        from ...ops.interest import _scan_observers, seam_table
+        from ...ops.interest import scan_sorted
 
         pspec = self.kernel.store.spec("Player")
         p_pos = pspec.slots["Position"].col
@@ -2847,14 +2847,13 @@ class GameRole(ServerRole):
         cell, width = self._interest_grid()
         bucket, spill_cells, spill_depth = self.resolved_interest(cname)
 
-        def interest_scan(payload, hot_of, pvec, pi32, obs_rows, obs_valid):
-            res = _scan_observers(
-                seam_table(payload, hot_of, cell, width, bucket,
-                           (spill_cells, spill_depth)),
+        def interest_scan(seam, pvec, pi32, obs_rows, obs_valid):
+            res = scan_sorted(
+                seam,
                 pvec[obs_rows, p_pos][:, :2],
                 pi32[obs_rows, p_sc].astype(jnp.float32),
                 pi32[obs_rows, p_gr].astype(jnp.float32),
-                radius, cell,
+                radius, cell, width, bucket, (spill_cells, spill_depth),
             )
             ok = res.ok & obs_valid[:, None]
             # the widest visible set rides the frame's fetch
@@ -2865,13 +2864,27 @@ class GameRole(ServerRole):
         self._interest_jit[key] = fn
         return fn
 
-    def _interest_dispatch(self, cname: str, lane: str):
+    @property
+    def _interest_rows_read(self):
+        """`nf_interest_rows_read_total{cls}`: the candidate rows the
+        scans read from the sorted list, S_pad x 9 x (bucket + depth) a
+        scan (made on first use)."""
+        return self.telemetry.registry.counter(
+            "nf_interest_rows_read_total",
+            "candidate rows the interest scans read from the sorted list",
+            ("cls",))
+
+    def _interest_dispatch(self, cname: str, lane: str, s_pad: int):
         """The span `nf.interest.dispatch` (`cls`, `lane`: "pos" or the
-        property's or record's name): one interest-table build and the
-        scans that read it, inputs uploaded and a first use compiled
-        inside it.  `nf_interest_builds_total` counts it here, so the
-        counter and the spans always agree."""
+        property's or record's name): one interest build and the scans
+        of its `s_pad` observer slots, inputs uploaded and a first use
+        compiled inside it.  `nf_interest_builds_total` and
+        `nf_interest_rows_read_total` count it here, so the counters and
+        the spans always agree."""
+        bucket, cells, depth = self.resolved_interest(cname)
         self._interest_builds.inc(cls=cname, lane=lane)
+        self._interest_rows_read.inc(
+            s_pad * 9 * (bucket + (depth if cells else 0)), cls=cname)
         return span("interest.dispatch", cls=cname, lane=lane)
 
     def _interest_step(self, cname: str, s_pad: int):
@@ -2886,17 +2899,16 @@ class GameRole(ServerRole):
         if self._interest_skin > 0.0:
             def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid,
                      cache):
-                q, payload, hot_of, built, cache = build(
-                    evec, ei32, alive, cache)
+                q, seam, cache = build(evec, ei32, alive, cache)
                 rows, ok, widest = scan(
-                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
-                return q, rows, ok, (built, widest), cache
+                    seam, pvec, pi32, obs_rows, obs_valid)
+                return q, rows, ok, (seam.stats, widest), cache
         else:
             def step(evec, ei32, alive, pvec, pi32, obs_rows, obs_valid):
-                q, payload, hot_of, built = build(evec, ei32, alive)
+                q, seam = build(evec, ei32, alive)
                 rows, ok, widest = scan(
-                    payload, hot_of, pvec, pi32, obs_rows, obs_valid)
-                return q, rows, ok, (built, widest)
+                    seam, pvec, pi32, obs_rows, obs_valid)
+                return q, rows, ok, (seam.stats, widest)
         return step
 
     def _interest_answer(self, cname: str, lane: str, obs_rows, obs_valid,
@@ -2912,7 +2924,7 @@ class GameRole(ServerRole):
         k = self.kernel
         cs = k.state.classes[cname]
         pcs = k.state.classes["Player"]
-        with self._interest_dispatch(cname, lane):
+        with self._interest_dispatch(cname, lane, len(obs_rows)):
             fn = self._interest_step(cname, len(obs_rows))
             args = (cs.vec, cs.i32, cs.alive, pcs.vec, pcs.i32,
                     jnp.asarray(obs_rows), jnp.asarray(obs_valid))
@@ -3289,8 +3301,9 @@ class GameRole(ServerRole):
 
     def _serve_prepare(self, cname: str):
         """Per-class 'prepare' jit: quantize + position-version bump +
-        cell-table build, ONCE per frame regardless of session chunking
-        (per-chunk bumping would multi-count a single move)."""
+        the class sorted by interest cell (`interest_sorted`), ONCE per
+        frame regardless of session chunking (per-chunk bumping would
+        multi-count a single move)."""
         key = ("sprep", cname)
         fn = self._serve_jit.get(key)
         if fn is not None:
@@ -3300,7 +3313,7 @@ class GameRole(ServerRole):
 
         from ...ops.interest import (
             _interest_feats,
-            interest_table,
+            interest_sorted,
             quantize,
             table_seam,
         )
@@ -3332,19 +3345,18 @@ class GameRole(ServerRole):
                     cache, in_extent & alive, feats, width * width,
                     cell, width, bucket,
                 )
-                return (q, qver2, prev2, *table_seam(table), cache)
+                return q, qver2, prev2, table_seam(table), cache
         else:
             def prep(evec, ei32, alive, qver, prev_q):
                 pos3 = evec[:, pos_col]
                 q, in_extent = quantize(pos3, alive, extent)
                 qver2, prev2 = bump_qver(q, prev_q, qver)
-                table = interest_table(
+                return q, qver2, prev2, interest_sorted(
                     pos3, in_extent,
                     ei32[:, sc_col].astype(jnp.float32),
                     ei32[:, gr_col].astype(jnp.float32),
                     cell, width, bucket, (spill_cells, spill_depth),
                 )
-                return (q, qver2, prev2, *table_seam(table))
 
         fn = self.kernel.costbook.wrap(
             f"serve.prepare/{cname}", prep, stage="interest"
@@ -3355,7 +3367,7 @@ class GameRole(ServerRole):
     def _serve_scan(self, cname: str, s_chunk: int):
         """Per-(class, chunk) 'scan' jit: 3x3 candidate read + the full
         delta set algebra for a contiguous block of session slots.  Only
-        the payload array crosses the prepare/scan seam — the grid
+        the seam (`InterestSeam`) crosses from prepare to scan — the grid
         geometry is static in both closures."""
         key = ("sscan", cname, s_chunk)
         fn = self._serve_jit.get(key)
@@ -3364,7 +3376,7 @@ class GameRole(ServerRole):
         import jax
         import jax.numpy as jnp
 
-        from ...ops.interest import _scan_observers, seam_table
+        from ...ops.interest import scan_sorted
         from ...ops.serving import SeenTable, interest_delta, slot_compact
 
         pspec = self.kernel.store.spec("Player")
@@ -3375,15 +3387,14 @@ class GameRole(ServerRole):
         _bucket, spill_cells, spill_depth = self.resolved_interest(cname)
         k9 = 9 * (bucket + spill_depth)
 
-        def scan(payload, hot_of, pvec, pi32, obs_rows, valid, alloc_ok,
+        def scan(seam, pvec, pi32, obs_rows, valid, alloc_ok,
                  gen, qver, seen_rows, seen_gen, seen_qver):
-            res = _scan_observers(
-                seam_table(payload, hot_of, cell, width, bucket,
-                           (spill_cells, spill_depth)),
+            res = scan_sorted(
+                seam,
                 pvec[obs_rows, p_pos][:, :2],
                 pi32[obs_rows, p_sc].astype(jnp.float32),
                 pi32[obs_rows, p_gr].astype(jnp.float32),
-                radius, cell,
+                radius, cell, width, bucket, (spill_cells, spill_depth),
             )
             # device-side alloc filter: the legacy loop's just-died-row
             # drop (host.alloc_mask), applied before the delta algebra
@@ -3435,16 +3446,16 @@ class GameRole(ServerRole):
                   jnp.full((cap, 3), -1, jnp.int32))
         qver, prev_q = qp
 
-        with self._interest_dispatch(cname, "pos"):
+        with self._interest_dispatch(cname, "pos", st.capacity):
             prep = self._serve_prepare(cname)
             if self._interest_skin > 0.0:
                 ckey, cache = self._interest_cache_for(cname)
-                q, qver, prev_q, payload, hot_of, built, cache = prep(
+                q, qver, prev_q, seam, cache = prep(
                     cs.vec, cs.i32, cs.alive, qver, prev_q, cache
                 )
                 self._interest_cache_store(ckey, cache)
             else:
-                q, qver, prev_q, payload, hot_of, built = prep(
+                q, qver, prev_q, seam = prep(
                     cs.vec, cs.i32, cs.alive, qver, prev_q
                 )
             self._serve_qver[cname] = (qver, prev_q)
@@ -3464,13 +3475,13 @@ class GameRole(ServerRole):
                 c1 = c0 + chunk
                 fn = self._serve_scan(cname, chunk)
                 delta, widest = fn(
-                    payload, hot_of, pcs.vec, pcs.i32,
+                    seam, pcs.vec, pcs.i32,
                     obs_rows[c0:c1], valid[c0:c1], alloc_ok, gen, qver,
                     seen.rows[c0:c1], seen.gen[c0:c1], seen.qver[c0:c1],
                 )
                 # the build's counts ride the chunk's own answer
                 outs.append((delta.vis, delta.send, delta.gone,
-                             delta.gone_rows, widest, built))
+                             delta.gone_rows, widest, seam.stats))
                 seen = type(seen)(
                     rows=seen.rows.at[c0:c1].set(delta.seen.rows),
                     gen=seen.gen.at[c0:c1].set(delta.seen.gen),

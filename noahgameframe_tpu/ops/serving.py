@@ -21,7 +21,7 @@ one static-shaped dispatch:
    every flush in which any position changed, so the version counter
    observes every quantum transition the legacy path would have stored.
 2. `interest_delta` — per-session set algebra over the candidate slots
-   from ops/interest (`_scan_observers` 3x3 reads): sort the visible
+   from ops/interest (`scan_sorted` 3x3 reads): sort the visible
    rows (ascending, sentinel-padded — the legacy wire order), match
    them against the session's sorted seen-table by vmapped
    searchsorted, and emit `send` (enter or changed) and `gone`

@@ -1,11 +1,12 @@
 """The interest table's second level (ISSUE 32).
 
 A crowd stands far deeper in an interest cell than `auto_bucket` sizes
-for; `ops/stencil.build_cell_table` keeps the rows of the over-full
-cells beyond the bucket in a second level, `ops/interest._scan_observers`
-reads it for those of an observer's nine cells that are over-full, and
-the game role sizes it from what a frame's own build counted
-(`GameRole._observe_interest`), with no option anywhere.  Held here:
+for; the two-level slot rule (`ops/stencil._spill_slots`) keeps the rows
+of the over-full cells beyond the bucket in a second level,
+`ops/interest.scan_sorted` reads it from the sorted list for those of an
+observer's nine cells that are over-full, and the game role sizes it
+from what a frame's own build counted (`GameRole._observe_interest`),
+with no option anywhere.  Held here:
 
 - the answer against brute force on a clustered world: every row within
   the radius and in scope is a candidate exactly once;
@@ -33,7 +34,8 @@ from noahgameframe_tpu.net.wire import Ident, InterestPosSync, ident_key, unwrap
 from noahgameframe_tpu.ops.interest import (
     STAT_NAMES,
     _interest_feats,
-    _scan_observers,
+    scan_sorted,
+    table_seam,
     visible_candidates,
 )
 from noahgameframe_tpu.ops.stencil import (
@@ -143,7 +145,8 @@ def test_what_fits_neither_level_is_dropped_and_counted(seed):
 
 def parent_candidates(world, bucket):
     """The one-level answer as the tree before this level built it: the
-    slots un-sorted from the ranks, the payload scattered."""
+    slots un-sorted from the ranks, the payload scattered, read by the
+    scan through `table_seam`."""
     pos, active, scene, group, obs, obs_scene, obs_group = (
         jnp.asarray(a) for a in world)
 
@@ -153,8 +156,8 @@ def parent_candidates(world, bucket):
         slot_of = _slots_from_ranks(N, n_cells, order, skey, rank, bucket)
         table = table_from_slots(_interest_feats(pos, scene, group), active,
                                  slot_of, n_cells, RADIUS, WIDTH, bucket)
-        return _scan_observers(table, obs, obs_scene, obs_group, RADIUS,
-                               RADIUS)
+        return scan_sorted(table_seam(table), obs, obs_scene, obs_group,
+                           RADIUS, RADIUS, WIDTH, bucket)
 
     res = jax.jit(build)(pos, active, scene, group, obs, obs_scene,
                          obs_group)
@@ -374,6 +377,26 @@ def test_counters_and_gauges_are_published():
         'nf_interest_hot_cells{cls="NPC"} 1',
     ):
         assert any(ln.startswith(line) for ln in text.splitlines()), line
+
+
+@pytest.mark.parametrize("serve_batch", [False, True])
+def test_rows_read_counts_the_windows_every_scan_reads(serve_batch):
+    """`nf_interest_rows_read_total`: S_pad x 9 x (bucket + depth) a
+    scan, the level's depth once a breach has sized it."""
+    role, world, _sent = build_role(serve_batch)
+    crowd = Crowd(role, world, still=True)
+    crowd.frame(0)
+    pad = role._session_table.capacity if serve_batch else 8
+    bucket = auto_bucket(256, WIDTH)
+    read = role._interest_rows_read
+    assert read.value(cls="NPC") == pad * 9 * bucket
+    _bucket, cells, depth = role.resolved_interest("NPC")
+    assert cells > 0 and depth > 0
+    role._interest_dirty.add("NPC")
+    crowd.frame(1)
+    assert read.value(cls="NPC") == pad * 9 * (2 * bucket + depth)
+    text = role.telemetry.registry.exposition()
+    assert 'nf_interest_rows_read_total{cls="NPC"}' in text
 
 
 def compiled_texts(dispatch):
